@@ -1,9 +1,12 @@
-"""Pallas kernel micro-benchmarks (interpret mode on CPU).
+"""Pallas kernel micro-benchmarks: compiled on a TPU, interpret mode on
+the CPU (``repro.kernels.pallas_interpret``).
 
-Wall times here are CPU-interpret times (correctness artifacts, NOT TPU
-perf); the derived column reports the kernel's work so the TPU roofline can
-be cross-checked: flops, bytes, and the arithmetic intensity the BlockSpec
-tiling achieves.
+Each row names the backend it ran on.  A CPU row times the Pallas
+interpreter (a correctness artifact, NOT device perf); the derived column
+reports the kernel's work so the TPU roofline can be cross-checked: flops,
+bytes, and the arithmetic intensity the BlockSpec tiling achieves.  The
+rwkv6 and mamba2 kernels do not compile for a TPU yet, so on a TPU this
+suite fails at them.
 """
 from __future__ import annotations
 
@@ -11,6 +14,8 @@ import time
 
 import jax
 import jax.numpy as jnp
+
+from repro.kernels import pallas_interpret
 
 from .common import Reporter
 
@@ -27,6 +32,8 @@ def _time(fn, *args, n=3):
 
 def main(rep: Reporter) -> dict:
     key = jax.random.PRNGKey(0)
+    interpret = pallas_interpret()
+    where = f"({jax.default_backend()})"
     out = {}
 
     # flash attention
@@ -38,10 +45,11 @@ def main(rep: Reporter) -> dict:
     k = jax.random.normal(ks[1], (B, S, H, D))
     v = jax.random.normal(ks[2], (B, S, H, D))
     dt = _time(lambda a, b, c: flash_attention(
-        a, b, c, causal=True, block_q=128, block_k=128, interpret=True), q, k, v)
+        a, b, c, causal=True, block_q=128, block_k=128,
+        interpret=interpret), q, k, v)
     flops = 2 * B * H * (S * S // 2) * D * 2
     rep.add("kernel_flash_attention", dt * 1e6,
-            f"S={S} D={D} causal flops={flops:.2e} (interpret)")
+            f"S={S} D={D} causal flops={flops:.2e} {where}")
     out["flash"] = dt
 
     # decode attention
@@ -53,10 +61,11 @@ def main(rep: Reporter) -> dict:
     vc = jax.random.normal(ks[2], (B2, S2, Hkv, D))
     lens = jnp.full((B2,), S2, jnp.int32)
     dt = _time(lambda a, b, c: decode_attention(
-        a, b, c, lens, block_k=256, interpret=True), q2, kc, vc)
+        a, b, c, lens, block_k=256, interpret=interpret), q2, kc, vc)
     bytes_moved = 2 * B2 * S2 * Hkv * D * 4
     rep.add("kernel_decode_attention", dt * 1e6,
-            f"S={S2} G={Hq // Hkv} bytes={bytes_moved:.2e} AI~{Hq // Hkv}")
+            f"S={S2} G={Hq // Hkv} bytes={bytes_moved:.2e} AI~{Hq // Hkv} "
+            f"{where}")
     out["decode"] = dt
 
     # rwkv6 wkv
@@ -69,9 +78,9 @@ def main(rep: Reporter) -> dict:
     v3 = jax.random.normal(ks2[2], (B3, T3, H3, hd))
     lw = -jnp.exp(jax.random.normal(ks2[3], (B3, T3, H3, hd)) - 1.0)
     u = jax.random.normal(ks2[4], (H3, hd)) * 0.1
-    dt = _time(lambda a, b, c: wkv(a, b, c, lw, u, chunk=32, interpret=True),
-               r, k3, v3)
-    rep.add("kernel_rwkv6_wkv", dt * 1e6, f"T={T3} hd={hd} chunk=32")
+    dt = _time(lambda a, b, c: wkv(a, b, c, lw, u, chunk=32,
+                                   interpret=interpret), r, k3, v3)
+    rep.add("kernel_rwkv6_wkv", dt * 1e6, f"T={T3} hd={hd} chunk=32 {where}")
     out["wkv"] = dt
 
     # mamba2 ssd
@@ -83,8 +92,9 @@ def main(rep: Reporter) -> dict:
     A = -jnp.exp(jax.random.normal(ks2[2], (H4,)))
     Bm = jax.random.normal(ks2[3], (B4, T4, N4))
     Cm = jax.random.normal(ks2[4], (B4, T4, N4))
-    dt = _time(lambda a: ssd(a, dts, A, Bm, Cm, chunk=32, interpret=True), x)
-    rep.add("kernel_mamba2_ssd", dt * 1e6, f"T={T4} N={N4} chunk=32")
+    dt = _time(lambda a: ssd(a, dts, A, Bm, Cm, chunk=32,
+                             interpret=interpret), x)
+    rep.add("kernel_mamba2_ssd", dt * 1e6, f"T={T4} N={N4} chunk=32 {where}")
     out["ssd"] = dt
     return out
 

@@ -10,12 +10,14 @@ results/).  Experiments map 1:1 to the paper:
   exp5_*      Fig. 6  coupled AI-HPC data exchange
   exp6_*      Fig. 7  agent decision rate vs ARR
   roofline_*  (this build) dry-run roofline terms per arch x shape
-  kernel_*    Pallas kernel micro-benchmarks (interpret mode on CPU)
+  kernel_*    Pallas kernel micro-benchmarks (interpret mode on the CPU)
 """
 from __future__ import annotations
 
 import argparse
 import sys
+
+from repro.launch.compile_cache import enable_compile_cache
 
 from . import (bench_agentic, bench_coupling, bench_heterogeneity,
                bench_inference_scaling, bench_roofline, bench_routing,
@@ -46,6 +48,7 @@ def main() -> None:
     ap.add_argument("--only", nargs="*", default=None,
                     help="subset of suites to run")
     args = ap.parse_args()
+    enable_compile_cache()
     rep = Reporter()
     print("name,us_per_call,derived")
     payload = {}

@@ -36,5 +36,11 @@ def get_smoke_config(arch: str, **overrides) -> ModelConfig:
     return cfg.scaled(**overrides) if overrides else cfg
 
 
+def get_arch_config(arch: str, *, smoke: bool = False) -> ModelConfig:
+    """What the launchers' ``--arch`` / ``--smoke`` mean: the published
+    config, or with ``smoke`` the reduced one."""
+    return get_smoke_config(arch) if smoke else get_config(arch)
+
+
 def list_archs():
     return [a for a in ARCHS if a != "rhapsody-demo"]

@@ -173,7 +173,7 @@ def weighted_split(total: int, weights: dict) -> dict:
 
 
 _STAT_KEYS = ("requests", "completed", "errors", "cost",
-              "prefix_hits", "prefix_misses")
+              "prefix_hits", "prefix_misses", "crashes", "replays")
 
 
 def _merge_tenant_stats(snaps, folded, denied) -> dict:
@@ -263,7 +263,11 @@ class ServiceEndpoint:
                       "cost": 0.0,  # routed token-cost (load imbalance)
                       # sticky-routing outcomes (prefix_affinity): a hit
                       # means this replica was the request's cache-warm home
-                      "prefix_hits": 0, "prefix_misses": 0}
+                      "prefix_hits": 0, "prefix_misses": 0,
+                      # servicer crashes on this replica, and in-flight
+                      # requests re-queued for its relaunch: a run that
+                      # got through a crash still shows it here
+                      "crashes": 0, "replays": 0}
         self._stats_lock = threading.Lock()
         self.retired = False  # set when scaled away / replaced
         self.on_retired: Optional[Callable] = None  # drains my queue
@@ -403,11 +407,13 @@ class ServiceInstance(threading.Thread):
         except BaseException as e:  # noqa: BLE001
             self.error = e
             self.endpoint.ready.clear()
+            self.endpoint.bump("crashes")
             # preemption-safe: replay in-flight requests on the relaunched
             # instance (bounded by env.replays), else fail their futures
             for uid, (fut, env) in self._pending.items():
                 if env.replays < 2:
                     env.replays += 1
+                    self.endpoint.bump("replays")
                     self.endpoint.requests.put((env, fut))
                 else:
                     fut.set_error(e)
@@ -458,6 +464,7 @@ class ServiceInstance(threading.Thread):
                     # the relaunched instance before propagating
                     if env.replays < 2:
                         env.replays += 1
+                        self.endpoint.bump("replays")
                         self.endpoint.requests.put((env, fut))
                     else:
                         fut.set_error(e)

@@ -1,12 +1,18 @@
 """Flash-decode — single-token GQA attention over a long KV cache.
 
-Grid ``(B, Hkv, n_k_blocks)``: each program streams one KV block of one kv
-head for one sequence, updating the online-softmax state for that head's
-``G = Hq/Hkv`` query group in VMEM scratch.  KV-length masking handles the
-ragged valid region of the cache; out-of-range blocks are predicated off.
+Grid ``(B, n_k_blocks)``: each program streams one KV block of one
+sequence, ALL kv heads at once, and updates the online-softmax state of
+every query head in VMEM scratch.  A block is ``[block, Hkv, D]``: its
+last two dimensions are the cache's own, which is what the TPU's tiling
+asks of a block, so the kernel compiles for any head count and head
+width.  KV-length masking handles the ragged valid region of the cache;
+blocks at or past a sequence's length are predicated off.
 
-This is the memory-roofline kernel: per block it moves ``2 * bk * D`` cache
-bytes and does ``O(G * bk * D)`` MACs — arithmetic intensity ~G.
+The scores are elementwise products reduced over ``D`` on the vector
+unit (a decode query is one row per head, too thin for the MXU), in
+float32 whatever the cache dtype.  This is the memory-roofline kernel:
+per block it moves ``2 * block * Hkv * D`` cache elements and does
+``O(G * block * Hkv * D)`` MACs — arithmetic intensity ~G.
 
 Two variants share the online-softmax body:
 
@@ -14,12 +20,13 @@ Two variants share the online-softmax body:
   (the slot-pool layout); the KV block index IS the grid index.
 * ``paged_decode_attention_grouped`` — block-paged stores
   ``[num_blocks, block_size, Hkv, D]`` plus per-sequence block tables:
-  the tables and lengths ride in scalar-prefetch SMEM
-  (``PrefetchScalarGridSpec``) so each grid step's BlockSpec index map
-  dereferences ``table[b, ki]`` and the DMA engine fetches the right
-  *physical* block — the gather costs no extra copy.  Logical blocks at
-  or past a sequence's length are predicated off (their table entries
-  point at the null block 0).
+  the tables ride in scalar-prefetch SMEM (``PrefetchScalarGridSpec``)
+  so each grid step's BlockSpec index map dereferences ``table[b, ki]``
+  and the DMA engine fetches the right *physical* block — the gather
+  costs no extra copy.  Logical blocks at or past a sequence's length
+  are predicated off (their table entries point at the null block 0).
+
+Both take the lengths in scalar prefetch as well.
 """
 from __future__ import annotations
 
@@ -34,9 +41,11 @@ from jax.experimental.pallas import tpu as pltpu
 NEG_INF = -1e30
 
 
-def _decode_kernel(len_ref, q_ref, k_ref, v_ref, o_ref, acc_ref, m_ref,
-                   l_ref, *, block_k: int, n_k_blocks: int, sm_scale: float):
-    ki = pl.program_id(2)
+def _decode_body(kv_len, q_ref, k_ref, v_ref, o_ref, acc_ref, m_ref, l_ref,
+                 *, block: int, n_blocks: int, sm_scale: float):
+    """One KV block of one sequence.  q/o: [1, G, Hkv, D]; k/v:
+    [1, block, Hkv, D]; scratch acc [G, Hkv, D], m/l [G, Hkv, 1]."""
+    ki = pl.program_id(1)
 
     @pl.when(ki == 0)
     def _init():
@@ -44,31 +53,71 @@ def _decode_kernel(len_ref, q_ref, k_ref, v_ref, o_ref, acc_ref, m_ref,
         m_ref[...] = jnp.full_like(m_ref, NEG_INF)
         l_ref[...] = jnp.zeros_like(l_ref)
 
-    kv_len = len_ref[0]
-    needed = ki * block_k < kv_len
+    start = ki * block
 
-    @pl.when(needed)
+    @pl.when(start < kv_len)
     def _compute():
-        q = q_ref[0, 0].astype(jnp.float32)  # [G, D]
-        k = k_ref[0, :, 0, :].astype(jnp.float32)  # [bk, D]
-        v = v_ref[0, :, 0, :].astype(jnp.float32)
-        s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ()))) * sm_scale
-        pos = ki * block_k + jax.lax.broadcasted_iota(
-            jnp.int32, s.shape, 1)
-        s = jnp.where(pos < kv_len, s, NEG_INF)
-        m_prev = m_ref[...]
-        m_new = jnp.maximum(m_prev, jnp.max(s, axis=1))
-        p = jnp.exp(s - m_new[:, None])
-        alpha = jnp.exp(m_prev - m_new)
-        l_ref[...] = alpha * l_ref[...] + jnp.sum(p, axis=1)
-        acc_ref[...] = acc_ref[...] * alpha[:, None] + jax.lax.dot_general(
-            p, v, (((1,), (0,)), ((), ())))
-        m_ref[...] = m_new
+        k = k_ref[0].astype(jnp.float32)  # [block, Hkv, D]
+        v = v_ref[0].astype(jnp.float32)
+        pos = start + jax.lax.broadcasted_iota(
+            jnp.int32, (block, k.shape[1], 1), 0)
+        valid = pos < kv_len
+        for g in range(q_ref.shape[1]):  # query heads sharing a kv head
+            q = q_ref[0, g].astype(jnp.float32)  # [Hkv, D]
+            s = jnp.sum(k * q[None], axis=-1, keepdims=True) * sm_scale
+            s = jnp.where(valid, s, NEG_INF)  # [block, Hkv, 1]
+            m_prev = m_ref[g]  # [Hkv, 1]
+            m_new = jnp.maximum(m_prev, jnp.max(s, axis=0))
+            p = jnp.exp(s - m_new[None])
+            alpha = jnp.exp(m_prev - m_new)
+            l_ref[g] = alpha * l_ref[g] + jnp.sum(p, axis=0)
+            acc_ref[g] = acc_ref[g] * alpha + jnp.sum(p * v, axis=0)
+            m_ref[g] = m_new
 
-    @pl.when(ki == n_k_blocks - 1)
+    @pl.when(ki == n_blocks - 1)
     def _finish():
         denom = jnp.maximum(l_ref[...], 1e-30)
-        o_ref[0, 0] = (acc_ref[...] / denom[:, None]).astype(o_ref.dtype)
+        o_ref[0] = (acc_ref[...] / denom).astype(o_ref.dtype)
+
+
+def _decode_kernel(len_ref, *refs, **kw):
+    _decode_body(len_ref[pl.program_id(0)], *refs, **kw)
+
+
+def _paged_decode_kernel(bt_ref, len_ref, *refs, **kw):
+    del bt_ref  # consumed by the index maps
+    _decode_body(len_ref[pl.program_id(0)], *refs, **kw)
+
+
+def _call(kernel, q, caches, scalars, kv_index_map, *, block: int,
+          n_blocks: int, interpret: bool):
+    """Shared pallas_call: q [B, Hkv, G, D] is laid out [B, G, Hkv, D]
+    so each grid step's q/out block is every head of one sequence."""
+    B, Hkv, G, D = q.shape
+    qt = jnp.swapaxes(q, 1, 2)
+    q_spec = pl.BlockSpec((1, G, Hkv, D),
+                          lambda b, ki, *_: (b, 0, 0, 0))
+    kv_spec = pl.BlockSpec((1, block, Hkv, D), kv_index_map)
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=len(scalars),
+        grid=(B, n_blocks),
+        in_specs=[q_spec, kv_spec, kv_spec],
+        out_specs=q_spec,
+        scratch_shapes=[
+            pltpu.VMEM((G, Hkv, D), jnp.float32),
+            pltpu.VMEM((G, Hkv, 1), jnp.float32),
+            pltpu.VMEM((G, Hkv, 1), jnp.float32),
+        ],
+    )
+    kernel = functools.partial(kernel, block=block, n_blocks=n_blocks,
+                               sm_scale=1.0 / math.sqrt(D))
+    out = pl.pallas_call(
+        kernel,
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct(qt.shape, q.dtype),
+        interpret=interpret,
+    )(*scalars, qt, *caches)
+    return jnp.swapaxes(out, 1, 2)
 
 
 def decode_attention_grouped(q, k_cache, v_cache, kv_length, *,
@@ -77,71 +126,14 @@ def decode_attention_grouped(q, k_cache, v_cache, kv_length, *,
 
     Returns [B, Hkv, G, D].
     """
-    B, Hkv, G, D = q.shape
     S = k_cache.shape[1]
     block_k = min(block_k, S)
     if S % block_k:
         raise ValueError(f"cache len {S} % block_k {block_k} != 0")
-    n_k = S // block_k
-    kernel = functools.partial(_decode_kernel, block_k=block_k,
-                               n_k_blocks=n_k, sm_scale=1.0 / math.sqrt(D))
-    return pl.pallas_call(
-        kernel,
-        grid=(B, Hkv, n_k),
-        in_specs=[
-            pl.BlockSpec((1,), lambda b, h, ki: (b,)),
-            pl.BlockSpec((1, 1, G, D), lambda b, h, ki: (b, h, 0, 0)),
-            pl.BlockSpec((1, block_k, 1, D), lambda b, h, ki: (b, ki, h, 0)),
-            pl.BlockSpec((1, block_k, 1, D), lambda b, h, ki: (b, ki, h, 0)),
-        ],
-        out_specs=pl.BlockSpec((1, 1, G, D), lambda b, h, ki: (b, h, 0, 0)),
-        out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
-        scratch_shapes=[
-            pltpu.VMEM((G, D), jnp.float32),
-            pltpu.VMEM((G,), jnp.float32),
-            pltpu.VMEM((G,), jnp.float32),
-        ],
-        interpret=interpret,
-    )(kv_length, q, k_cache, v_cache)
-
-
-def _paged_decode_kernel(bt_ref, len_ref, q_ref, k_ref, v_ref, o_ref,
-                         acc_ref, m_ref, l_ref, *, block_size: int,
-                         max_blocks: int, sm_scale: float):
-    b = pl.program_id(0)
-    ki = pl.program_id(2)
-
-    @pl.when(ki == 0)
-    def _init():
-        acc_ref[...] = jnp.zeros_like(acc_ref)
-        m_ref[...] = jnp.full_like(m_ref, NEG_INF)
-        l_ref[...] = jnp.zeros_like(l_ref)
-
-    kv_len = len_ref[b]
-    needed = ki * block_size < kv_len
-
-    @pl.when(needed)
-    def _compute():
-        q = q_ref[0, 0].astype(jnp.float32)  # [G, D]
-        k = k_ref[0, :, 0, :].astype(jnp.float32)  # [block_size, D]
-        v = v_ref[0, :, 0, :].astype(jnp.float32)
-        s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ()))) * sm_scale
-        pos = ki * block_size + jax.lax.broadcasted_iota(
-            jnp.int32, s.shape, 1)
-        s = jnp.where(pos < kv_len, s, NEG_INF)
-        m_prev = m_ref[...]
-        m_new = jnp.maximum(m_prev, jnp.max(s, axis=1))
-        p = jnp.exp(s - m_new[:, None])
-        alpha = jnp.exp(m_prev - m_new)
-        l_ref[...] = alpha * l_ref[...] + jnp.sum(p, axis=1)
-        acc_ref[...] = acc_ref[...] * alpha[:, None] + jax.lax.dot_general(
-            p, v, (((1,), (0,)), ((), ())))
-        m_ref[...] = m_new
-
-    @pl.when(ki == max_blocks - 1)
-    def _finish():
-        denom = jnp.maximum(l_ref[...], 1e-30)
-        o_ref[0, 0] = (acc_ref[...] / denom[:, None]).astype(o_ref.dtype)
+    return _call(_decode_kernel, q, (k_cache, v_cache),
+                 (kv_length.astype(jnp.int32),),
+                 lambda b, ki, ln: (b, ki, 0, 0),
+                 block=block_k, n_blocks=S // block_k, interpret=interpret)
 
 
 def paged_decode_attention_grouped(q, k_store, v_store, block_tables,
@@ -156,39 +148,14 @@ def paged_decode_attention_grouped(q, k_store, v_store, block_tables,
 
     The tables/lengths are scalar-prefetched: the k/v BlockSpec index maps
     receive them AFTER the grid indices and return
-    ``(table[b, ki], 0, h, 0)``, so the physical block is resolved at DMA
+    ``(table[b, ki], 0, 0, 0)``, so the physical block is resolved at DMA
     issue time — the paged gather is free relative to the contiguous
     kernel, which is the point of paging on a machine that cannot
     reallocate buffers dynamically.
     """
-    B, Hkv, G, D = q.shape
-    _, block_size, _, _ = k_store.shape
-    max_blocks = block_tables.shape[1]
-    kernel = functools.partial(_paged_decode_kernel, block_size=block_size,
-                               max_blocks=max_blocks,
-                               sm_scale=1.0 / math.sqrt(D))
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,  # block_tables, kv_length
-        grid=(B, Hkv, max_blocks),
-        in_specs=[
-            pl.BlockSpec((1, 1, G, D), lambda b, h, ki, bt, ln: (b, h, 0, 0)),
-            pl.BlockSpec((1, block_size, 1, D),
-                         lambda b, h, ki, bt, ln: (bt[b, ki], 0, h, 0)),
-            pl.BlockSpec((1, block_size, 1, D),
-                         lambda b, h, ki, bt, ln: (bt[b, ki], 0, h, 0)),
-        ],
-        out_specs=pl.BlockSpec((1, 1, G, D),
-                               lambda b, h, ki, bt, ln: (b, h, 0, 0)),
-        scratch_shapes=[
-            pltpu.VMEM((G, D), jnp.float32),
-            pltpu.VMEM((G,), jnp.float32),
-            pltpu.VMEM((G,), jnp.float32),
-        ],
-    )
-    return pl.pallas_call(
-        kernel,
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
-        interpret=interpret,
-    )(block_tables.astype(jnp.int32), kv_length.astype(jnp.int32),
-      q, k_store, v_store)
+    return _call(_paged_decode_kernel, q, (k_store, v_store),
+                 (block_tables.astype(jnp.int32),
+                  kv_length.astype(jnp.int32)),
+                 lambda b, ki, bt, ln: (bt[b, ki], 0, 0, 0),
+                 block=k_store.shape[1], n_blocks=block_tables.shape[1],
+                 interpret=interpret)

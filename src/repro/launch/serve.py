@@ -1,5 +1,9 @@
 """Serving launcher: ``python -m repro.launch.serve --arch <id> [...]``.
 
+``--arch`` serves that model at its published widths and depth (seeded,
+random weights); ``--smoke`` serves its reduced smoke config instead.
+The process exits non-zero if any request failed.
+
 Brings up ONE replicated inference service (``--replicas N``) through the
 RHAPSODY middleware and drives a synthetic request stream as INFERENCE
 tasks, so every request is routed to a replica by the policy router
@@ -40,25 +44,38 @@ lowers at production shapes.
 from __future__ import annotations
 
 import argparse
+import sys
 import time
 
 import numpy as np
 
-from repro.configs import get_config, get_smoke_config, list_archs
+from repro.backends.jaxrt import JaxBackend
+from repro.backends.local import PoolBackend
+from repro.configs import get_arch_config, list_archs
 from repro.core import (ExecutionPolicy, ResourceDescription, Rhapsody,
-                        ServiceDescription, TaskDescription, TaskKind)
+                        ServiceDescription, TaskDescription, TaskKind,
+                        TaskState)
 from repro.core.router import ROUTERS
+from repro.launch.compile_cache import enable_compile_cache
 from repro.serving.client import llm_model_group, llm_service_factory
 
+# a published-width replica builds its weights and compiles its first
+# programs before it is ready; a factory that fails is reported at once
+READY_TIMEOUT_S = 600.0
 
-def main():
+
+def build_parser() -> argparse.ArgumentParser:
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="rhapsody-demo",
-                    choices=list_archs() + ["rhapsody-demo"])
-    ap.add_argument("--smoke", action="store_true")
+                    choices=list_archs() + ["rhapsody-demo"],
+                    help="model at its published widths and depth")
+    ap.add_argument("--smoke", action="store_true",
+                    help="use the arch's reduced smoke config instead")
     ap.add_argument("--replicas", "--services", dest="replicas", type=int,
                     default=2, help="service replica count (scaling unit)")
     ap.add_argument("--requests", type=int, default=16)
+    ap.add_argument("--seed", type=int, default=0,
+                    help="seeds the random weights and the request stream")
     ap.add_argument("--max-new-tokens", type=int, default=8)
     ap.add_argument("--max-num-seqs", type=int, default=4)
     ap.add_argument("--max-num-batched-tokens", type=int, default=512)
@@ -108,107 +125,152 @@ def main():
     ap.add_argument("--prefill-replicas", type=int, default=None,
                     help="--disagg: prefill pool size (default: half of "
                          "--replicas, at least 1)")
-    args = ap.parse_args()
+    return ap
+
+
+def make_rhapsody(args, *, jax_nodes: int = 0) -> Rhapsody:
+    """The middleware the service runs in: one node per replica.  With
+    ``jax_nodes``, that many more nodes form a ``"jax"`` partition whose
+    tasks run on the JAX backend, beside the service."""
+    backends = partitions = None
+    if jax_nodes:
+        backends = {"pool": PoolBackend(n_workers=2), "jax": JaxBackend()}
+        partitions = {"pool": args.replicas, "jax": jax_nodes}
+    return Rhapsody(ResourceDescription(nodes=args.replicas + jax_nodes,
+                                        cores_per_node=16),
+                    policy=ExecutionPolicy(
+                        routing=args.routing,
+                        affinity_prefix_len=args.affinity_prefix_len,
+                        affinity_spill_factor=args.affinity_spill_factor,
+                        warmup=args.warmup,
+                        autoscale=args.autoscale,
+                        autoscaler=args.autoscaler,
+                        autoscale_max_replicas=max(4, args.replicas),
+                        slo_p95_ms=args.slo_p95_ms),
+                    backends=backends, partitions=partitions, n_workers=2)
+
+
+def engine_kwargs(args) -> dict:
+    """The engine settings every replica of the service is built with."""
+    return dict(max_num_seqs=args.max_num_seqs,
+                max_num_batched_tokens=args.max_num_batched_tokens,
+                max_len=args.max_len, prefill_buckets=(16, 32, 64),
+                # None = auto: LLMServicer resolves to paged for
+                # dense/moe, slot pool for state-carrying families
+                paged=args.paged, block_size=args.block_size,
+                num_blocks=args.num_blocks, seed=args.seed)
+
+
+def launch_service(rh: Rhapsody, args, cfg):
+    """Launch the ``llm`` service in ``rh`` as ``args`` describe it.
+    Returns (replica set, model group names; empty unless --models)."""
+    engine_kw = engine_kwargs(args)
+    model_names: list = []
+    if args.disagg:
+        n_pre = args.prefill_replicas or max(1, args.replicas // 2)
+        n_dec = max(1, args.replicas - n_pre)
+        disagg_kw = dict(engine_kw, paged=True)
+        groups = [
+            llm_model_group(
+                "prefill", cfg, role="prefill", paired_with="decode",
+                replicas=n_pre, slo_p95_ms=args.slo_p95_ms,
+                **dict(disagg_kw,
+                       # prefill replicas never interleave decode:
+                       # run the whole prompt in as few chunks as
+                       # possible
+                       max_num_batched_tokens=max(
+                           args.max_num_batched_tokens, args.max_len))),
+            llm_model_group(
+                "decode", cfg, role="decode", replicas=n_dec,
+                slo_p95_ms=args.slo_p95_ms, **disagg_kw),
+        ]
+        replica_set = rh.add_service(ServiceDescription(
+            name="llm", replicas=args.replicas, models=groups,
+            ready_timeout=READY_TIMEOUT_S))
+        print(f"[serve] {cfg.name} disaggregated "
+              f"{replica_set.group_counts()} ready:",
+              rh.services.list())
+    elif args.models:
+        groups = []
+        for spec in args.models:
+            name, _, w = spec.partition(":")
+            groups.append(llm_model_group(
+                name, cfg, weight=float(w) if w else 1.0, **engine_kw))
+        model_names = [g.name for g in groups]
+        replica_set = rh.add_service(ServiceDescription(
+            name="llm", replicas=args.replicas, models=groups,
+            ready_timeout=READY_TIMEOUT_S))
+        print(f"[serve] {cfg.name} x {args.replicas} replicas "
+              f"across groups {replica_set.group_counts()} ready:",
+              rh.services.list())
+    else:
+        replica_set = rh.add_service(ServiceDescription(
+            name="llm", replicas=args.replicas,
+            factory=llm_service_factory(cfg, **engine_kw),
+            ready_timeout=READY_TIMEOUT_S))
+        print(f"[serve] {cfg.name} x {args.replicas} replicas ready:",
+              rh.services.list())
+    return replica_set, model_names
+
+
+def request_descs(args, prompts, model_names=()) -> list:
+    """One INFERENCE task per prompt, addressed to the ``llm`` service."""
+
+    def payload(i, p):
+        out = {"prompt": p, "max_new_tokens": args.max_new_tokens}
+        if args.disagg:  # clients always address the prefill pool;
+            #              the set migrates each sequence to a decode
+            #              replica on first token
+            out["model"] = "prefill"
+        elif model_names:  # address models round-robin across stream
+            out["model"] = model_names[i % len(model_names)]
+        return out
+
+    return [TaskDescription(kind=TaskKind.INFERENCE, service="llm",
+                            payload=payload(i, p), task_type="inference")
+            for i, p in enumerate(prompts)]
+
+
+def failed_tasks(rh: Rhapsody, uids) -> list:
+    """(uid, error) of every task among ``uids`` that failed."""
+    return [(u, rh.tasks[u].error) for u in uids
+            if rh.state(u) == TaskState.FAILED]
+
+
+def main(argv=None) -> int:
+    ap = build_parser()
+    args = ap.parse_args(argv)
     if args.disagg and args.models:
         ap.error("--disagg and --models are mutually exclusive")
     if args.disagg and args.paged is False:
         ap.error("--disagg requires the paged KV cache (drop --no-paged)")
 
-    cfg = (get_smoke_config(args.arch)
-           if args.smoke or args.arch != "rhapsody-demo"
-           else get_config(args.arch))
-    rh = Rhapsody(ResourceDescription(nodes=args.replicas,
-                                      cores_per_node=16),
-                  policy=ExecutionPolicy(
-                      routing=args.routing,
-                      affinity_prefix_len=args.affinity_prefix_len,
-                      affinity_spill_factor=args.affinity_spill_factor,
-                      warmup=args.warmup,
-                      autoscale=args.autoscale,
-                      autoscaler=args.autoscaler,
-                      autoscale_max_replicas=max(4, args.replicas),
-                      slo_p95_ms=args.slo_p95_ms),
-                  n_workers=2)
-    engine_kw = dict(max_num_seqs=args.max_num_seqs,
-                     max_num_batched_tokens=args.max_num_batched_tokens,
-                     max_len=args.max_len, prefill_buckets=(16, 32, 64),
-                     # None = auto: LLMServicer resolves to paged for
-                     # dense/moe, slot pool for state-carrying families
-                     paged=args.paged, block_size=args.block_size,
-                     num_blocks=args.num_blocks)
-    model_names: list = []
+    enable_compile_cache()
+    cfg = get_arch_config(args.arch, smoke=args.smoke)
+    rh = make_rhapsody(args)
     try:
-        if args.disagg:
-            n_pre = args.prefill_replicas or max(1, args.replicas // 2)
-            n_dec = max(1, args.replicas - n_pre)
-            disagg_kw = dict(engine_kw, paged=True)
-            groups = [
-                llm_model_group(
-                    "prefill", cfg, role="prefill", paired_with="decode",
-                    replicas=n_pre, slo_p95_ms=args.slo_p95_ms,
-                    **dict(disagg_kw,
-                           # prefill replicas never interleave decode:
-                           # run the whole prompt in as few chunks as
-                           # possible
-                           max_num_batched_tokens=max(
-                               args.max_num_batched_tokens, args.max_len))),
-                llm_model_group(
-                    "decode", cfg, role="decode", replicas=n_dec,
-                    slo_p95_ms=args.slo_p95_ms, **disagg_kw),
-            ]
-            replica_set = rh.add_service(ServiceDescription(
-                name="llm", replicas=args.replicas, models=groups))
-            print(f"[serve] {cfg.name} disaggregated "
-                  f"{replica_set.group_counts()} ready:",
-                  rh.services.list())
-        elif args.models:
-            groups = []
-            for spec in args.models:
-                name, _, w = spec.partition(":")
-                groups.append(llm_model_group(
-                    name, cfg, weight=float(w) if w else 1.0, **engine_kw))
-            model_names = [g.name for g in groups]
-            replica_set = rh.add_service(ServiceDescription(
-                name="llm", replicas=args.replicas, models=groups))
-            print(f"[serve] {cfg.name} x {args.replicas} replicas "
-                  f"across groups {replica_set.group_counts()} ready:",
-                  rh.services.list())
-        else:
-            replica_set = rh.add_service(ServiceDescription(
-                name="llm", replicas=args.replicas,
-                factory=llm_service_factory(cfg, **engine_kw)))
-            print(f"[serve] {cfg.name} x {args.replicas} replicas ready:",
-                  rh.services.list())
-
-        rng = np.random.RandomState(0)
+        replica_set, model_names = launch_service(rh, args, cfg)
+        rng = np.random.RandomState(args.seed)
         lens = np.clip(np.exp(rng.normal(3.0, 0.7, args.requests)), 4,
                        args.max_len - args.max_new_tokens - 1).astype(int)
         prompts = [list(rng.randint(0, cfg.vocab, size=int(L)))
                    for L in lens]
-
-        def payload(i, p):
-            out = {"prompt": p, "max_new_tokens": args.max_new_tokens}
-            if args.disagg:  # clients always address the prefill pool;
-                #              the set migrates each sequence to a decode
-                #              replica on first token
-                out["model"] = "prefill"
-            elif model_names:  # address models round-robin across stream
-                out["model"] = model_names[i % len(model_names)]
-            return out
-
-        descs = [TaskDescription(kind=TaskKind.INFERENCE, service="llm",
-                                 payload=payload(i, p),
-                                 task_type="inference")
-                 for i, p in enumerate(prompts)]
         t0 = time.perf_counter()
-        uids = rh.submit(descs)
+        uids = rh.submit(request_descs(args, prompts, model_names))
         if not rh.wait(uids, timeout=1200):
             raise TimeoutError("inference stream timed out")
-        results = [rh.result(u) for u in uids]
         dt = time.perf_counter() - t0
+        stats = replica_set.stats()
+        failed = failed_tasks(rh, uids)
+        print(f"[serve] replica crashes {stats['crashes']}, replayed "
+              f"requests {stats['replays']}")
+        if failed:
+            print(f"[serve] {len(failed)}/{len(uids)} requests FAILED; "
+                  f"first: {failed[0][1]!r}")
+            return 1
+        results = [rh.result(u) for u in uids]
         tokens = sum(len(r["tokens"]) + r["n_prompt"] for r in results)
         lat = sorted(r["latency_s"] for r in results)
-        stats = replica_set.stats()
         utils = [inst.servicer.stats.utilization
                  for inst in replica_set.instances]
         print(f"[serve] {len(results)} requests, {dt:.2f}s, "
@@ -262,9 +324,10 @@ def main():
             print(f"[serve] prefix-affinity: {hits} hits / {misses} misses "
                   f"(rate {hits / max(1, hits + misses):.2f}); "
                   f"engine prefill tokens skipped per replica: {reuse}")
+        return 0
     finally:
         rh.close()
 
 
 if __name__ == "__main__":
-    main()
+    sys.exit(main())

@@ -1,9 +1,11 @@
 """Training launcher: ``python -m repro.launch.train --arch <id> [...]``.
 
-Single-host execution with the real data pipeline, checkpoint/restart, and
-(optionally) a local device mesh.  At production scale the same factories
-are consumed by the dry-run (``repro.launch.dryrun``) with the 16x16 /
-2x16x16 meshes — this CLI is the runnable end of the same code path.
+Single-host execution with the real data pipeline and checkpoint/restart.
+``--arch`` trains that model at its published widths and depth;
+``--smoke`` trains its reduced smoke config.  At production scale the
+same factories are consumed by the dry-run (``repro.launch.dryrun``) with
+the 16x16 / 2x16x16 meshes — this CLI is the runnable end of the same
+code path.
 """
 from __future__ import annotations
 
@@ -12,8 +14,8 @@ import time
 
 import jax
 
-from repro.configs import get_config, get_smoke_config, list_archs
-from repro.launch.mesh import make_local_mesh
+from repro.configs import get_arch_config, list_archs
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models import get_model
 from repro.substrate.data import DataConfig, DataPipeline
 from repro.training.checkpoint import Checkpointer
@@ -21,12 +23,12 @@ from repro.training.optim import OptimizerConfig
 from repro.training.train import TrainConfig, init_state, make_train_step
 
 
-def main():
+def build_parser() -> argparse.ArgumentParser:
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="rhapsody-demo",
                     choices=list_archs() + ["rhapsody-demo"])
     ap.add_argument("--smoke", action="store_true",
-                    help="use the reduced smoke config (CPU-friendly)")
+                    help="use the arch's reduced smoke config instead")
     ap.add_argument("--steps", type=int, default=100)
     ap.add_argument("--batch", type=int, default=8)
     ap.add_argument("--seq", type=int, default=128)
@@ -37,10 +39,13 @@ def main():
     ap.add_argument("--ckpt-every", type=int, default=50)
     ap.add_argument("--resume", action="store_true")
     ap.add_argument("--log-every", type=int, default=10)
-    args = ap.parse_args()
+    return ap
 
-    cfg = (get_smoke_config(args.arch) if args.smoke or args.arch != "rhapsody-demo"
-           else get_config(args.arch))
+
+def main(argv=None):
+    args = build_parser().parse_args(argv)
+    enable_compile_cache()
+    cfg = get_arch_config(args.arch, smoke=args.smoke)
     api = get_model(cfg)
     opt = OptimizerConfig(lr=args.lr, warmup_steps=max(1, args.steps // 20),
                           decay_steps=args.steps,

@@ -8,8 +8,8 @@ Implementations
                    live memory to one ``[B, H, block_q, kv_len]`` score tile —
                    it is both the long-context dry-run path and the oracle
                    shape for the Pallas flash kernel.
-  * ``pallas``   — ``repro.kernels.flash_attention`` (TPU target; interpret
-                   mode on CPU).
+  * ``pallas``   — ``repro.kernels.flash_attention`` (compiled on a TPU;
+                   interpret mode on the CPU, see ``repro.kernels``).
 
 Decode attends one new token against a (possibly sequence-sharded) KV cache;
 softmax over the sharded axis lowers to partial-reduce + all-reduce under
@@ -22,6 +22,8 @@ from typing import Optional
 
 import jax
 import jax.numpy as jnp
+
+from repro import kernels
 
 from . import nn
 from .config import ModelConfig
@@ -216,8 +218,6 @@ def decode_attention(q, k_cache, v_cache, kv_length):
 def _pick_impl(cfg: ModelConfig, seq: int) -> str:
     if cfg.attention_impl != "auto":
         return cfg.attention_impl
-    if cfg.use_pallas:
-        return "pallas"
     return "chunked" if seq > 2048 else "full"
 
 
@@ -272,7 +272,7 @@ def attention_apply(p, x, cfg: ModelConfig, *, causal=True, positions=None,
         out = fa_ops.flash_attention(q, k, v, causal=True,
                                      block_q=cfg.attn_chunk_q,
                                      block_k=cfg.attn_chunk_k,
-                                     interpret=not cfg.use_pallas or None)
+                                     interpret=kernels.pallas_interpret())
     elif impl == "chunked" and causal and x_kv is x and S % cfg.attn_chunk_q == 0:
         out = chunked_causal_attention(q, k, v, block_q=cfg.attn_chunk_q,
                                        block_k=cfg.attn_chunk_k)
@@ -359,13 +359,14 @@ def attention_decode_paged(p, x, k_store, v_store, block_tables, kv_length,
 
     Unlike ``attention_decode`` this never materializes a contiguous
     [B, Smax] cache view: the new K/V row is written into ONLY its tail
-    block, and attention reads K/V through the block table — via the
-    scalar-prefetch Pallas kernel (``paged_decode_attention``) when
-    ``cfg.use_pallas`` is on, so the gather happens at DMA issue time and
-    per-token HBM traffic is O(blocks-touched) instead of O(Smax).  The
-    CPU fallback gathers through the table in jnp (the
-    ``paged_decode_ref`` oracle shape) and reuses ``decode_attention``,
-    so greedy outputs are bit-identical to the slot path.
+    block, and attention reads K/V through the block table.  On a TPU
+    that is the scalar-prefetch Pallas kernel (``paged_decode_attention``),
+    so the gather happens at DMA issue time and per-token HBM traffic is
+    O(blocks-touched) instead of O(Smax).  On the CPU it gathers through
+    the table in jnp (the ``paged_decode_ref`` oracle shape) and reuses
+    ``decode_attention``, so greedy outputs are bit-identical to the slot
+    path; ``attention_impl="pallas"`` runs the kernel there instead, in
+    interpret mode.
 
     Returns (out [B,1,d], k_store, v_store).
     """
@@ -378,14 +379,13 @@ def attention_decode_paged(p, x, k_store, v_store, block_tables, kv_length,
     v_store = v_store.at[write_phys, write_off].set(
         v_new[:, 0].astype(v_store.dtype))
     new_len = kv_length + 1
-    if cfg.use_pallas or cfg.attention_impl == "pallas":
+    interpret = kernels.pallas_interpret()
+    if cfg.attention_impl == "pallas" or not interpret:
         from repro.kernels.decode_attention import ops as da_ops
 
-        # interpret mode when forced onto the kernel without a TPU
-        # (attention_impl="pallas" on CPU), mirroring attention_apply
         out = da_ops.paged_decode_attention(q, k_store, v_store,
                                             block_tables, new_len,
-                                            interpret=not cfg.use_pallas)
+                                            interpret=interpret)
     else:
         from repro.kernels.decode_attention.ref import gather_kv
 

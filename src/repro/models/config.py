@@ -84,7 +84,6 @@ class ModelConfig:
     compute_dtype: str = "float32"
     scan_layers: bool = True
     remat: str = "full"  # none | full | dots
-    use_pallas: bool = False  # TPU target; CPU tests use interpret/jnp paths
     # distribution optimizations (hillclimb; baseline = False)
     pad_heads_to: int = 0  # pad q-heads per kv-group for clean TP sharding
     explicit_tp: bool = False  # Megatron-style shard_map TP linears (bf16 AR)

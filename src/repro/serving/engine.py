@@ -46,7 +46,7 @@ partial hits, and skipped tokens are tracked in ``EngineStats``.
     (``paged_decode_mode="direct"``, the default): the new token's K/V is
     written into only its tail block and attention reads K/V through the
     block table (``api.decode_paged`` -> the scalar-prefetch Pallas
-    kernel when ``use_pallas`` is on), so per-token HBM traffic is
+    kernel on a TPU), so per-token HBM traffic is
     O(blocks-touched) instead of the O(B*Smax*H*D) gather/scatter
     round-trip.  ``paged_decode_mode="gather"`` keeps the old
     reassembled-view decode for A/B benchmarking; chunked *extend*
@@ -86,6 +86,7 @@ plain target-engine stepping — the same graceful-off signal the
 from __future__ import annotations
 
 import dataclasses
+import functools
 import itertools
 import time
 from collections import OrderedDict
@@ -196,6 +197,30 @@ class EngineStats:
         return (self.decode_tokens + self.prefill_tokens) / max(1e-9, dt)
 
 
+def paged_extend_step(api: ModelApi, cfg: ModelConfig, params, store, bt,
+                      lens, tokens, wphys, woff, *, mesh=None):
+    """One prefill chunk on the paged store: gather the sequences' block
+    views, extend them by ``tokens`` [B, T], and scatter the T new K/V
+    rows into their (``wphys``, ``woff``) cells.  Returns (store, logits
+    [B, T, vocab])."""
+    view = gather_block_view(store, bt, lens)
+    view, logits = api.extend(params, view, tokens, cfg, mesh=mesh)
+    T = tokens.shape[1]
+    wpos = lens[:, None] + jnp.arange(T)[None, :]
+    return scatter_block_writes(store, view, wphys, woff, wpos), logits
+
+
+def paged_decode_step(api: ModelApi, cfg: ModelConfig, params, store, bt,
+                      lens, tokens, wphys, woff, *, mesh=None):
+    """One decode token per sequence straight on the paged store: no
+    gathered view — the model writes the token's K/V into its tail block
+    and reads K/V through the block table (the Pallas paged kernel on a
+    TPU, a jnp table-gather on the CPU).  Returns (store, logits [B,
+    vocab])."""
+    return api.decode_paged(params, store, bt, lens, tokens, wphys, woff,
+                            cfg, mesh=mesh)
+
+
 class InferenceEngine:
     """Single-model continuous-batching engine."""
 
@@ -276,30 +301,17 @@ class InferenceEngine:
             self._res_counter = itertools.count()
             self._reserved = 0  # admission-reserved, not-yet-allocated
 
-            def paged_extend_fn(params, store, bt, lens, tokens, wphys, woff):
-                view = gather_block_view(store, bt, lens)
-                view, logits = api.extend(params, view, tokens, cfg,
-                                          mesh=mesh)
-                T = tokens.shape[1]
-                wpos = lens[:, None] + jnp.arange(T)[None, :]
-                store = scatter_block_writes(store, view, wphys, woff, wpos)
-                return store, logits
-
             if paged_decode_mode not in ("direct", "gather"):
                 raise ValueError(
                     f"paged_decode_mode must be 'direct' or 'gather', "
                     f"not {paged_decode_mode!r}")
             self.paged_decode_mode = paged_decode_mode
+            paged_extend_fn = functools.partial(paged_extend_step, api, cfg,
+                                                mesh=mesh)
 
             if paged_decode_mode == "direct":
-                # the tentpole path: no gather_block_view on decode — the
-                # model writes the token's K/V into its tail block and
-                # reads K/V through the block table (Pallas paged kernel
-                # under use_pallas, jnp table-gather fallback otherwise)
-                def paged_decode_fn(params, store, bt, lens, tokens, wphys,
-                                    woff):
-                    return api.decode_paged(params, store, bt, lens, tokens,
-                                            wphys, woff, cfg, mesh=mesh)
+                paged_decode_fn = functools.partial(paged_decode_step, api,
+                                                    cfg, mesh=mesh)
             else:
                 # legacy A/B path: reassemble a contiguous [B, Smax] view,
                 # run the slot-pool decode, scatter the one new row back

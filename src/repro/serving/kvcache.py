@@ -21,8 +21,8 @@ index becomes real memory headroom instead of whole-slot duplication.
 Decode runs DIRECTLY on the physical store: the engine's default
 ``paged_decode_mode="direct"`` writes each new token's K/V into its
 sequence's tail block (one cell per row) and attends through the block
-table — the Pallas paged-decode kernel under ``use_pallas``, a jnp
-table-gather fallback on CPU — so the per-step cost scales with the
+table — the Pallas paged-decode kernel on a TPU, a jnp table-gather
+on the CPU — so the per-step cost scales with the
 blocks a sequence actually occupies, not ``max_len``.  Only chunked
 prefill/extend still reassembles a contiguous ``[B, max_len, ...]``
 view (``gather_block_view``) and scatters the newly produced positions

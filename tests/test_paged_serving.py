@@ -300,6 +300,22 @@ def test_paged_decode_modes_equivalent(family, dense_lm, moe_lm):
     assert outs["direct"] == outs["gather"] == outs["slot"]
 
 
+@pytest.mark.parametrize("family", ["dense", "moe"])
+def test_paged_kernel_decode_matches_slot(family, dense_lm, moe_lm):
+    """Direct decode through the Pallas paged kernel (interpret mode here;
+    the path a TPU takes with the shipped configs) is token-identical to
+    the slot pool, across block-boundary lengths."""
+    cfg, api, params = dense_lm if family == "dense" else moe_lm
+    rng = np.random.RandomState(9)
+    prompts = [list(rng.randint(1, cfg.vocab, size=n))
+               for n in (7, 8, 9, 16, 17, 31)]
+    slot = _drive(InferenceEngine(cfg, params, **ENGINE_KW), prompts, 6)
+    kernel = _drive(InferenceEngine(cfg.scaled(attention_impl="pallas"),
+                                    params, **ENGINE_KW, paged=True,
+                                    block_size=8), prompts, 6)
+    assert kernel == slot
+
+
 def test_paged_decode_modes_agree_on_divergence(dense_lm):
     """Partial-hit resume plus copy-on-write divergence produce identical
     greedy tokens under the direct kernel and the gather round-trip, and
