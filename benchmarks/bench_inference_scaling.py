@@ -2,9 +2,9 @@
 
 Proportionally grows replicas / clients (paper: 1/1/10 -> 8/8/80; here
 scaled to the host) with homogeneous prompts, measuring aggregate token
-throughput and engine utilization (the GPU-utilization analogue: fraction
-of decode-slot-steps occupied).  One service name, N replicas: clients all
-hit the same replica set and the shared router spreads them.
+throughput and the engines' mean decode batch (sequences per decode
+call).  One service name, N replicas: clients all hit the same replica
+set and the shared router spreads them.
 
 ``--autoscale`` switches to the admission-controlled autoscaling scenario
 (§III-C: services claim resources from the same partition ledger as
@@ -102,8 +102,8 @@ def run_config(n_replicas: int, clients_per_replica: int,
             t.join()
         dt = time.perf_counter() - t0
         total_tokens = sum(len(r["tokens"]) + r["n_prompt"] for r in results)
-        utils = [inst.servicer.stats.utilization
-                 for inst in replica_set.instances]
+        engines = [inst.servicer.stats for inst in replica_set.instances]
+        calls = sum(st.decode_calls for st in engines)
         stats = replica_set.stats()
         return {
             "replicas": n_replicas,
@@ -111,7 +111,8 @@ def run_config(n_replicas: int, clients_per_replica: int,
             "requests": len(results),
             "seconds": dt,
             "tokens_per_s": total_tokens / dt,
-            "utilization": sum(utils) / len(utils),
+            "decode_batch_mean": (sum(st.decode_tokens for st in engines)
+                                  / max(1, calls)),
             "per_replica_requests": [p["requests"]
                                      for p in stats["per_replica"]],
         }
@@ -126,7 +127,8 @@ def main(rep: Reporter, *, configs=((1, 2), (2, 2), (4, 2))) -> dict:
         out.append(r)
         rep.add(f"exp3_infer_s{n_replicas}",
                 1e6 * r["seconds"] / max(1, r["requests"]),
-                f"{r['tokens_per_s']:.0f} tok/s util={r['utilization']:.2f} "
+                f"{r['tokens_per_s']:.0f} tok/s "
+                f"decode batch={r['decode_batch_mean']:.1f} "
                 f"clients={r['clients']}")
     return {"configs": out}
 
@@ -485,11 +487,12 @@ def run_paged_compare(*, max_num_seqs: int = 4, max_len: int = 64,
         stem_out, _ = _drive(eng, [stem], new_tokens)
         branch_out, peak = _drive(eng, branches, new_tokens)
         dt = time.perf_counter() - t0
+        st = eng.stats
+        driven = st.decode_tokens + st.prefill_tokens
         # everything is compiled now: measure pure decode throughput
         # (best of 3 warm bursts — see _decode_burst)
         decode_tps = _decode_burst(eng, branches, burst_tokens)
         outs[name] = stem_out + branch_out
-        st = eng.stats
         tel = eng.block_telemetry()
         rows.append({
             "scenario": "paged_compare",
@@ -502,7 +505,7 @@ def run_paged_compare(*, max_num_seqs: int = 4, max_len: int = 64,
             "num_blocks": eng.num_blocks if extra.get("paged") else None,
             "requests": 1 + n_branches,
             "seconds": dt,
-            "tokens_per_s": st.tokens_per_s,
+            "tokens_per_s": driven / dt,
             "decode_tokens_per_s": decode_tps,
             "peak_concurrent": peak,
             "prefix_reuse_hits": st.prefix_reuse_hits,

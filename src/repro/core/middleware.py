@@ -24,6 +24,7 @@ from .request import AdmissionDenied, InferenceRequest
 from .router import default_cost, router_from_policy
 from .service import ServiceDescription, ServiceManager
 from .task import Task, TaskDescription, TaskKind, TaskState
+from .tracing import span
 
 
 class Rhapsody:
@@ -277,37 +278,38 @@ class Rhapsody:
         backend.submit(task)
 
     def _dispatch_inference(self, task: Task):
-        desc = task.desc
-        # the task's payload + metadata become one InferenceRequest
-        # envelope: ``wrap`` lifts the {"model": ...} tag and any
-        # tenant/priority/deadline_s metadata onto first-class fields,
-        # so QoS identity rides the task into the serving layer.
-        env = InferenceRequest.wrap(desc.payload, meta=dict(desc.metadata))
-        cost = default_cost(env.payload)
-        try:
-            replica_set = self.services.get(desc.service)
-            if not self.router.admit(env, cost=cost):
-                # rate limiting is backpressure to the CLIENT: the task
-                # fails immediately instead of queueing over-quota load
-                replica_set.note_tenant_denied(env.tenant)
-                raise AdmissionDenied(env.tenant)
-            # the load-balancing spine: every INFERENCE task picks its
-            # replica through the policy router (token-cost + queue-depth
-            # aware), not a fixed endpoint; under prefix_affinity routing
-            # the payload's prompt-prefix signature makes same-session
-            # requests stick to their cache-warm replica.  An envelope
-            # with ``model`` set routes only among that model group's
-            # replicas (multi-model services); an unknown tag fails the
-            # task like an unknown service would.
-            endpoint = replica_set.route(env, self.router, cost=cost)
-        except (KeyError, AdmissionDenied) as e:
-            self._complete(task, None, e)
-            return
-        task.state = TaskState.RUNNING
-        task.started_at = time.perf_counter()
-        self.events.emit(task.uid, "RUNNING", desc.task_type,
-                         f"replica={endpoint.replica_idx}")
-        fut = endpoint.request_env(env)
+        with span("middleware.dispatch", task=task.uid):
+            desc = task.desc
+            # the task's payload + metadata become one InferenceRequest
+            # envelope: ``wrap`` lifts the {"model": ...} tag and any
+            # tenant/priority/deadline_s metadata onto first-class fields,
+            # so QoS identity rides the task into the serving layer.
+            env = InferenceRequest.wrap(desc.payload, meta=dict(desc.metadata))
+            cost = default_cost(env.payload)
+            try:
+                replica_set = self.services.get(desc.service)
+                if not self.router.admit(env, cost=cost):
+                    # rate limiting is backpressure to the CLIENT: the task
+                    # fails immediately instead of queueing over-quota load
+                    replica_set.note_tenant_denied(env.tenant)
+                    raise AdmissionDenied(env.tenant)
+                # the load-balancing spine: every INFERENCE task picks its
+                # replica through the policy router (token-cost + queue-depth
+                # aware), not a fixed endpoint; under prefix_affinity routing
+                # the payload's prompt-prefix signature makes same-session
+                # requests stick to their cache-warm replica.  An envelope
+                # with ``model`` set routes only among that model group's
+                # replicas (multi-model services); an unknown tag fails the
+                # task like an unknown service would.
+                endpoint = replica_set.route(env, self.router, cost=cost)
+            except (KeyError, AdmissionDenied) as e:
+                self._complete(task, None, e)
+                return
+            task.state = TaskState.RUNNING
+            task.started_at = time.perf_counter()
+            self.events.emit(task.uid, "RUNNING", desc.task_type,
+                             f"replica={endpoint.replica_idx}")
+            fut = endpoint.request_env(env)
         timeout = self.policy.inference_timeout_s
 
         def waiter():

@@ -71,6 +71,7 @@ from .autoscale import LatencyWindow, autoscaler_from_policy, percentile
 from .request import AdmissionDenied, InferenceRequest, RouteContext
 from .router import Router, default_cost, router_from_policy
 from .task import ResourceRequirements
+from .tracing import span, trace_gc
 
 
 @dataclasses.dataclass
@@ -391,15 +392,21 @@ class ServiceInstance(threading.Thread):
                 self.servicer.warmup()
             self.endpoint.ready.set()
             self.ready_at = time.perf_counter()
+            trace_gc()
             pumped = hasattr(self.servicer, "step")
             while self.alive or (self._drain and self._pending):
                 self.last_beat = time.perf_counter()
                 moved = self._admit() if self.alive else False
                 if pumped:
                     if self._pending:
-                        for uid, result in self.servicer.step() or []:
-                            self._resolve(uid, result)
-                        self._drain_finished()
+                        results = self.servicer.step() or []
+                        with span("service.resolve") as sp:
+                            n = 0
+                            for uid, result in results:
+                                self._resolve(uid, result)
+                                n += 1
+                            self._drain_finished()
+                            sp.set_metadata(results=n)
                     elif not moved:
                         time.sleep(1e-4)
                 elif not moved:
@@ -443,43 +450,47 @@ class ServiceInstance(threading.Thread):
 
     # -- internals ----------------------------------------------------------
     def _admit(self) -> bool:
-        moved = False
-        for _ in range(64):
-            try:
-                env, fut = self.endpoint.requests.get_nowait()
-            except queue.Empty:
-                break
-            moved = True
-            kw = env.servicer_kwargs()
-            if hasattr(self.servicer, "submit"):
-                if getattr(self.servicer, "accepts_envelope", False):
-                    # envelope-aware servicers (LLMServicer) get the full
-                    # record (tenant/priority/handoff); plain test
-                    # servicers keep the bare payload + public meta
-                    kw["envelope"] = env
+        if self.endpoint.requests.empty():
+            return False
+        with span("service.admit") as sp:
+            moved = 0
+            for _ in range(64):
                 try:
-                    uid = self.servicer.submit(env.payload, **kw)
-                except BaseException as e:  # noqa: BLE001
-                    # crash mid-submit: requeue THIS request for replay on
-                    # the relaunched instance before propagating
-                    if env.replays < 2:
-                        env.replays += 1
-                        self.endpoint.bump("replays")
-                        self.endpoint.requests.put((env, fut))
-                    else:
+                    env, fut = self.endpoint.requests.get_nowait()
+                except queue.Empty:
+                    break
+                moved += 1
+                kw = env.servicer_kwargs()
+                if hasattr(self.servicer, "submit"):
+                    if getattr(self.servicer, "accepts_envelope", False):
+                        # envelope-aware servicers (LLMServicer) get the full
+                        # record (tenant/priority/handoff); plain test
+                        # servicers keep the bare payload + public meta
+                        kw["envelope"] = env
+                    try:
+                        uid = self.servicer.submit(env.payload, **kw)
+                    except BaseException as e:  # noqa: BLE001
+                        # crash mid-submit: requeue THIS request for replay on
+                        # the relaunched instance before propagating
+                        if env.replays < 2:
+                            env.replays += 1
+                            self.endpoint.bump("replays")
+                            self.endpoint.requests.put((env, fut))
+                        else:
+                            fut.set_error(e)
+                            self.endpoint.bump("errors", tenant=env.tenant)
+                        raise
+                    self._pending[uid] = (fut, env)
+                else:  # sync RPC servicer (same public-meta kwargs as submit)
+                    try:
+                        fut.set_result(self.servicer.handle(env.payload, **kw))
+                        self.endpoint.bump("completed", tenant=env.tenant)
+                        self._observe(env)
+                    except BaseException as e:  # noqa: BLE001
                         fut.set_error(e)
                         self.endpoint.bump("errors", tenant=env.tenant)
-                    raise
-                self._pending[uid] = (fut, env)
-            else:  # sync RPC servicer (same public-meta kwargs as submit)
-                try:
-                    fut.set_result(self.servicer.handle(env.payload, **kw))
-                    self.endpoint.bump("completed", tenant=env.tenant)
-                    self._observe(env)
-                except BaseException as e:  # noqa: BLE001
-                    fut.set_error(e)
-                    self.endpoint.bump("errors", tenant=env.tenant)
-        return moved
+            sp.set_metadata(requests=moved)
+        return moved > 0
 
     def _observe(self, env: InferenceRequest):
         if env.submitted_at is not None:
@@ -936,80 +947,85 @@ class ReplicaSet:
         Only READY replicas are candidates: a freshly spawned replica is
         in ``endpoints`` before its factory finishes, and routing to it
         would queue work nothing admits yet."""
-        gsel = self._resolve_group(env.model)
-        if cost is None:
-            cost = default_cost(env.payload)
-        with self._lock:
-            pairs = [(ep, inst) for ep, inst
-                     in zip(self.endpoints, self.instances)
-                     if ep.group == gsel]
-            eps = [ep for ep, _ in pairs
-                   if ep.ready.is_set() and not ep.retired]
-            self._route_count += 1  # under the lock: lost increments
-            route_count = self._route_count  # would starve gossip ticks
+        with span("replica_set.route"):
+            gsel = self._resolve_group(env.model)
+            if cost is None:
+                cost = default_cost(env.payload)
+            with self._lock:
+                pairs = [(ep, inst) for ep, inst
+                         in zip(self.endpoints, self.instances)
+                         if ep.group == gsel]
+                eps = [ep for ep, _ in pairs
+                       if ep.ready.is_set() and not ep.retired]
+                self._route_count += 1  # under the lock: lost increments
+                route_count = self._route_count  # would starve gossip ticks
+                if not eps:
+                    # none ready yet (launch/relaunch window): queue on a
+                    # replica that is still coming up. A crashed replica
+                    # counts only when restarts are enabled (its endpoint
+                    # survives the relaunch and the queue is served then);
+                    # otherwise the request would sit on a dead queue forever
+                    restart = getattr(self.manager.policy,
+                                      "restart_failed_services", False)
+                    eps = [ep for ep, inst in pairs if not ep.retired
+                           and (inst.error is None or restart)]
+                successor = self._successor
             if not eps:
-                # none ready yet (launch/relaunch window): queue on a
-                # replica that is still coming up. A crashed replica
-                # counts only when restarts are enabled (its endpoint
-                # survives the relaunch and the queue is served then);
-                # otherwise the request would sit on a dead queue forever
-                restart = getattr(self.manager.policy,
-                                  "restart_failed_services", False)
-                eps = [ep for ep, inst in pairs
-                       if not ep.retired and (inst.error is None or restart)]
-            successor = self._successor
-        if not eps:
-            if successor is not None:  # name was re-launched; follow it
-                return successor.route(env, router, cost=cost,
-                                       account_affinity=account_affinity)
-            raise KeyError(f"service {self.name} has no live replicas"
-                           + (f" for model {gsel!r}" if self.multi_model
-                              else ""))
-        # periodically gossip replica residency summaries to the router so
-        # prefix-aware spill sees fresh caches (stats() also syncs); the
-        # pull runs on a background thread — snapshotting every engine's
-        # index must not add inline latency to the unlucky Nth request
-        if getattr(router, "uses_residency", False):
-            every = getattr(self.manager.policy, "residency_sync_every", 32)
-            if every and every > 0 and route_count % every == 0:
-                self._sync_residency_async()
-        # key BALANCE state by generation + candidate MEMBERSHIP, not just
-        # the name: positions in eps shift as replicas crash/recover, and
-        # reusing positional load history across different subsets (or a
-        # recurring subset from before a membership change) would charge
-        # one replica's history to another.  Sticky state instead keys on
-        # the stable (name, uid) affinity group with stable replica_idx
-        # member identities, so session assignments survive membership
-        # churn and only sessions homed on a departed replica re-home.
-        # Both keys also carry the MODEL GROUP, so each model balances and
-        # sticks independently — per-group affinity falls out of the keying
-        # (two models sharing a token prefix never share a session home).
-        members = tuple(ep.replica_idx for ep in eps)
-        group = (self.name, self._uid, self._gen, gsel) + members
-        info: dict = {}
-        # residency-aware routers get the PAIR namespace: a draft-role
-        # group's sticky/residency state keys under its target group, so
-        # the draft and target legs of one prompt share a radix key (the
-        # radix indices hold many members per prefix, and each leg only
-        # picks among its own group's candidates).  Hash-map affinity
-        # routers keep per-group namespaces — one key -> one member there,
-        # and two legs would evict each other's assignment every request.
-        gaff = (self._affinity_alias(gsel)
-                if getattr(router, "uses_residency", False) else gsel)
-        ctx = RouteContext(n_instances=len(eps), group=group,
-                           queue_depths=[ep.depth() for ep in eps],
-                           members=members,
-                           affinity_group=(self.name, self._uid, gaff),
-                           info=info)
-        idx = router.route(env, ctx, cost=cost)
-        eps[idx].bump("cost", cost)
-        if account_affinity:
-            affinity = info.get("affinity")
-            if affinity == "hit":
-                eps[idx].bump("prefix_hits")
-            elif affinity is not None:  # miss or spill: prefix not reused
-                eps[idx].bump("prefix_misses")
-        return eps[idx]
+                if successor is not None:  # name was re-launched; follow it
+                    return successor.route(env, router, cost=cost,
+                                           account_affinity=account_affinity)
+                raise KeyError(f"service {self.name} has no live replicas"
+                               + (f" for model {gsel!r}" if self.multi_model
+                                  else ""))
+            # periodically gossip replica residency summaries to the router
+            # so prefix-aware spill sees fresh caches (stats() also syncs);
+            # the pull runs on a background thread — snapshotting every
+            # engine's index must not add inline latency to the unlucky Nth
+            # request
+            if getattr(router, "uses_residency", False):
+                every = getattr(self.manager.policy, "residency_sync_every",
+                                32)
+                if every and every > 0 and route_count % every == 0:
+                    self._sync_residency_async()
+            # key BALANCE state by generation + candidate MEMBERSHIP, not
+            # just the name: positions in eps shift as replicas
+            # crash/recover, and reusing positional load history across
+            # different subsets (or a recurring subset from before a
+            # membership change) would charge one replica's history to
+            # another.  Sticky state instead keys on the stable (name, uid)
+            # affinity group with stable replica_idx member identities, so
+            # session assignments survive membership churn and only sessions
+            # homed on a departed replica re-home.  Both keys also carry the
+            # MODEL GROUP, so each model balances and sticks independently —
+            # per-group affinity falls out of the keying (two models sharing
+            # a token prefix never share a session home).
+            members = tuple(ep.replica_idx for ep in eps)
+            group = (self.name, self._uid, self._gen, gsel) + members
+            info: dict = {}
+            # residency-aware routers get the PAIR namespace: a draft-role
+            # group's sticky/residency state keys under its target group, so
+            # the draft and target legs of one prompt share a radix key (the
+            # radix indices hold many members per prefix, and each leg only
+            # picks among its own group's candidates).  Hash-map affinity
+            # routers keep per-group namespaces — one key -> one member
+            # there, and two legs would evict each other's assignment every
+            # request.
+            gaff = (self._affinity_alias(gsel)
+                    if getattr(router, "uses_residency", False) else gsel)
+            ctx = RouteContext(n_instances=len(eps), group=group,
+                               queue_depths=[ep.depth() for ep in eps],
+                               members=members,
+                               affinity_group=(self.name, self._uid, gaff),
+                               info=info)
+            idx = router.route(env, ctx, cost=cost)
+            eps[idx].bump("cost", cost)
+            if account_affinity:
+                affinity = info.get("affinity")
+                if affinity == "hit":
+                    eps[idx].bump("prefix_hits")
+                elif affinity is not None:  # miss or spill: prefix not reused
+                    eps[idx].bump("prefix_misses")
+            return eps[idx]
 
     def ready(self) -> bool:
         with self._lock:

@@ -90,9 +90,11 @@ def _paged_decode_kernel(bt_ref, len_ref, *refs, **kw):
 
 
 def _call(kernel, q, caches, scalars, kv_index_map, *, block: int,
-          n_blocks: int, interpret: bool):
+          n_blocks: int, interpret: bool, name: str):
     """Shared pallas_call: q [B, Hkv, G, D] is laid out [B, G, Hkv, D]
-    so each grid step's q/out block is every head of one sequence."""
+    so each grid step's q/out block is every head of one sequence.
+    ``name`` names the kernel's operation in a device trace, where
+    readers find it by that name."""
     B, Hkv, G, D = q.shape
     qt = jnp.swapaxes(q, 1, 2)
     q_spec = pl.BlockSpec((1, G, Hkv, D),
@@ -116,6 +118,7 @@ def _call(kernel, q, caches, scalars, kv_index_map, *, block: int,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct(qt.shape, q.dtype),
         interpret=interpret,
+        name=name,
     )(*scalars, qt, *caches)
     return jnp.swapaxes(out, 1, 2)
 
@@ -133,7 +136,8 @@ def decode_attention_grouped(q, k_cache, v_cache, kv_length, *,
     return _call(_decode_kernel, q, (k_cache, v_cache),
                  (kv_length.astype(jnp.int32),),
                  lambda b, ki, ln: (b, ki, 0, 0),
-                 block=block_k, n_blocks=S // block_k, interpret=interpret)
+                 block=block_k, n_blocks=S // block_k, interpret=interpret,
+                 name="decode_attention")
 
 
 def paged_decode_attention_grouped(q, k_store, v_store, block_tables,
@@ -158,4 +162,4 @@ def paged_decode_attention_grouped(q, k_store, v_store, block_tables,
                   kv_length.astype(jnp.int32)),
                  lambda b, ki, bt, ln: (bt[b, ki], 0, 0, 0),
                  block=k_store.shape[1], n_blocks=block_tables.shape[1],
-                 interpret=interpret)
+                 interpret=interpret, name="paged_decode_attention")

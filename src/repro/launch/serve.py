@@ -37,9 +37,10 @@ group; on first token the sequence's paged KV blocks are exported and
 imported into a decode replica (recompute fallback when its pool is
 full), and per-phase TTFT/ITL p95s are reported per group.
 
-Reports aggregate + per-replica (and per-group) throughput, latency, and
-utilization — the runnable end of the inference-at-scale path the dry-run
-lowers at production shapes.
+Reports aggregate + per-replica (and per-group) throughput, latency, the
+engines' mean decode batch, attended KV per decoded token, mean prefill
+chunk and its padded share — the runnable end of the inference-at-scale
+path the dry-run lowers at production shapes.
 """
 from __future__ import annotations
 
@@ -271,13 +272,26 @@ def main(argv=None) -> int:
         results = [rh.result(u) for u in uids]
         tokens = sum(len(r["tokens"]) + r["n_prompt"] for r in results)
         lat = sorted(r["latency_s"] for r in results)
-        utils = [inst.servicer.stats.utilization
-                 for inst in replica_set.instances]
+        engines = [inst.servicer.stats for inst in replica_set.instances]
+
+        def ratio(num, den, fmt, of=lambda x: x):
+            n, d = (sum(getattr(st, k) for st in engines) for k in (num, den))
+            return format(of(n / d), fmt) if d else "n/a"
+
+        batch = ratio("decode_tokens", "decode_calls", ".1f")
+        kv = ratio("decode_kv_positions", "decode_tokens", ".0f")
+        # paged engines count real prefill tokens and the padded buckets
+        # that ran; slot-pool engines count no chunks
+        chunk = ratio("prefill_padded_tokens", "prefill_chunks", ".0f")
+        pad = ratio("prefill_tokens", "prefill_padded_tokens", ".2f",
+                    lambda real: 1 - real)
         print(f"[serve] {len(results)} requests, {dt:.2f}s, "
               f"{tokens / dt:.0f} tok/s, routing={args.routing}")
         print(f"[serve] latency p50 {lat[len(lat) // 2]:.2f}s "
               f"p95 {lat[int(len(lat) * 0.95)]:.2f}s; "
-              f"mean slot-utilization {np.mean(utils):.2f}")
+              f"mean decode batch {batch}, attended KV per decoded token "
+              f"{kv}; mean prefill chunk {chunk} tokens, padded share "
+              f"{pad}")
         print("[serve] per-replica requests:",
               [p["requests"] for p in stats["per_replica"]])
         btel = {g: s.get("block_telemetry")

@@ -6,6 +6,7 @@ import time
 from typing import Any, Optional
 
 from repro.core.service import ModelGroup
+from repro.core.tracing import span
 from repro.models.config import ModelConfig
 from .engine import (InferenceEngine, SpecDecodeSession,
                      make_engine_from_scratch)
@@ -222,15 +223,16 @@ class LLMServicer:
             if not out:
                 time.sleep(1e-4)
             return out
-        if self.phase == "prefill":
-            return out + self._step_prefill()
-        if self._qos is not None:
-            self._qos.schedule(self.engine)
-        self._driver.step()
-        for req in self._driver.collect_finished():
+        with span("servicer.step"):
+            if self.phase == "prefill":
+                return out + self._step_prefill()
             if self._qos is not None:
-                self._qos.on_finish(req.uid)
-            out.append((req.uid, self._result(req)))
+                self._qos.schedule(self.engine)
+            self._driver.step()
+            for req in self._driver.collect_finished():
+                if self._qos is not None:
+                    self._qos.on_finish(req.uid)
+                out.append((req.uid, self._result(req)))
         return out
 
     def _step_prefill(self):

@@ -58,9 +58,13 @@ underflow to exact zeros), and both the gathered block view and the
 direct path's table-gathered read are bit-identical to a contiguous
 slot cache (masked columns underflow to exact zeros in the softmax).
 
-Telemetry (per-step active slots, tokens, queue depth, live
-free/reserved block gauges) feeds the paper's utilization/throughput
-experiments and the replica set's headroom-aware routing.
+Telemetry (``EngineStats``: tokens, decode calls and the KV positions
+they attend, prefill chunks and their padded tokens, live free/reserved
+block gauges) feeds the paper's throughput experiments and the replica
+set's headroom-aware routing.  The paged step's phases run under
+profiler spans (``engine.admit``, ``engine.prefill``, ``engine.decode``,
+``engine.collect``, and within them ``engine.prepare``,
+``engine.dispatch`` and ``engine.sample``; see ``repro.core.tracing``).
 
 Speculative decoding (``SpecDecodeSession``) couples TWO engines into a
 propose/verify/rewind cycle — the first cross-group *pipeline* (cheap
@@ -86,7 +90,6 @@ plain target-engine stepping — the same graceful-off signal the
 from __future__ import annotations
 
 import dataclasses
-import functools
 import itertools
 import time
 from collections import OrderedDict
@@ -97,6 +100,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.core.prefix import RadixIndex
+from repro.core.tracing import span
 from repro.models import ModelApi, get_model
 from repro.models.config import ModelConfig
 from .kvcache import (CachePool, PagedCachePool, extract_blocks,
@@ -128,9 +132,10 @@ class Request:
     cached_prefix: int = 0  # prompt tokens whose prefill was skipped
     truncated: bool = False  # prompt exceeded max_len/bucket at prefill:
     #                          the cache does not cover the full prompt
+    pos: int = 0  # cache positions holding valid KV (the slot pool's
+    #               count may run past max_len, where its writes clamp)
     # paged engine state
     table: list = dataclasses.field(default_factory=list)  # physical blocks
-    pos: int = 0  # cache positions holding valid KV
     pending_tokens: list = dataclasses.field(default_factory=list)  # unfed
     reserve_left: int = 0  # admission-reserved blocks not yet allocated
     last_token: Optional[int] = None  # next decode feed
@@ -164,8 +169,16 @@ class EngineStats:
     steps: int = 0
     decode_tokens: int = 0
     prefill_tokens: int = 0
-    active_slot_steps: int = 0
-    slot_steps: int = 0
+    # the jitted steps' work, counted where each call is made: calls of
+    # the decode program (a speculative session's draft proposals too)
+    # and the KV positions they attend (each real row's length + 1, the
+    # new token's own, up to the pool's positions per sequence); prompt
+    # chunks of the paged pool and the padded bucket tokens they ran (a
+    # speculative verify is no prompt chunk: the session counts rounds)
+    decode_calls: int = 0
+    decode_kv_positions: int = 0
+    prefill_chunks: int = 0
+    prefill_padded_tokens: int = 0
     prefix_reuse_hits: int = 0  # admissions that resumed a resident slot
     prefix_partial_hits: int = 0  # resumes that rewound PAST a divergence
     #                               (resident sequence != prompt prefix)
@@ -185,16 +198,6 @@ class EngineStats:
     # and the autoscaler were missing when only peaks were reported
     free_blocks: int = 0
     reserved_blocks: int = 0
-    started: float = dataclasses.field(default_factory=time.perf_counter)
-
-    @property
-    def utilization(self) -> float:
-        return self.active_slot_steps / max(1, self.slot_steps)
-
-    @property
-    def tokens_per_s(self) -> float:
-        dt = time.perf_counter() - self.started
-        return (self.decode_tokens + self.prefill_tokens) / max(1e-9, dt)
 
 
 def paged_extend_step(api: ModelApi, cfg: ModelConfig, params, store, bt,
@@ -306,17 +309,25 @@ class InferenceEngine:
                     f"paged_decode_mode must be 'direct' or 'gather', "
                     f"not {paged_decode_mode!r}")
             self.paged_decode_mode = paged_decode_mode
-            paged_extend_fn = functools.partial(paged_extend_step, api, cfg,
-                                                mesh=mesh)
+
+            # named functions, so that the compiled programs carry the
+            # names ``jit_paged_extend`` and ``jit_paged_decode`` in a
+            # device trace (a jitted ``functools.partial`` is anonymous)
+            def paged_extend(params, store, bt, lens, tokens, wphys, woff):
+                return paged_extend_step(api, cfg, params, store, bt, lens,
+                                         tokens, wphys, woff, mesh=mesh)
 
             if paged_decode_mode == "direct":
-                paged_decode_fn = functools.partial(paged_decode_step, api,
-                                                    cfg, mesh=mesh)
+                def paged_decode(params, store, bt, lens, tokens, wphys,
+                                 woff):
+                    return paged_decode_step(api, cfg, params, store, bt,
+                                             lens, tokens, wphys, woff,
+                                             mesh=mesh)
             else:
                 # legacy A/B path: reassemble a contiguous [B, Smax] view,
                 # run the slot-pool decode, scatter the one new row back
-                def paged_decode_fn(params, store, bt, lens, tokens, wphys,
-                                    woff):
+                def paged_decode(params, store, bt, lens, tokens, wphys,
+                                 woff):
                     view = gather_block_view(store, bt, lens)
                     view, logits = api.decode(params, view, tokens, cfg,
                                               mesh=mesh)
@@ -324,8 +335,8 @@ class InferenceEngine:
                                                  woff[:, None], lens[:, None])
                     return store, logits
 
-            self._paged_extend = jax.jit(paged_extend_fn, donate_argnums=(1,))
-            self._paged_decode = jax.jit(paged_decode_fn, donate_argnums=(1,))
+            self._paged_extend = jax.jit(paged_extend, donate_argnums=(1,))
+            self._paged_decode = jax.jit(paged_decode, donate_argnums=(1,))
             self.stats.free_blocks = self.pool.n_free
             return
 
@@ -368,8 +379,6 @@ class InferenceEngine:
         if self.running:
             events = self._decode_step()
         self.stats.steps += 1
-        self.stats.active_slot_steps += len(self.running)
-        self.stats.slot_steps += self.max_num_seqs
         return events
 
     def collect_finished(self) -> list:
@@ -426,8 +435,6 @@ class InferenceEngine:
                                       len(self.running))
         self._prefill_step_paged()
         self.stats.steps += 1
-        self.stats.active_slot_steps += len(self.running)
-        self.stats.slot_steps += max(self.max_num_seqs, len(self.running))
         self.stats.shared_block_peak = max(self.stats.shared_block_peak,
                                            self.pool.block_savings())
         self.stats.free_blocks = self.pool.n_free
@@ -608,6 +615,7 @@ class InferenceEngine:
             else:
                 tok = int(jnp.argmax(logits_last))
             req.slot = slot
+            req.pos = n
             req.output.append(tok)
             req.first_token_at = time.perf_counter()
             self._last_tokens = self._last_tokens.at[slot].set(tok)
@@ -685,6 +693,7 @@ class InferenceEngine:
             req.pending_prefix = list(req.prompt[covered + 1:])
             req.cached_prefix = covered
             req.slot = slot
+            req.pos = covered
             self.running[slot] = req
             self.stats.prefix_reuse_hits += 1
             if d < L and d < m:  # the resident transcript and the prompt
@@ -700,6 +709,10 @@ class InferenceEngine:
     def _decode_step(self):
         self.pool.cache, logits = self._decode(
             self.params, self.pool.cache, self._last_tokens)
+        live = [req for req in self.running.values() if not req.done]
+        self._count_decode([req.pos for req in live])
+        for req in live:
+            req.pos += 1
         temps = np.zeros((self.max_num_seqs,), np.float32)
         for slot, req in self.running.items():
             temps[slot] = req.temperature
@@ -742,6 +755,18 @@ class InferenceEngine:
         self._last_tokens = jnp.asarray(tokens_np) if has_pending else tokens
         return events
 
+    def _count_decode(self, lens) -> int:
+        """Count one call of the decode program whose real rows hold
+        ``lens`` cache positions: each row attends those and its new
+        token's own, up to the pool's positions per sequence, where writes
+        clamp.  Returns the KV positions counted."""
+        cap = (self.pool.max_blocks * self.block_size if self.paged
+               else self.max_len)
+        kv = int(np.minimum(np.asarray(lens) + 1, cap).sum())
+        self.stats.decode_calls += 1
+        self.stats.decode_kv_positions += kv
+        return kv
+
     def _check_done(self, req: Request):
         if req.done:
             return
@@ -760,8 +785,6 @@ class InferenceEngine:
         self._prefill_step_paged()
         events = self._decode_step_paged()
         self.stats.steps += 1
-        self.stats.active_slot_steps += len(self.running)
-        self.stats.slot_steps += max(self.max_num_seqs, len(self.running))
         self.stats.shared_block_peak = max(self.stats.shared_block_peak,
                                            self.pool.block_savings())
         self.stats.free_blocks = self.pool.n_free
@@ -800,26 +823,29 @@ class InferenceEngine:
         return True
 
     def _admit_paged(self):
-        while self.queue and len(self.running) < self.max_running:
-            req = self.queue[0]
-            if req.output:  # preempted mid-generation: dedicated resume
-                if not self._readmit_preempted(req):
+        with span("engine.admit") as sp:
+            queued = len(self.queue)
+            while self.queue and len(self.running) < self.max_running:
+                req = self.queue[0]
+                if req.output:  # preempted mid-generation: dedicated resume
+                    if not self._readmit_preempted(req):
+                        break
+                    self.queue.pop(0)
+                    continue
+                if self._prefix_reuse and self._try_resume_paged(req):
+                    self.queue.pop(0)
+                    continue
+                m = min(req.n_prompt, self.max_len - 1)
+                need = self._blocks_needed(m + req.max_new_tokens, 0)
+                if not self._reserve(need):
                     break
                 self.queue.pop(0)
-                continue
-            if self._prefix_reuse and self._try_resume_paged(req):
-                self.queue.pop(0)
-                continue
-            m = min(req.n_prompt, self.max_len - 1)
-            need = self._blocks_needed(m + req.max_new_tokens, 0)
-            if not self._reserve(need):
-                break
-            self.queue.pop(0)
-            req.truncated = m < req.n_prompt
-            req.pending_tokens = list(req.prompt[-m:])
-            req.reserve_left = need
-            self.running[req.uid] = req
-            self._prefill_order.append(req)
+                req.truncated = m < req.n_prompt
+                req.pending_tokens = list(req.prompt[-m:])
+                req.reserve_left = need
+                self.running[req.uid] = req
+                self._prefill_order.append(req)
+            sp.set_metadata(admitted=queued - len(self.queue))
 
     def _try_resume_paged(self, req: Request) -> bool:
         """Prefix resume by block sharing: fork (refcount++) the resident
@@ -1035,56 +1061,67 @@ class InferenceEngine:
         decode.  Each chunk therefore picks the largest bucket that still
         fits the remaining budget (the smallest chunk bucket always fits a
         fresh budget, so prefill never stalls)."""
-        budget = self.max_num_batched_tokens
-        mb = self.pool.max_blocks
-        bs = self.block_size
-        for req in list(self._prefill_order):
-            if req.done or not req.pending_tokens:
-                self._prefill_order.remove(req)
-                continue
-            fitting = [b for b in self._chunk_buckets if b <= budget]
-            if not fitting:
-                break
-            T = min(len(req.pending_tokens), self.prefill_chunk, fitting[-1])
-            bucket = _bucket(T, self._chunk_buckets)
-            T = min(T, bucket)
-            self._ensure_writable(req, req.pos, T)
-            bt = np.zeros((1, mb), np.int32)
-            bt[0, :len(req.table)] = req.table
-            tokens = np.zeros((1, bucket), np.int32)
-            tokens[0, :T] = req.pending_tokens[:T]
-            # padded chunk positions scatter into the null block
-            wphys = np.zeros((1, bucket), np.int32)
-            woff = np.zeros((1, bucket), np.int32)
-            for t in range(T):
-                p = req.pos + t
-                wphys[0, t] = req.table[p // bs]
-                woff[0, t] = p % bs
-            self.pool.cache, logits = self._paged_extend(
-                self.params, self.pool.cache, jnp.asarray(bt),
-                jnp.asarray([req.pos], jnp.int32), jnp.asarray(tokens),
-                jnp.asarray(wphys), jnp.asarray(woff))
-            req.pending_tokens = req.pending_tokens[T:]
-            req.pos += T
-            budget -= bucket  # charge the padded size that actually ran
-            self.stats.prefill_tokens += T
-            if not req.pending_tokens:  # prompt complete: first token
-                self._prefill_order.remove(req)
-                logits_last = logits[0, T - 1]
-                if req.temperature > 0:
-                    self._key, sub = jax.random.split(self._key)
-                    tok = int(sample(logits_last[None, :], sub,
-                                     temperature=req.temperature)[0])
-                else:
-                    tok = int(jnp.argmax(logits_last))
-                req.output.append(tok)
-                req.last_token = tok
-                if req.first_token_at is None:
-                    # preempted-and-resumed sequences re-run this path
-                    # (their catch-up "prompt" ends mid-generation); TTFT
-                    # must keep the ORIGINAL first-token stamp
-                    req.first_token_at = time.perf_counter()
-                self._check_done(req)
+        with span("engine.prefill") as sp:
+            budget = self.max_num_batched_tokens
+            mb = self.pool.max_blocks
+            bs = self.block_size
+            chunks = 0
+            for req in list(self._prefill_order):
+                if req.done or not req.pending_tokens:
+                    self._prefill_order.remove(req)
+                    continue
+                fitting = [b for b in self._chunk_buckets if b <= budget]
+                if not fitting:
+                    break
+                T = min(len(req.pending_tokens), self.prefill_chunk,
+                        fitting[-1])
+                bucket = _bucket(T, self._chunk_buckets)
+                T = min(T, bucket)
+                with span("engine.prepare"):
+                    self._ensure_writable(req, req.pos, T)
+                    bt = np.zeros((1, mb), np.int32)
+                    bt[0, :len(req.table)] = req.table
+                    tokens = np.zeros((1, bucket), np.int32)
+                    tokens[0, :T] = req.pending_tokens[:T]
+                    # padded chunk positions scatter into the null block
+                    wphys = np.zeros((1, bucket), np.int32)
+                    woff = np.zeros((1, bucket), np.int32)
+                    for t in range(T):
+                        p = req.pos + t
+                        wphys[0, t] = req.table[p // bs]
+                        woff[0, t] = p % bs
+                with span("engine.dispatch", program="extend", uid=req.uid,
+                          bucket=bucket, kv=req.pos + T):
+                    self.pool.cache, logits = self._paged_extend(
+                        self.params, self.pool.cache, jnp.asarray(bt),
+                        jnp.asarray([req.pos], jnp.int32), jnp.asarray(tokens),
+                        jnp.asarray(wphys), jnp.asarray(woff))
+                chunks += 1
+                self.stats.prefill_chunks += 1
+                self.stats.prefill_padded_tokens += bucket
+                req.pending_tokens = req.pending_tokens[T:]
+                req.pos += T
+                budget -= bucket  # charge the padded size that actually ran
+                self.stats.prefill_tokens += T
+                if not req.pending_tokens:  # prompt complete: first token
+                    self._prefill_order.remove(req)
+                    with span("engine.sample"):
+                        logits_last = logits[0, T - 1]
+                        if req.temperature > 0:
+                            self._key, sub = jax.random.split(self._key)
+                            tok = int(sample(logits_last[None, :], sub,
+                                             temperature=req.temperature)[0])
+                        else:
+                            tok = int(jnp.argmax(logits_last))
+                    req.output.append(tok)
+                    req.last_token = tok
+                    if req.first_token_at is None:
+                        # preempted-and-resumed sequences re-run this path
+                        # (their catch-up "prompt" ends mid-generation); TTFT
+                        # must keep the ORIGINAL first-token stamp
+                        req.first_token_at = time.perf_counter()
+                    self._check_done(req)
+            sp.set_metadata(chunks=chunks)
 
     def _decode_step_paged(self) -> list:
         """One batched decode over every sequence past prefill.  The
@@ -1095,53 +1132,58 @@ class InferenceEngine:
                   if not r.pending_tokens and not r.done and r.output]
         if not active:
             return []
-        for r in active:
-            self._ensure_writable(r, r.pos, 1)
-        B = 1
-        while B < len(active):
-            B *= 2
-        mb = self.pool.max_blocks
-        bs = self.block_size
-        bt = np.zeros((B, mb), np.int32)
-        lens = np.zeros((B,), np.int32)
-        tokens = np.zeros((B,), np.int32)
-        # padding rows write to the null block's cell (0, 0); duplicate
-        # writes there are harmless because masked positions are never
-        # attended
-        wphys = np.zeros((B,), np.int32)
-        woff = np.zeros((B,), np.int32)
-        temps = np.zeros((B,), np.float32)
-        for i, r in enumerate(active):
-            bt[i, :len(r.table)] = r.table
-            lens[i] = r.pos
-            tokens[i] = r.last_token
-            p = min(r.pos, mb * bs - 1)  # clamp like the slot pool
-            wphys[i] = r.table[p // bs]
-            woff[i] = p % bs
-            temps[i] = r.temperature
-        self.pool.cache, logits = self._paged_decode(
-            self.params, self.pool.cache, jnp.asarray(bt),
-            jnp.asarray(lens), jnp.asarray(tokens), jnp.asarray(wphys),
-            jnp.asarray(woff))
-        # all-greedy batches skip the sampled path and the key split (the
-        # same fast path as the slot pool's _decode_step)
-        greedy = jnp.argmax(logits, axis=-1).astype(jnp.int32)
-        if np.any(temps > 0):
-            self._key, sub = jax.random.split(self._key)
-            sampled = sample(logits, sub, temperature=1.0)
-            toks = np.asarray(jnp.where(jnp.asarray(temps) > 0, sampled,
-                                        greedy))
-        else:
-            toks = np.asarray(greedy)
-        events = []
-        for i, r in enumerate(active):
-            tok = int(toks[i])
-            r.output.append(tok)
-            r.last_token = tok
-            r.pos += 1
-            events.append((r.uid, tok))
-            self.stats.decode_tokens += 1
-            self._check_done(r)
+        with span("engine.decode", batch=len(active)):
+            with span("engine.prepare"):
+                for r in active:
+                    self._ensure_writable(r, r.pos, 1)
+                B = 1
+                while B < len(active):
+                    B *= 2
+                mb = self.pool.max_blocks
+                bs = self.block_size
+                bt = np.zeros((B, mb), np.int32)
+                lens = np.zeros((B,), np.int32)
+                tokens = np.zeros((B,), np.int32)
+                # padding rows write to the null block's cell (0, 0); duplicate
+                # writes there are harmless because masked positions are never
+                # attended
+                wphys = np.zeros((B,), np.int32)
+                woff = np.zeros((B,), np.int32)
+                temps = np.zeros((B,), np.float32)
+                for i, r in enumerate(active):
+                    bt[i, :len(r.table)] = r.table
+                    lens[i] = r.pos
+                    tokens[i] = r.last_token
+                    p = min(r.pos, mb * bs - 1)  # clamp like the slot pool
+                    wphys[i] = r.table[p // bs]
+                    woff[i] = p % bs
+                    temps[i] = r.temperature
+                kv = self._count_decode(lens[:len(active)])
+            with span("engine.dispatch", program="decode", batch=B, kv=kv):
+                self.pool.cache, logits = self._paged_decode(
+                    self.params, self.pool.cache, jnp.asarray(bt),
+                    jnp.asarray(lens), jnp.asarray(tokens), jnp.asarray(wphys),
+                    jnp.asarray(woff))
+            with span("engine.sample"):
+                # all-greedy batches skip the sampled path and the key split
+                # (the same fast path as the slot pool's _decode_step)
+                greedy = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+                if np.any(temps > 0):
+                    self._key, sub = jax.random.split(self._key)
+                    sampled = sample(logits, sub, temperature=1.0)
+                    toks = np.asarray(jnp.where(jnp.asarray(temps) > 0,
+                                                sampled, greedy))
+                else:
+                    toks = np.asarray(greedy)
+            events = []
+            for i, r in enumerate(active):
+                tok = int(toks[i])
+                r.output.append(tok)
+                r.last_token = tok
+                r.pos += 1
+                events.append((r.uid, tok))
+                self.stats.decode_tokens += 1
+                self._check_done(r)
         return events
 
     def _collect_finished_paged(self) -> list:
@@ -1149,30 +1191,32 @@ class InferenceEngine:
         table transfers to a residency entry (no refcount change — the
         references move, they are not duplicated), so the blocks stay
         shareable until block-granular eviction reclaims them."""
-        done = []
-        for uid, req in list(self.running.items()):
-            if not req.done:
-                continue
-            del self.running[uid]
-            if req in self._prefill_order:
-                self._prefill_order.remove(req)
-            self._reserved -= req.reserve_left
-            req.reserve_left = 0
-            if self._prefix_reuse and not req.truncated and req.table:
-                seq = tuple(req.prompt) + tuple(req.output)
-                res_id = next(self._res_counter)
-                self._residency[res_id] = _Residency(tuple(req.table),
-                                                     len(seq))
-                for b in req.table:
-                    self._res_holds[b] = self._res_holds.get(b, 0) + 1
-                self._prefix_index.insert(seq, res_id)
-            else:
-                for b in req.table:
-                    self.pool.alloc.free(b)
-            req.table = []
-            done.append(req)
-        self.stats.free_blocks = self.pool.n_free
-        self.stats.reserved_blocks = self._reserved
+        with span("engine.collect") as sp:
+            done = []
+            for uid, req in list(self.running.items()):
+                if not req.done:
+                    continue
+                del self.running[uid]
+                if req in self._prefill_order:
+                    self._prefill_order.remove(req)
+                self._reserved -= req.reserve_left
+                req.reserve_left = 0
+                if self._prefix_reuse and not req.truncated and req.table:
+                    seq = tuple(req.prompt) + tuple(req.output)
+                    res_id = next(self._res_counter)
+                    self._residency[res_id] = _Residency(tuple(req.table),
+                                                         len(seq))
+                    for b in req.table:
+                        self._res_holds[b] = self._res_holds.get(b, 0) + 1
+                    self._prefix_index.insert(seq, res_id)
+                else:
+                    for b in req.table:
+                        self.pool.alloc.free(b)
+                req.table = []
+                done.append(req)
+            self.stats.free_blocks = self.pool.n_free
+            self.stats.reserved_blocks = self._reserved
+            sp.set_metadata(finished=len(done))
         return done
 
 
@@ -1336,8 +1380,6 @@ class SpecDecodeSession:
                   if s.ready and not s.treq.done]
         events = self._spec_round(active) if active else []
         t.stats.steps += 1
-        t.stats.active_slot_steps += len(t.running)
-        t.stats.slot_steps += max(t.max_num_seqs, len(t.running))
         if t.paged:
             t.stats.shared_block_peak = max(t.stats.shared_block_peak,
                                             t.pool.block_savings())
@@ -1526,6 +1568,9 @@ class SpecDecodeSession:
                     cov = min(n, _bucket(n, eng.buckets))
             updates[slot] = cov
         eng.pool.set_lens(updates)
+        for slot, n in updates.items():  # the decode counter reads pos
+            if slot in eng.running:
+                eng.running[slot].pos = n
 
     # ------------------------------------------------------------------
     # The propose / verify / rewind round
@@ -1617,6 +1662,9 @@ class SpecDecodeSession:
                 feeds[s.dreq.slot] = f
             eng.pool.cache, logits = eng._decode(
                 eng.params, eng.pool.cache, jnp.asarray(feeds))
+            eng._count_decode([s.dreq.pos for s in active])
+            for s in active:
+                s.dreq.pos += 1
             gtok = np.asarray(jnp.argmax(logits, axis=-1).astype(jnp.int32))
             return [int(gtok[s.dreq.slot]) for s in active]
         B = 1
@@ -1640,6 +1688,7 @@ class SpecDecodeSession:
         eng.pool.cache, logits = eng._paged_decode(
             eng.params, eng.pool.cache, jnp.asarray(bt), jnp.asarray(lens),
             jnp.asarray(tokens), jnp.asarray(wphys), jnp.asarray(woff))
+        eng._count_decode(lens[:len(active)])
         for s in active:
             s.dreq.pos += 1
         gtok = np.asarray(jnp.argmax(logits, axis=-1).astype(jnp.int32))

@@ -61,7 +61,7 @@ def test_inference_at_scale_roundtrip():
         outs = [f.result(timeout=300) for f in futs]
         assert all(len(o["tokens"]) == 3 for o in outs)
         inst = rh.services.instances["llm"]
-        assert inst.servicer.stats.utilization > 0
+        assert inst.servicer.stats.decode_calls > 0
     finally:
         rh.close()
 
