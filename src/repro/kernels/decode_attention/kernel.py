@@ -18,13 +18,15 @@ Two variants share the online-softmax body:
 
 * ``decode_attention_grouped`` — contiguous caches ``[B, S, Hkv, D]``
   (the slot-pool layout); the KV block index IS the grid index.
-* ``paged_decode_attention_grouped`` — block-paged stores
-  ``[num_blocks, block_size, Hkv, D]`` plus per-sequence block tables:
-  the tables ride in scalar-prefetch SMEM (``PrefetchScalarGridSpec``)
-  so each grid step's BlockSpec index map dereferences ``table[b, ki]``
-  and the DMA engine fetches the right *physical* block — the gather
-  costs no extra copy.  Logical blocks at or past a sequence's length
-  are predicated off (their table entries point at the null block 0).
+* ``paged_decode_attention_grouped`` — block-paged stores stacked over
+  layers, ``[L, num_blocks, block_size, Hkv, D]``, plus a layer index
+  and per-sequence block tables: the index and the tables ride in
+  scalar-prefetch SMEM (``PrefetchScalarGridSpec``) so each grid step's
+  BlockSpec index map returns ``(layer, table[b, ki])`` and the DMA
+  engine fetches the right *physical* block of the right layer straight
+  from the stack — neither the layer slice nor the gather costs a copy.
+  Logical blocks at or past a sequence's length are predicated off
+  (their table entries point at the null block 0).
 
 Both take the lengths in scalar prefetch as well.
 """
@@ -84,8 +86,8 @@ def _decode_kernel(len_ref, *refs, **kw):
     _decode_body(len_ref[pl.program_id(0)], *refs, **kw)
 
 
-def _paged_decode_kernel(bt_ref, len_ref, *refs, **kw):
-    del bt_ref  # consumed by the index maps
+def _paged_decode_kernel(layer_ref, bt_ref, len_ref, *refs, **kw):
+    del layer_ref, bt_ref  # consumed by the index maps
     _decode_body(len_ref[pl.program_id(0)], *refs, **kw)
 
 
@@ -93,13 +95,16 @@ def _call(kernel, q, caches, scalars, kv_index_map, *, block: int,
           n_blocks: int, interpret: bool, name: str):
     """Shared pallas_call: q [B, Hkv, G, D] is laid out [B, G, Hkv, D]
     so each grid step's q/out block is every head of one sequence.
-    ``name`` names the kernel's operation in a device trace, where
-    readers find it by that name."""
+    Cache dimensions before the last four (a stacked store's layer) are
+    squeezed out of the K/V blocks, so the body sees
+    ``[1, block, Hkv, D]`` either way.  ``name`` names the kernel's
+    operation in a device trace, where readers find it by that name."""
     B, Hkv, G, D = q.shape
     qt = jnp.swapaxes(q, 1, 2)
     q_spec = pl.BlockSpec((1, G, Hkv, D),
                           lambda b, ki, *_: (b, 0, 0, 0))
-    kv_spec = pl.BlockSpec((1, block, Hkv, D), kv_index_map)
+    lead = (None,) * (caches[0].ndim - 4)
+    kv_spec = pl.BlockSpec(lead + (1, block, Hkv, D), kv_index_map)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=len(scalars),
         grid=(B, n_blocks),
@@ -140,26 +145,31 @@ def decode_attention_grouped(q, k_cache, v_cache, kv_length, *,
                  name="decode_attention")
 
 
-def paged_decode_attention_grouped(q, k_store, v_store, block_tables,
+def paged_decode_attention_grouped(q, k_store, v_store, layer, block_tables,
                                    kv_length, *, interpret: bool = False):
-    """Paged flash-decode through block-table indirection.
+    """Paged flash-decode of one layer of a stacked store, through
+    block-table indirection.
 
-    q: [B, Hkv, G, D]; stores: [num_blocks, block_size, Hkv, D];
-    block_tables: [B, max_blocks] int32 physical block ids (entries at or
-    past ceil(kv_length/block_size) must point at a valid — conventionally
+    q: [B, Hkv, G, D]; stores: [L, num_blocks, block_size, Hkv, D];
+    layer: int32 scalar, the layer read; block_tables: [B, max_blocks]
+    int32 physical block ids (entries at or past
+    ceil(kv_length/block_size) must point at a valid — conventionally
     the null — block; they are compute-predicated off); kv_length: [B].
     Returns [B, Hkv, G, D].
 
-    The tables/lengths are scalar-prefetched: the k/v BlockSpec index maps
-    receive them AFTER the grid indices and return
-    ``(table[b, ki], 0, 0, 0)``, so the physical block is resolved at DMA
-    issue time — the paged gather is free relative to the contiguous
-    kernel, which is the point of paging on a machine that cannot
-    reallocate buffers dynamically.
+    The layer, tables and lengths are scalar-prefetched: the k/v
+    BlockSpec index maps receive them AFTER the grid indices and return
+    ``(layer, table[b, ki], 0, 0, 0)``, so the layer and the physical
+    block are resolved at DMA issue time.  The kernel reads the stack
+    where it lies: a caller that carries the whole store through its
+    layer loop never slices a layer out, and the paged gather is free
+    relative to the contiguous kernel, which is the point of paging on a
+    machine that cannot reallocate buffers dynamically.
     """
     return _call(_paged_decode_kernel, q, (k_store, v_store),
-                 (block_tables.astype(jnp.int32),
+                 (jnp.reshape(layer, (1,)).astype(jnp.int32),
+                  block_tables.astype(jnp.int32),
                   kv_length.astype(jnp.int32)),
-                 lambda b, ki, bt, ln: (bt[b, ki], 0, 0, 0),
-                 block=k_store.shape[1], n_blocks=block_tables.shape[1],
+                 lambda b, ki, ly, bt, ln: (ly[0], bt[b, ki], 0, 0, 0),
+                 block=k_store.shape[2], n_blocks=block_tables.shape[1],
                  interpret=interpret, name="paged_decode_attention")

@@ -346,26 +346,33 @@ def attention_extend(p, x, cache_k, cache_v, kv_length, cfg: ModelConfig):
     return nn.linear_apply(p["o"], out, cfg.cdtype), cache_k, cache_v, new_len
 
 
-def attention_decode_paged(p, x, k_store, v_store, block_tables, kv_length,
-                           write_phys, write_off, cfg: ModelConfig):
+def attention_decode_paged(p, x, k_store, v_store, layer, block_tables,
+                           kv_length, write_phys, write_off,
+                           cfg: ModelConfig):
     """Single-token decode directly against a block-paged KV store.
 
-    x: [B,1,d]; k_store/v_store: [num_blocks, block_size, Hkv, D] physical
-    stores shared by every sequence; block_tables: [B, max_blocks] int32
-    physical block ids per sequence; kv_length: [B] valid positions
-    *before* this token; write_phys/write_off: [B] the (physical block,
-    in-block offset) cell where this token's K/V lands (padded batch rows
-    point at the null block (0, 0), where collisions are harmless).
+    x: [B,1,d]; k_store/v_store: [L, num_blocks, block_size, Hkv, D]
+    physical stores of every layer, shared by every sequence; layer:
+    int32 scalar, the layer this call reads and writes; block_tables:
+    [B, max_blocks] int32 physical block ids per sequence; kv_length: [B]
+    valid positions *before* this token; write_phys/write_off: [B] the
+    (physical block, in-block offset) cell where this token's K/V lands
+    (padded batch rows point at the null block (0, 0), where collisions
+    are harmless).
 
     Unlike ``attention_decode`` this never materializes a contiguous
-    [B, Smax] cache view: the new K/V row is written into ONLY its tail
-    block, and attention reads K/V through the block table.  On a TPU
-    that is the scalar-prefetch Pallas kernel (``paged_decode_attention``),
-    so the gather happens at DMA issue time and per-token HBM traffic is
-    O(blocks-touched) instead of O(Smax).  On the CPU it gathers through
-    the table in jnp (the ``paged_decode_ref`` oracle shape) and reuses
-    ``decode_attention``, so greedy outputs are bit-identical to the slot
-    path; ``attention_impl="pallas"`` runs the kernel there instead, in
+    [B, Smax] cache view, and never slices the layer out of the stack:
+    the new K/V row is scattered into ONLY its cell of the stack, which
+    a caller that carries the stack through its layer loop updates in
+    place, and attention reads K/V through the block table.  On a TPU
+    that is the scalar-prefetch Pallas kernel
+    (``paged_decode_attention``), which takes the stack and the layer
+    index, so the layer and the gather are resolved at DMA issue time
+    and per-token HBM traffic is O(blocks-touched) instead of O(Smax).
+    On the CPU it gathers through the table in jnp (the
+    ``paged_decode_ref`` oracle shape) and reuses ``decode_attention``,
+    so greedy outputs are bit-identical to the slot path;
+    ``attention_impl="pallas"`` runs the kernel there instead, in
     interpret mode.
 
     Returns (out [B,1,d], k_store, v_store).
@@ -374,23 +381,24 @@ def attention_decode_paged(p, x, k_store, v_store, block_tables, kv_length,
     pos = kv_length[:, None]  # [B,1] this token's absolute position
     q, k_new, v_new = _project_qkv(p, x, x, cfg, pos, pos,
                                    rope=cfg.positions == "rope")
-    k_store = k_store.at[write_phys, write_off].set(
+    k_store = k_store.at[layer, write_phys, write_off].set(
         k_new[:, 0].astype(k_store.dtype))
-    v_store = v_store.at[write_phys, write_off].set(
+    v_store = v_store.at[layer, write_phys, write_off].set(
         v_new[:, 0].astype(v_store.dtype))
     new_len = kv_length + 1
     interpret = kernels.pallas_interpret()
     if cfg.attention_impl == "pallas" or not interpret:
         from repro.kernels.decode_attention import ops as da_ops
 
-        out = da_ops.paged_decode_attention(q, k_store, v_store,
+        out = da_ops.paged_decode_attention(q, k_store, v_store, layer,
                                             block_tables, new_len,
                                             interpret=interpret)
     else:
         from repro.kernels.decode_attention.ref import gather_kv
 
-        out = decode_attention(q, gather_kv(k_store, block_tables),
-                               gather_kv(v_store, block_tables), new_len)
+        out = decode_attention(q, gather_kv(k_store[layer], block_tables),
+                               gather_kv(v_store[layer], block_tables),
+                               new_len)
     out = out.reshape(B, 1, cfg.padded_heads * cfg.head_dim)
     return nn.linear_apply(p["o"], out, cfg.cdtype), k_store, v_store
 

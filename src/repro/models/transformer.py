@@ -160,23 +160,26 @@ def block_decode(p, x, cache, cfg: ModelConfig, *, mesh=None):
     return y, cache
 
 
-def block_decode_paged(p, x, cache, block_tables, lens, write_phys,
+def block_decode_paged(p, x, store, layer, block_tables, lens, write_phys,
                        write_off, cfg: ModelConfig, *, mesh=None):
-    """Single-token decode against one layer's paged K/V store leaves.
+    """Single-token decode of layer ``layer`` against the stacked paged
+    K/V store.
 
-    ``cache`` is the layer's slice of the paged store tree ({"k", "v",
-    "len"} with block-paged k/v of shape [num_blocks, block_size, Hkv, D]);
-    the "len" leaf is a template artifact — lengths live host-side in the
-    engine and arrive as ``lens`` — so it passes through untouched.  Only
-    dense/moe stacks run paged, so there is no cross-attention branch."""
+    ``store`` holds the block-paged k/v of every layer, shape [L,
+    num_blocks, block_size, Hkv, D]; this layer's token K/V is written
+    into it and attention reads the layer from it, so the store is
+    returned whole (any other leaf, such as the template's "len",
+    passes through untouched: lengths live host-side in the engine and
+    arrive as ``lens``).  Only dense/moe stacks run paged, so there is
+    no cross-attention branch."""
     h = nn.rmsnorm_apply(p["ln_attn"], x, cfg.norm_eps)
     h, ck, cv = attn.attention_decode_paged(
-        p["attn"], h, cache["k"], cache["v"], block_tables, lens,
+        p["attn"], h, store["k"], store["v"], layer, block_tables, lens,
         write_phys, write_off, cfg)
-    cache = dict(cache, k=ck, v=cv)
+    store = dict(store, k=ck, v=cv)
     x = x + h
     y, _ = _ffn(p, x, cfg, mesh, decode=True)
-    return y, cache
+    return y, store
 
 
 def block_extend(p, x, cache, cfg: ModelConfig, *, mesh=None):
@@ -550,28 +553,37 @@ def paged_decode_step(p, store, block_tables, lens, tokens, write_phys,
     this token, and ``write_phys``/``write_off`` [B] name the single
     physical cell the new token's K/V is written into.  No contiguous
     [B, Smax] view is ever materialized — attention reads K/V through the
-    block table (see ``attention_decode_paged``).  Returns
-    (store, logits [B, vocab])."""
+    block table (see ``attention_decode_paged``).
+
+    The layer scan carries the stacked store and scans only the layer
+    params and the layer index: each layer scatters its row into the
+    carried stack and the kernel reads its layer from it, so the donated
+    store is written in place, with no per-layer slice or update-slice
+    and no second stacked store.  An unscanned ``pre`` layer is a stack
+    of one.  Returns (store, logits [B, vocab])."""
     x = nn.embedding_apply(p["embed"], tokens[:, None], cfg.cdtype, mesh=mesh)
     if cfg.positions == "learned":
         tab = p["pos_embed"]["table"].astype(x.dtype)
         x = x + jnp.take(tab, lens, axis=0)[:, None, :]
+    step = functools.partial(block_decode_paged, block_tables=block_tables,
+                             lens=lens, write_phys=write_phys,
+                             write_off=write_off, cfg=cfg, mesh=mesh)
 
     new_pre = {}
     for name in _pre_names(p):
-        x, c = block_decode_paged(p["pre"][name], x, store["pre"][name],
-                                  block_tables, lens, write_phys, write_off,
-                                  cfg, mesh=mesh)
-        new_pre[name] = c
+        one = jax.tree.map(lambda a: a[None], store["pre"][name])
+        x, one = step(p["pre"][name], x, one, 0)
+        new_pre[name] = jax.tree.map(lambda a: a[0], one)
 
-    def scan_body(x, layer):
-        layer_params, layer_store = layer
-        y, c = block_decode_paged(layer_params, x, layer_store,
-                                  block_tables, lens, write_phys, write_off,
-                                  cfg, mesh=mesh)
-        return y, c
+    def scan_body(carry, layer):
+        x, scan_store = carry
+        layer_params, idx = layer
+        return step(layer_params, x, scan_store, idx), None
 
-    x, new_scan = jax.lax.scan(scan_body, x, (p["blocks"], store["scan"]))
+    n_scan = store["scan"]["k"].shape[0]
+    (x, new_scan), _ = jax.lax.scan(
+        scan_body, (x, store["scan"]),
+        (p["blocks"], jnp.arange(n_scan, dtype=jnp.int32)))
     new_store = {"scan": new_scan}
     if new_pre:
         new_store["pre"] = new_pre

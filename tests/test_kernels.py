@@ -88,6 +88,13 @@ def _paged_setup(key, B, num_blocks, bs, mb, Hq, Hkv, D, *, permute=True):
     return q, k_store, v_store, jnp.asarray(bt), jnp.asarray(lens)
 
 
+def _paged(q, k_store, v_store, bt, lens):
+    """The paged kernel on a one-layer stack: ``[N, bs, Hkv, D]`` stores
+    read as layer 0 of ``[1, N, bs, Hkv, D]``."""
+    return paged_decode_attention(q, k_store[None], v_store[None], 0, bt,
+                                  lens, interpret=True)
+
+
 @pytest.mark.parametrize("B,num_blocks,bs,mb,Hq,Hkv,D", [
     (2, 17, 16, 4, 4, 2, 32),     # ragged lens, permuted tables
     (3, 32, 8, 6, 8, 4, 16),      # small blocks, more heads
@@ -97,10 +104,43 @@ def test_paged_decode_attention(B, num_blocks, bs, mb, Hq, Hkv, D):
     """Paged kernel vs the gather-then-dense oracle."""
     q, ks_, vs_, bt, lens = _paged_setup(
         jax.random.PRNGKey(5), B, num_blocks, bs, mb, Hq, Hkv, D)
-    out = paged_decode_attention(q, ks_, vs_, bt, lens, interpret=True)
+    out = _paged(q, ks_, vs_, bt, lens)
     ref = paged_decode_ref(q[:, 0].reshape(B, Hkv, Hq // Hkv, D),
                            ks_, vs_, bt, lens).reshape(B, 1, Hq, D)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
+                               rtol=2e-5, atol=2e-5)
+
+
+@pytest.mark.parametrize("L,layer", [(1, 0), (3, 0), (3, 1), (3, 2)])
+@pytest.mark.parametrize("Hq,Hkv,D", [
+    (16, 16, 64),   # MHA, qwen1.5-0.5b's heads
+    (24, 8, 128),   # GQA (G=3) at D=128, qwen3-8b's head width
+])
+def test_paged_decode_reads_layer_of_stack(L, layer, Hq, Hkv, D):
+    """The kernel reading layer ``layer`` of a stacked [L, N, bs, Hkv, D]
+    store equals the oracle on that layer's store alone.  Every layer is
+    drawn apart, so reading the wrong one fails."""
+    B, num_blocks, bs, mb = 2, 9, 16, 4
+    q, _, _, bt, lens = _paged_setup(
+        jax.random.PRNGKey(10), B, num_blocks, bs, mb, Hq, Hkv, D)
+    kk, kv = jax.random.split(jax.random.PRNGKey(11))
+    shape = (L, num_blocks, bs, Hkv, D)
+    # layer l is offset by 2l as well: no two layers share a value
+    off = 2.0 * jnp.arange(L, dtype=jnp.float32)[:, None, None, None, None]
+    k_stack = jax.random.normal(kk, shape) + off
+    v_stack = jax.random.normal(kv, shape) - off
+    out = paged_decode_attention(q, k_stack, v_stack, layer, bt, lens,
+                                 interpret=True)
+
+    def ref(l):
+        return paged_decode_ref(q[:, 0].reshape(B, Hkv, Hq // Hkv, D),
+                                k_stack[l], v_stack[l], bt,
+                                lens).reshape(B, 1, Hq, D)
+
+    np.testing.assert_allclose(np.asarray(out), np.asarray(ref(layer)),
+                               rtol=2e-5, atol=2e-5)
+    for other in set(range(L)) - {layer}:
+        assert not np.allclose(np.asarray(out), np.asarray(ref(other)),
                                rtol=2e-5, atol=2e-5)
 
 
@@ -111,7 +151,7 @@ def test_paged_matches_contiguous_kernel():
     B, num_blocks, bs, mb, Hq, Hkv, D = 2, 13, 16, 3, 4, 2, 32
     q, ks_, vs_, bt, lens = _paged_setup(
         jax.random.PRNGKey(6), B, num_blocks, bs, mb, Hq, Hkv, D)
-    paged = paged_decode_attention(q, ks_, vs_, bt, lens, interpret=True)
+    paged = _paged(q, ks_, vs_, bt, lens)
     kc, vc = gather_kv(ks_, bt), gather_kv(vs_, bt)
     contig = decode_attention(q, kc, vc, lens, block_k=bs, interpret=True)
     np.testing.assert_allclose(np.asarray(paged), np.asarray(contig),
@@ -135,7 +175,7 @@ def test_paged_decode_block_size_edges():
     for b in range(B):
         bt_np[b, -(-edge_lens[b] // bs):] = 0
     bt = jnp.asarray(bt_np)
-    out = paged_decode_attention(q, ks_, vs_, bt, lens, interpret=True)
+    out = _paged(q, ks_, vs_, bt, lens)
     ref = paged_decode_ref(q[:, 0].reshape(B, Hkv, Hq // Hkv, D),
                            ks_, vs_, bt, lens).reshape(B, 1, Hq, D)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
@@ -150,7 +190,7 @@ def test_paged_table_permutation_invariance():
     q, ks_, vs_, bt, lens = _paged_setup(
         jax.random.PRNGKey(8), B, num_blocks, bs, mb, Hq, Hkv, D,
         permute=False)
-    out1 = paged_decode_attention(q, ks_, vs_, bt, lens, interpret=True)
+    out1 = _paged(q, ks_, vs_, bt, lens)
     # relocate: physical block p -> perm[p], stores shuffled to match
     perm = np.concatenate([[0], 1 + np.asarray(
         jax.random.permutation(jax.random.PRNGKey(9), num_blocks - 1))])
@@ -158,7 +198,7 @@ def test_paged_table_permutation_invariance():
     ks2 = jnp.asarray(np.asarray(ks_)[inv])
     vs2 = jnp.asarray(np.asarray(vs_)[inv])
     bt2 = jnp.asarray(perm[np.asarray(bt)])
-    out2 = paged_decode_attention(q, ks2, vs2, bt2, lens, interpret=True)
+    out2 = _paged(q, ks2, vs2, bt2, lens)
     np.testing.assert_allclose(np.asarray(out1), np.asarray(out2),
                                rtol=0, atol=0)
 

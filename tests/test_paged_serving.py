@@ -8,8 +8,9 @@ import pytest
 
 from repro.configs import get_config, get_smoke_config
 from repro.models import get_model, nn
-from repro.serving.engine import InferenceEngine
-from repro.serving.kvcache import BlockAllocator, PagedCachePool
+from repro.serving.engine import InferenceEngine, paged_decode_step
+from repro.serving.kvcache import (BlockAllocator, PagedCachePool,
+                                   gather_block_view)
 
 
 def _build(name):
@@ -373,6 +374,59 @@ def test_direct_decode_never_gathers(dense_lm, monkeypatch):
         _drive(eng, prompts, 6)
     assert decode_gathers["direct"] == 0
     assert decode_gathers["gather"] > 0
+
+
+@pytest.mark.parametrize("family", ["dense", "moe"])
+def test_paged_decode_writes_store_in_place(family, dense_lm, moe_lm):
+    """One paged decode step changes only the batch's (layer, write_phys,
+    write_off) cells of the store, every other K/V cell bit-identical to
+    before, and each written cell holds the token's K/V: the row the
+    slot-pool decode writes at the sequence's length.  The moe model's
+    unscanned ``pre`` layer is checked alongside the scanned stack."""
+    cfg, api, params = dense_lm if family == "dense" else moe_lm
+    bs = 8
+    pool = PagedCachePool(cfg, num_blocks=16, block_size=bs, max_len=4 * bs)
+    # random contents, so that a cell left alone is told from one zeroed
+    leaves, tree = jax.tree.flatten(pool.cache)
+    keys = jax.random.split(jax.random.PRNGKey(3), len(leaves))
+    store = tree.unflatten([
+        leaf if leaf.dtype == jnp.int32
+        else jax.random.normal(k, leaf.shape, leaf.dtype)
+        for k, leaf in zip(keys, leaves)])
+    # tokens land mid-block, at a block's first cell, and past two blocks
+    lens = np.asarray([7, 8, 17], np.int32)
+    bt = np.asarray([[3, 0, 0, 0], [5, 9, 0, 0], [2, 11, 14, 0]], np.int32)
+    wphys = bt[np.arange(3), lens // bs]
+    woff = lens % bs
+    tokens = jnp.asarray([11, 22, 33], jnp.int32)
+    new, _ = paged_decode_step(api, cfg, params, store, jnp.asarray(bt),
+                               jnp.asarray(lens), tokens, jnp.asarray(wphys),
+                               jnp.asarray(woff))
+    view, _ = api.decode(params, gather_block_view(
+        store, jnp.asarray(bt), jnp.asarray(lens)), tokens, cfg)
+
+    checked = []
+
+    def check(path, old, after, ref):
+        old, after, ref = (np.asarray(a) for a in (old, after, ref))
+        if path[-1].key == "len":
+            np.testing.assert_array_equal(after, old)
+            return
+        if old.ndim == 4:  # an unscanned layer: a stack of one
+            old, after, ref = old[None], after[None], ref[None]
+        written = np.zeros(old.shape[:3], bool)
+        written[:, wphys, woff] = True
+        np.testing.assert_array_equal(after[~written], old[~written])
+        for b in range(len(lens)):
+            np.testing.assert_array_equal(after[:, wphys[b], woff[b]],
+                                          ref[:, b, lens[b]])
+        checked.append(jax.tree_util.keystr(path))
+
+    jax.tree_util.tree_map_with_path(check, store, new, view)
+    expect = {"['scan']['k']", "['scan']['v']"}
+    if family == "moe":
+        expect |= {"['pre']['layer_0']['k']", "['pre']['layer_0']['v']"}
+    assert set(checked) == expect
 
 
 def test_paged_rejects_unknown_decode_mode(dense_lm):

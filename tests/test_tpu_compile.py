@@ -4,8 +4,8 @@ The TPU compiler is installed wherever JAX is; it compiles for a chip that
 is described (``v5e:2x2``) and not attached.  Nothing here runs: a compile
 that passes shows the chip's compiler accepts the block shapes, memory and
 lowering, which interpret mode on the CPU cannot.  Shapes are the published
-widths of qwen1.5-0.5b (16 heads of 64, block_size 16), with model depth
-cut to 2 layers.
+widths of qwen1.5-0.5b (16 heads of 64, block_size 16) and qwen3-8b (8 KV
+heads of 128), with model depth cut to 2 layers.
 
 The topology is described inside a module fixture only, never at import:
 only one process at a time may load the TPU library, and every test worker
@@ -13,6 +13,7 @@ imports this file.
 """
 import functools
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -31,6 +32,10 @@ from repro.serving.engine import paged_decode_step, paged_extend_step
 BLOCK_SIZE = 16
 MAX_LEN = 128
 NUM_BLOCKS = 4 * MAX_LEN // BLOCK_SIZE + 1  # the engine's default pool
+# a pool whose 2-layer K stack (158 MB at either model's widths) exceeds
+# the v5e's 128 MiB of VMEM, as a deployed pool does: the compiler stages
+# a toy pool there whole.  No dimension of either model is 2411.
+LARGE_POOL = 2411
 
 
 @pytest.fixture(scope="module")
@@ -66,11 +71,11 @@ def _compile(fn, *args):
 ])
 def test_paged_decode_kernel_compiles(one_chip, B, Hq, Hkv, D):
     mb = MAX_LEN // BLOCK_SIZE
+    stack = _sds(one_chip, (2, NUM_BLOCKS, BLOCK_SIZE, Hkv, D))
     compiled = _compile(
         functools.partial(paged_decode_attention, interpret=False),
-        _sds(one_chip, (B, 1, Hq, D)),
-        _sds(one_chip, (NUM_BLOCKS, BLOCK_SIZE, Hkv, D)),
-        _sds(one_chip, (NUM_BLOCKS, BLOCK_SIZE, Hkv, D)),
+        _sds(one_chip, (B, 1, Hq, D)), stack, stack,
+        _sds(one_chip, (), jnp.int32),
         _sds(one_chip, (B, mb), jnp.int32), _sds(one_chip, (B,), jnp.int32))
     assert "tpu_custom_call" in compiled.as_text()
 
@@ -92,16 +97,20 @@ def test_flash_attention_compiles(one_chip):
     assert "tpu_custom_call" in compiled.as_text()
 
 
-@pytest.fixture(scope="module")
-def qwen_two_layers(one_chip):
-    """qwen1.5-0.5b at published widths, depth cut to 2 layers: the model
+def _two_layers(sharding, arch, num_blocks):
+    """``arch`` at published widths, depth cut to 2 layers: the model
     api, config, and its parameter and paged-store shapes on the chip."""
-    cfg = get_config("qwen1.5-0.5b").scaled(n_layers=2)
+    cfg = get_config(arch).scaled(n_layers=2)
     api = get_model(cfg)
     params = jax.eval_shape(
         lambda k: nn.split(api.init(k, cfg))[0], jax.random.PRNGKey(0))
-    store = specs.cache_template(cfg, NUM_BLOCKS, BLOCK_SIZE)
-    return api, cfg, _on(one_chip, params), _on(one_chip, store)
+    store = specs.cache_template(cfg, num_blocks, BLOCK_SIZE)
+    return api, cfg, _on(sharding, params), _on(sharding, store)
+
+
+@pytest.fixture(scope="module")
+def qwen_two_layers(one_chip):
+    return _two_layers(one_chip, "qwen1.5-0.5b", NUM_BLOCKS)
 
 
 def _compile_step(step, api, cfg, params, store, *args):
@@ -134,3 +143,91 @@ def test_qwen_paged_extend_step_compiles(one_chip, qwen_two_layers,
                              _sds(one_chip, (1,), jnp.int32),
                              chunk, chunk, chunk)
     assert compiled.memory_analysis() is not None
+
+
+_HLO_COMPUTATION = re.compile(r"^(?:ENTRY )?%(\S+) .*\{$")
+_HLO_INSTRUCTION = re.compile(r"^\s*(?:ROOT )?%(\S+) = ")
+
+
+def _hlo_instructions(hlo):
+    """{computation: [(name, result shape, opcode, line)]} of HLO text."""
+    comps, cur = {}, None
+    for line in hlo.splitlines():
+        m = _HLO_COMPUTATION.match(line)
+        if m:
+            cur = comps.setdefault(m.group(1), [])
+            continue
+        m = _HLO_INSTRUCTION.match(line)
+        if m is None or cur is None:
+            continue
+        rest = line[m.end():]
+        if rest.startswith("("):  # a tuple shape, which may nest
+            depth = 0
+            for end, ch in enumerate(rest):
+                depth += (ch == "(") - (ch == ")")
+                if depth == 0:
+                    break
+            shape, rest = rest[:end + 1], rest[end + 1:].lstrip()
+        else:
+            shape, _, rest = rest.partition(" ")
+        op = re.match(r"[\w-]*", rest).group(0)
+        cur.append((m.group(1), shape, op, line))
+    return comps
+
+
+def _store_copies(hlo, num_blocks):
+    """The copies, slices and update-slices whose result shape holds the
+    pool's block count, split into those inside a while loop's body (or
+    a computation it calls) and those outside: [(opcode, name)] each."""
+    comps = _hlo_instructions(hlo)
+    stack = [re.search(r"body=%([\w.\-]+)", line).group(1)
+             for ins in comps.values() for _, _, op, line in ins
+             if op == "while"]
+    in_loop = set()
+    while stack:
+        c = stack.pop()
+        if c not in in_loop:
+            in_loop.add(c)
+            stack += [n for _, _, _, line in comps[c]
+                      for n in re.findall(r"%([\w.\-]+)", line)
+                      if n in comps]
+    ops = ("copy", "copy-start", "dynamic-slice", "dynamic-update-slice",
+           "slice", "slice-start")
+    found = {True: [], False: []}
+    for c, ins in comps.items():
+        for name, shape, op, _ in ins:
+            if op in ops and re.search(rf"[\[,]{num_blocks}[,\]]", shape):
+                found[c in in_loop].append((op, name))
+    return found[True], found[False]
+
+
+@pytest.mark.parametrize("arch,relayouts", [
+    ("qwen3-8b", 0),
+    # head_dim 64: the chip lays the store out with num_blocks minor, so
+    # the stack is relaid into the kernel's row-major layout once on the
+    # way in and once on the way out, for K and for V.  A lane-dense
+    # store layout ([L, N, bs, Hkv * D]) would remove these four.
+    ("qwen1.5-0.5b", 4),
+])
+def test_paged_decode_writes_store_in_place(one_chip, monkeypatch, arch,
+                                            relayouts):
+    """The decode step carries the stacked store through its layer loop
+    and the kernel reads its layer from it: no layer of the store is
+    sliced out, copied or updated back inside the loop, and at
+    head_dim 128 nothing copies the store at all."""
+    monkeypatch.setattr(kernels, "pallas_interpret", lambda: False)
+    api, cfg, params, store = _two_layers(one_chip, arch, LARGE_POOL)
+    B, mb = 8, MAX_LEN // BLOCK_SIZE
+    vec = _sds(one_chip, (B,), jnp.int32)
+    compiled = _compile_step(paged_decode_step, api, cfg, params, store,
+                             _sds(one_chip, (B, mb), jnp.int32),
+                             vec, vec, vec, vec)
+    hlo = compiled.as_text()
+    assert "tpu_custom_call" in hlo and " while(" in hlo
+    in_loop, outside = _store_copies(hlo, LARGE_POOL)
+    assert in_loop == []
+    assert len(outside) <= relayouts, outside
+    if relayouts == 0:
+        k = store["scan"]["k"]
+        layer_bytes = k.size // k.shape[0] * k.dtype.itemsize
+        assert compiled.memory_analysis().temp_size_in_bytes < layer_bytes
