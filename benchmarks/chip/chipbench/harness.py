@@ -24,7 +24,7 @@ from typing import Callable, Optional
 
 import numpy as np
 
-from . import flops, metrics, reference, spec, stats, traffic, weights
+from . import metrics, reference, spec, stats, traffic, weights
 
 COUNTERS = ("decode_tokens", "prefill_tokens", "prefix_cached_tokens")
 BLOCK_SIZE = 16  # positions per KV block, the engine's default
@@ -156,6 +156,11 @@ def pool_blocks(temp_at, budget: int, block_bytes: int, n1: int) -> tuple:
     return n, {"temp": {n1: t1, n2: t2}}
 
 
+def kv_block_bytes(block, dims: dict) -> int:
+    """Bytes of one KV block of ``BLOCK_SIZE`` positions, every layer."""
+    return dims["layers"] * block.kv_bytes_per_position(dims) * BLOCK_SIZE
+
+
 def _kv_pool_blocks(cell, cfg, api, params, dims, device) -> tuple:
     """Blocks the paged KV pool gets: what ``HBM_UTILIZATION`` of the
     chip's memory leaves after what is in use (the weights) and the
@@ -165,8 +170,7 @@ def _kv_pool_blocks(cell, cfg, api, params, dims, device) -> tuple:
     eng = cell.engine
     if "num_blocks" in eng:
         return int(eng["num_blocks"]), {}
-    block_bytes = (dims["layers"] * flops.kv_bytes_per_position(dims)
-                   * BLOCK_SIZE)
+    block_bytes = kv_block_bytes(cell.block, dims)
     ms = device.memory_stats()
     budget = HBM_UTILIZATION * ms["bytes_limit"] - ms["bytes_in_use"]
     n, info = pool_blocks(
@@ -298,12 +302,13 @@ def run_cell(cell: spec.Cell, seed: int, seconds: float, trace: bool, *,
     from repro.models import get_model, nn
     from repro.serving.client import llm_service_factory
 
-    cfg = spec.model_config(cell.config)
-    dims = spec.dims(cell.config)
+    block = cell.block
+    cfg = block.model_config(cell.config)
+    dims = block.dims(cell.config)
     api = get_model(cfg)
     shapes = jax.eval_shape(lambda k: nn.split(api.init(k, cfg))[0],
                             jax.random.PRNGKey(0))
-    params = weights.program_params(shapes, dims, seed)
+    params = weights.program_params(shapes, block, dims, seed)
     jax.block_until_ready(params)
     num_blocks, sizing = _kv_pool_blocks(cell, cfg, api, params, dims, dev)
     log(f"KV pool: {num_blocks} blocks of {BLOCK_SIZE} positions; sizing "
@@ -393,7 +398,7 @@ def run_cell(cell: spec.Cell, seed: int, seconds: float, trace: bool, *,
               "metrics": {}, "device": {
                   "platform": dev.platform, "kind": dev.device_kind,
                   "count": count, "memory_peak_bytes": int(peak)}}
-    ctx = metrics.Context(cell=cell, dims=dims, records=recs,
+    ctx = metrics.Context(cell=cell, block=block, dims=dims, records=recs,
                           window=(ws, we), load=load, device=dev)
     if trace:
         tr.reduce(ctx, result, wanted or {})
@@ -476,12 +481,13 @@ def correctness(cell, dims, seed, recs, window, schedule,
     t0 = time.perf_counter()
     picks = {}
     for mode in controls:
-        ctl = reference.Reference(dims, seed, quantize_mode=mode)
+        ctl = reference.Reference(cell.block, dims, seed,
+                                  quantize_mode=mode)
         picks[mode] = [reference.top_tokens(x, len(r))
                        for x, r in zip(ctl.logits(seqs, rows), rows)]
         del ctl
     t1 = time.perf_counter()
-    ref = reference.Reference(dims, seed)
+    ref = reference.Reference(cell.block, dims, seed)
     gaps = []
     ctl_gaps = {m: [] for m in controls}
     for i, x in enumerate(ref.logits(seqs, rows)):

@@ -21,9 +21,12 @@ class Context:
     (see ``harness._Load.records``); ``window``: the measured window (host
     clock); ``traced``: the traced sub-window; ``counters``: the engine
     counters' change over the traced window; ``calls``: the jitted steps'
-    arguments recorded while traced; ``trace``: the reduced device trace."""
+    arguments recorded while traced; ``trace``: the reduced device trace;
+    ``block``: the configuration's block kind, whose counts a reader
+    takes (``chipbench/spec.py::load_block``)."""
 
     cell: Any
+    block: Any
     dims: dict
     records: list
     window: tuple

@@ -1,13 +1,11 @@
-"""The plain reference: a Qwen-style decoder in float32 jax.numpy.
+"""The plain reference: the model in float32 jax.numpy.
 
-It follows the published architecture (Qwen2 and Qwen3 in Hugging Face
-``transformers``): RMSNorm before attention and MLP, q/k/v projections
-(with bias where ``qkv_bias``), RMSNorm of each query and key head where
-``qk_norm``, rotary embeddings on the two halves of each head (theta from
-the config), causal grouped-query softmax attention scaled by
-1/sqrt(head_dim), a SwiGLU MLP, a final RMSNorm and an untied vocabulary
-projection.  Every matrix product runs at ``Precision.HIGHEST``, since a
-TPU otherwise multiplies float32 in bfloat16.
+The layers are the block kind's (``layer`` in ``blocks/<kind>.py``);
+this module runs them over whole sequences, between the input embedding
+and the final RMSNorm and untied vocabulary projection, and holds the
+building blocks a kind's layer is made of.  Every matrix product runs at
+``Precision.HIGHEST``, since a TPU otherwise multiplies float32 in
+bfloat16.
 
 It imports nothing of the program.  Its weights are the benchmark's own
 seeded weights (``weights.layer``), upcast from bfloat16 one layer at a
@@ -20,7 +18,6 @@ the control that the comparison has to fail.
 from __future__ import annotations
 
 import functools
-import math
 
 import jax
 import jax.numpy as jnp
@@ -31,11 +28,11 @@ from . import weights
 HI = jax.lax.Precision.HIGHEST
 
 
-def _rmsnorm(x, scale, eps):
+def rmsnorm(x, scale, eps):
     return x * jax.lax.rsqrt(jnp.mean(x * x, -1, keepdims=True) + eps) * scale
 
 
-def _rope(x, pos, theta):
+def rope(x, pos, theta):
     """x [S, H, D]: rotate the pairs (i, i + D/2) by pos * theta^(-2i/D)."""
     half = x.shape[-1] // 2
     inv = 1.0 / theta ** (jnp.arange(half, dtype=jnp.float32) / half)
@@ -45,42 +42,15 @@ def _rope(x, pos, theta):
     return jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin], -1)
 
 
-def quantize(w, mode: str):
-    """Quantize-dequantize a weight matrix [in, out] per output column.
-    The rounding is explicit (``round``, ``reduce_precision``): a cast to
-    a narrow type and back may be dropped by the compiler, which is
-    allowed excess precision, and was on the chip."""
-    if mode == "int8":
-        s = jnp.max(jnp.abs(w), axis=0, keepdims=True) / 127.0
-        return jnp.round(w / s).clip(-127, 127) * s
-    if mode == "fp8":  # e4m3: 4 exponent and 3 mantissa bits, max 240
-        s = jnp.max(jnp.abs(w), axis=0, keepdims=True) / 240.0
-        return jax.lax.reduce_precision(w / s, exponent_bits=4,
-                                        mantissa_bits=3) * s
-    raise ValueError(f"unknown precision {mode!r}")
-
-
-def _layer(w, x, dims: dict, block: int):
-    """One decoder layer over one sequence x [S, d] (S a multiple of
-    ``block``), positions 0..S-1."""
-    S = x.shape[0]
-    h_, kv, hd = dims["heads"], dims["kv_heads"], dims["head_dim"]
+def causal_attention(q, k, v, block: int):
+    """Causal softmax attention over positions 0..S-1: q [S, H, D], scaled
+    already; k and v [S, G, D], each of the G heads read by H/G query
+    heads in turn.  Runs in blocks of ``block`` query rows (S a multiple
+    of it).  Returns [S, H * D]."""
+    S, h_, hd = q.shape
+    kv = k.shape[1]
     g = h_ // kv
-    eps = dims["norm_eps"]
     pos = jnp.arange(S)
-    h = _rmsnorm(x, w["ln_attn"], eps)
-    q = jnp.dot(h, w["wq"], precision=HI)
-    k = jnp.dot(h, w["wk"], precision=HI)
-    v = jnp.dot(h, w["wv"], precision=HI)
-    if dims["qkv_bias"]:
-        q, k, v = q + w["bq"], k + w["bk"], v + w["bv"]
-    q, k, v = (q.reshape(S, h_, hd), k.reshape(S, kv, hd),
-               v.reshape(S, kv, hd))
-    if dims["qk_norm"]:
-        q = _rmsnorm(q, w["q_norm"], eps)
-        k = _rmsnorm(k, w["k_norm"], eps)
-    q = _rope(q, pos, dims["rope_theta"]) / math.sqrt(hd)
-    k = _rope(k, pos, dims["rope_theta"])
 
     def attend(args):
         qb, start = args  # [block, kv, g, hd]
@@ -93,11 +63,23 @@ def _layer(w, x, dims: dict, block: int):
     nb = S // block
     o = jax.lax.map(attend, (q.reshape(nb, block, kv, g, hd),
                              jnp.arange(nb) * block))
-    x = x + jnp.dot(o.reshape(S, h_ * hd), w["wo"], precision=HI)
-    h = _rmsnorm(x, w["ln_mlp"], eps)
-    a = jax.nn.silu(jnp.dot(h, w["w_gate"], precision=HI))
-    a = a * jnp.dot(h, w["w_up"], precision=HI)
-    return x + jnp.dot(a, w["w_down"], precision=HI)
+    return o.reshape(S, h_ * hd)
+
+
+def quantize(w, mode: str):
+    """Quantize-dequantize a weight matrix [..., in, out] per output
+    column, each matrix of a stack (experts') on its own.  The rounding
+    is explicit (``round``, ``reduce_precision``): a cast to a narrow
+    type and back may be dropped by the compiler, which is allowed excess
+    precision, and was on the chip."""
+    if mode == "int8":
+        s = jnp.max(jnp.abs(w), axis=-2, keepdims=True) / 127.0
+        return jnp.round(w / s).clip(-127, 127) * s
+    if mode == "fp8":  # e4m3: 4 exponent and 3 mantissa bits, max 240
+        s = jnp.max(jnp.abs(w), axis=-2, keepdims=True) / 240.0
+        return jax.lax.reduce_precision(w / s, exponent_bits=4,
+                                        mantissa_bits=3) * s
+    raise ValueError(f"unknown precision {mode!r}")
 
 
 def _pow2(n: int, least: int) -> int:
@@ -116,41 +98,40 @@ def padded_len(n: int) -> int:
 
 
 class Reference:
-    """Logits of the plain model at chosen positions of given sequences."""
+    """Logits of the plain model at chosen positions of given sequences.
+    ``kind`` is the configuration's block kind (``blocks/<kind>.py``)."""
 
-    def __init__(self, dims: dict, seed: int, *, quantize_mode=None,
+    def __init__(self, kind, dims: dict, seed: int, *, quantize_mode=None,
                  block: int = 512):
-        self.dims = dims
+        self.kind, self.dims = kind, dims
         self.key = weights.seed_key(seed)
-        self._layer_w = jax.jit(functools.partial(self._weights, dims=dims,
-                                                  mode=quantize_mode))
-        self._global = jax.jit(functools.partial(self._global_w, dims=dims,
-                                                 mode=quantize_mode),
-                               static_argnums=1)
-        self._apply = jax.jit(functools.partial(_layer, dims=dims,
+        self._w = jax.jit(functools.partial(self._weights, mode=quantize_mode),
+                          static_argnums=2)
+        self._apply = jax.jit(functools.partial(kind.layer, dims=dims,
                                                 block=block))
         self._head = jax.jit(functools.partial(_head, eps=dims["norm_eps"]))
 
     @staticmethod
-    def _weights(key, i, *, dims, mode):
+    def _weights(key, i, specs, *, mode):
         w = {n: a.astype(jnp.float32)
-             for n, a in weights.layer(key, i, dims).items()}
+             for n, a in weights.layer(key, i, specs).items()}
         if mode:
-            w = {n: quantize(a, mode) if a.ndim == 2 else a
+            w = {n: quantize(a, mode) if a.ndim >= 2 else a
                  for n, a in w.items()}
         return w
 
-    @staticmethod
-    def _global_w(key, name, *, dims, mode):
-        w = weights.make(key, name, None, dims).astype(jnp.float32)
-        return quantize(w, mode) if mode and w.ndim == 2 else w
+    def _own(self, name: str):
+        """One of the model's own weights (``embed``, ``ln_f``,
+        ``unembed``)."""
+        spec = self.kind.specs(self.dims, None)[name]
+        return self._w(self.key, None, weights.frozen({name: spec}))[name]
 
     def logits(self, seqs: list, rows: list) -> list:
         """``seqs``: token lists; ``rows[i]``: positions of ``seqs[i]``
         whose next-token logits are wanted.  Returns device arrays
         [R, vocab], R a power of two at or above ``len(rows[i])``: the
         rows past it are padding."""
-        embed = self._global(self.key, "embed")
+        embed = self._own("embed")
         xs = []
         for s in seqs:
             ids = np.zeros(padded_len(len(s)), np.int32)
@@ -158,10 +139,10 @@ class Reference:
             xs.append(embed[jnp.asarray(ids)])
         del embed
         for i in range(self.dims["layers"]):
-            w = self._layer_w(self.key, i)
-            xs = [self._apply(w, x) for x in xs]
-        ln_f = self._global(self.key, "ln_f")
-        unembed = self._global(self.key, "unembed")
+            w = self._w(self.key, i,
+                        weights.frozen(self.kind.specs(self.dims, i)))
+            xs = [self._apply(w, x, i=i) for x in xs]
+        ln_f, unembed = self._own("ln_f"), self._own("unembed")
         out = []
         for x, r in zip(xs, rows):
             idx = np.zeros(_pow2(len(r), 64), np.int32)
@@ -171,7 +152,7 @@ class Reference:
 
 
 def _head(x, rows, ln_f, unembed, *, eps):
-    return jnp.dot(_rmsnorm(x[rows], ln_f, eps), unembed, precision=HI)
+    return jnp.dot(rmsnorm(x[rows], ln_f, eps), unembed, precision=HI)
 
 
 def served_rows(prompt_len: int, n_out: int) -> list:

@@ -1,37 +1,30 @@
-"""Cells, configurations and traffic mixes, found by name as data files.
+"""Cells, configurations, traffic mixes and block kinds, found by name as
+files.
 
 A cell ``<config>.<mix>`` is ``cells/<cell>.json``.  It names a
 configuration (``configs/<config>.json``) and a traffic mix
 (``traffic/<mix>.json``), and holds the engine settings, the phases of a
-run and the limit of the correctness check.  Adding a cell, a
-configuration or a mix is adding a file; no code here changes.
+run and the limit of the correctness check.  The configuration names its
+block kind (``"block"``), the module ``blocks/<kind>.py`` that knows the
+model's layers.  Adding a cell, a configuration, a mix or a kind is
+adding a file; no code here changes.
 """
 from __future__ import annotations
 
 import dataclasses
+import importlib.util
 import json
 from pathlib import Path
-from typing import Optional
+from typing import Any, Optional
 
 from . import BENCH_DIR
-
-# published config key -> the program's ModelConfig field
-_WIDTH_KEYS = {
-    "num_hidden_layers": "n_layers",
-    "hidden_size": "d_model",
-    "num_attention_heads": "n_heads",
-    "num_key_value_heads": "n_kv_heads",
-    "intermediate_size": "d_ff",
-    "vocab_size": "vocab",
-    "rope_theta": "rope_theta",
-    "rms_norm_eps": "norm_eps",
-}
 
 
 @dataclasses.dataclass
 class Cell:
     name: str
     config: dict  # configs/<config>.json
+    block: Any  # the module blocks/<kind>.py the configuration names
     traffic: dict  # traffic/<mix>.json, with the cell's overrides merged
     engine: dict  # engine settings (max_len, max_running, ...)
     run: dict  # ramp_s, drain_cap_s, trace_s, trace_offset_s
@@ -51,13 +44,14 @@ def load_cell(name: str, root: Optional[Path] = None) -> Cell:
         raise KeyError(f"no cell {name!r}: {path} does not exist")
     c = _load(path)
     config = load_config(c["config"], root)
+    block = load_block(config, root)
     traffic = _load(root / "traffic" / f"{c['traffic']}.json")
     for key, over in c.get("traffic_params", {}).items():
         if isinstance(over, dict):
             traffic[key] = dict(traffic.get(key, {}), **over)
         else:
             traffic[key] = over
-    return Cell(name=name, config=config, traffic=traffic,
+    return Cell(name=name, config=config, block=block, traffic=traffic,
                 engine=dict(c["engine"]), run=dict(c["run"]),
                 check=dict(c["check"]), limits=dict(c.get("limits", {})))
 
@@ -71,45 +65,51 @@ def load_config(name: str, root: Optional[Path] = None) -> dict:
     return cfg
 
 
-def dims(config: dict) -> dict:
-    """The sizes the yardstick computes with (FLOPs, bytes, the plain
-    reference, the weights), from the configuration file alone."""
-    d = config["hidden_size"]
-    heads = config["num_attention_heads"]
-    return {
-        "layers": config["num_hidden_layers"],
-        "d_model": d,
-        "heads": heads,
-        "kv_heads": config["num_key_value_heads"],
-        "head_dim": config.get("head_dim", d // heads),
-        "d_ff": config["intermediate_size"],
-        "vocab": config["vocab_size"],
-        "rope_theta": float(config["rope_theta"]),
-        "norm_eps": float(config["rms_norm_eps"]),
-        "qkv_bias": bool(config["qkv_bias"]),
-        "qk_norm": bool(config["qk_norm"]),
-    }
+def load_block(config: dict, root: Optional[Path] = None):
+    """The block kind a configuration names with its ``"block"`` key: the
+    module ``blocks/<kind>.py`` under ``root``, or else under the
+    benchmark's own directory.  It provides, from the configuration file
+    alone, everything the harness asks of a model's layers:
 
-
-def model_config(config: dict):
-    """The program's ``ModelConfig`` for a configuration file: the repo
-    arch named by ``arch``, with every size set from the file.  Fails when
-    the program's config would run something else than the file says."""
-    from repro.configs import get_config
-
-    if config["tie_word_embeddings"]:
-        raise ValueError(f"{config['name']}: tied embeddings; the "
-                         f"benchmark's seeded weights and plain reference "
-                         f"keep a separate output head")
-    dm = dims(config)
-    over = {field: config[key] for key, field in _WIDTH_KEYS.items()}
-    over.update(head_dim=dm["head_dim"], qkv_bias=dm["qkv_bias"],
-                qk_norm=dm["qk_norm"], tie_embeddings=False)
-    cfg = get_config(config["arch"], **over)
-    if (cfg.family != "dense" or not cfg.gated_mlp
-            or cfg.activation != "silu" or cfg.positions != "rope"
-            or cfg.param_dtype != config["torch_dtype"]
-            or cfg.compute_dtype != config["torch_dtype"]):
-        raise ValueError(f"{config['name']}: the program's {config['arch']} "
-                         f"config is not a bf16 gated-silu rope decoder")
-    return cfg
+    - ``dims(config)``: the sizes it computes with; every kind's hold
+      ``layers``, ``vocab`` and ``norm_eps`` (of the final norm);
+    - ``model_config(config)``: the program's ``ModelConfig``, refused
+      where the program would run something else than the file says;
+    - ``specs(dims, layer)``: name -> (shape, kind, std) of each seeded
+      weight of layer ``layer`` (an int; layers may differ), or with
+      ``layer`` None of the model's own: ``embed`` [vocab, d], ``ln_f``
+      [d] and ``unembed`` [d, vocab].  Leading axes, such as experts',
+      are part of a weight's shape;
+    - ``program_layout(dims)``: the program's parameter path -> (weight
+      name, layer): None for the model's own weights, else the layer it
+      holds or, where the path has a leading layer axis, the first layer
+      that axis covers;
+    - ``layer(w, x, dims, i, block)``: the plain reference layer over one
+      sequence ``x`` [S, d], float32 at ``Precision.HIGHEST``, attention
+      in blocks of ``block`` query rows; ``w`` holds layer ``i``'s
+      weights, so their names tell which kind of layer it is (``i`` is
+      traced);
+    - the counts: ``flops_per_token(dims)`` (the projections and FFN of
+      every layer, for one token: the active ones where a token uses only
+      some), ``attention_flops(dims, attended)``, ``logits_flops(dims)``,
+      ``kv_bytes_per_position(dims)`` (one layer) and
+      ``decode_attention_bytes(dims, attended)`` (the decode attention
+      kernel's least bytes, one sequence, one layer).
+    """
+    kind = config.get("block")
+    name = config.get("name")
+    if not kind:
+        raise KeyError(f"configs/{name}.json names no block kind: give it "
+                       f"\"block\": \"<kind>\" for the file blocks/<kind>.py")
+    bases = dict.fromkeys((Path(root or BENCH_DIR), BENCH_DIR))
+    paths = [base / "blocks" / f"{kind}.py" for base in bases]
+    path = next((p for p in paths if p.is_file()), None)
+    if path is None:
+        raise KeyError(f"configs/{name}.json names the block kind "
+                       f"{kind!r}, and no file {' or '.join(map(str, paths))}"
+                       f" exists")
+    spec = importlib.util.spec_from_file_location(f"chipbench_block_{kind}",
+                                                  path)
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod

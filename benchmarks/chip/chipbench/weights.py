@@ -1,15 +1,13 @@
 """Seeded weights, made on the device in one jitted call.
 
-Each weight is named by what it is (``wq``, ``w_down``, ...), and its
-values are a function of the seed, that name and the layer alone.  The
-program gets them in its own parameter layout (``program_params``); the
-plain reference makes the same values one layer at a time (``layer``),
-so it takes nothing that the program holds.
-
-Values are uniform with the standard deviation of a LeCun initialisation
-(1/sqrt(fan-in)), the embedding at 1, biases at 0.1, and norm scales at
-1 + 0.1 noise, so that every part of the block (qkv bias, qk-norm, the
-norm scales) changes the logits.
+Each weight is named by what it is, and its values are a function of the
+seed, that name and the layer alone.  The block kind
+(``blocks/<kind>.py``) gives each weight's shape, kind and standard
+deviation (``specs``) and where the program holds it
+(``program_layout``).  The program gets the weights in its own parameter
+layout (``program_params``); the plain reference makes the same values
+one layer at a time (``layer``), so it takes nothing that the program
+holds.
 """
 from __future__ import annotations
 
@@ -19,58 +17,6 @@ import zlib
 import jax
 import jax.numpy as jnp
 
-# the program's parameter paths -> the weight each one holds; layered
-# ones ("blocks/...") carry a leading layer axis in the program
-PROGRAM_LAYOUT = {
-    "embed/table": "embed",
-    "ln_f/scale": "ln_f",
-    "unembed/w": "unembed",
-    "blocks/ln_attn/scale": "ln_attn",
-    "blocks/ln_mlp/scale": "ln_mlp",
-    "blocks/attn/q/w": "wq",
-    "blocks/attn/q/b": "bq",
-    "blocks/attn/k/w": "wk",
-    "blocks/attn/k/b": "bk",
-    "blocks/attn/v/w": "wv",
-    "blocks/attn/v/b": "bv",
-    "blocks/attn/o/w": "wo",
-    "blocks/attn/q_norm/scale": "q_norm",
-    "blocks/attn/k_norm/scale": "k_norm",
-    "blocks/mlp/up/w": "w_up",
-    "blocks/mlp/gate/w": "w_gate",
-    "blocks/mlp/down/w": "w_down",
-}
-
-
-def specs(dims: dict) -> dict:
-    """name -> (shape, kind, std) of every weight; layered weights are
-    given per layer."""
-    d, h, kv, hd, ff, v = (dims["d_model"], dims["heads"], dims["kv_heads"],
-                           dims["head_dim"], dims["d_ff"], dims["vocab"])
-    out = {
-        "embed": ((v, d), "w", 1.0),
-        "unembed": ((d, v), "w", 1 / math.sqrt(d)),
-        "ln_f": ((d,), "scale", 0.1),
-        "ln_attn": ((d,), "scale", 0.1),
-        "ln_mlp": ((d,), "scale", 0.1),
-        "wq": ((d, h * hd), "w", 1 / math.sqrt(d)),
-        "wk": ((d, kv * hd), "w", 1 / math.sqrt(d)),
-        "wv": ((d, kv * hd), "w", 1 / math.sqrt(d)),
-        "wo": ((h * hd, d), "w", 1 / math.sqrt(h * hd)),
-        "w_up": ((d, ff), "w", 1 / math.sqrt(d)),
-        "w_gate": ((d, ff), "w", 1 / math.sqrt(d)),
-        "w_down": ((ff, d), "w", 1 / math.sqrt(ff)),
-    }
-    if dims["qkv_bias"]:
-        out.update(bq=((h * hd,), "w", 0.1), bk=((kv * hd,), "w", 0.1),
-                   bv=((kv * hd,), "w", 0.1))
-    if dims["qk_norm"]:
-        out.update(q_norm=((hd,), "scale", 0.1), k_norm=((hd,), "scale", 0.1))
-    return out
-
-
-GLOBAL = ("embed", "unembed", "ln_f")
-
 
 def seed_key(seed: int):
     """A PRNG key from a seed of any size (``PRNGKey`` keeps 32 bits)."""
@@ -78,9 +24,11 @@ def seed_key(seed: int):
     return jax.random.fold_in(key, (seed // 2**32) % 2**32)
 
 
-def make(key, name: str, layer, dims: dict):
-    """One weight in bfloat16, from the key, its name and its layer."""
-    shape, kind, std = specs(dims)[name]
+def make(key, name: str, layer, spec: tuple):
+    """One weight in bfloat16, from the key, its name and its layer (None
+    for the model's own); ``spec`` is its (shape, kind, std): uniform
+    with that standard deviation, and 1 + that for a ``scale``."""
+    shape, kind, std = spec
     k = jax.random.fold_in(key, zlib.crc32(name.encode()))
     if layer is not None:
         k = jax.random.fold_in(k, layer)
@@ -91,32 +39,55 @@ def make(key, name: str, layer, dims: dict):
     return x.astype(jnp.bfloat16)
 
 
-def layer(key, i, dims: dict) -> dict:
-    """Every weight of layer ``i`` (jit this; ``i`` may be traced)."""
-    return {n: make(key, n, i, dims) for n in specs(dims)
-            if n not in GLOBAL}
+def frozen(specs: dict) -> tuple:
+    """``specs`` as a hashable argument (for ``jax.jit``'s static ones)."""
+    return tuple(sorted(specs.items()))
 
 
-def program_params(program_shapes, dims: dict, seed: int):
-    """The program's parameter tree, in bfloat16 on the default device,
-    made in one jitted call.  ``program_shapes`` is the tree of shapes the
-    program's own initialiser gives (``jax.eval_shape``)."""
-    names = specs(dims)
-    used = set()
+def layer(key, i, specs: tuple) -> dict:
+    """Every weight of layer ``i`` (jit this with ``specs``, from
+    ``frozen``, static; ``i`` may be traced)."""
+    return {n: make(key, n, i, s) for n, s in specs}
+
+
+def program_params(program_shapes, block, dims: dict, seed: int):
+    """The program's parameter tree on the default device, made in one
+    jitted call.  ``program_shapes`` is the tree of shapes the program's
+    own initialiser gives (``jax.eval_shape``); ``block`` is the block
+    kind.  Every program leaf has to hold a seeded weight, and every
+    weight of every layer has to be held once."""
+    layout = block.program_layout(dims)
+    layers = {i: block.specs(dims, i) for i in range(dims["layers"])}
+    layers[None] = block.specs(dims, None)
+    held = set()
 
     def build(key):
         def leaf(path, sds):
             p = "/".join(str(getattr(k, "key", k)) for k in path)
-            name = PROGRAM_LAYOUT.get(p)
-            if name is None or name not in names:
+            name, first = layout.get(p, (None, None))
+            spec = layers.get(first, {}).get(name)
+            if spec is None:
                 raise KeyError(f"program parameter {p!r} has no seeded "
                                f"weight for this configuration")
-            used.add(name)
-            if p.startswith("blocks/"):
-                x = jax.vmap(lambda i: make(key, name, i, dims))(
-                    jnp.arange(dims["layers"]))
-            else:
-                x = make(key, name, None, dims)
+            if first is None or sds.ndim == len(spec[0]):
+                covers = [first]
+                x = make(key, name, first, spec)
+            else:  # a leading axis over layers first, first + 1, ...
+                covers = list(range(first, first + sds.shape[0]))
+                if any(layers.get(i, {}).get(name) != spec for i in covers):
+                    raise ValueError(f"{p}: layers {covers} do not all hold "
+                                     f"{name} as {spec}")
+                x = jax.vmap(lambda i: make(key, name, i, spec))(
+                    jnp.arange(first, first + sds.shape[0]))
+            for i in covers:
+                if (name, i) in held:
+                    raise ValueError(f"{p}: {name} of layer {i} is held "
+                                     f"twice")
+                held.add((name, i))
+            # a float32 leaf (a MoE router, or a program run in float32)
+            # gets the same bfloat16 values, exactly
+            if sds.dtype == jnp.float32:
+                x = x.astype(jnp.float32)
             if x.shape != sds.shape or x.dtype != sds.dtype:
                 raise ValueError(f"{p}: the program holds {sds.shape} "
                                  f"{sds.dtype}, the weight is {x.shape} "
@@ -124,9 +95,11 @@ def program_params(program_shapes, dims: dict, seed: int):
             return x
 
         tree = jax.tree_util.tree_map_with_path(leaf, program_shapes)
-        if used != set(names):
-            raise KeyError(f"the program holds no {sorted(set(names) - used)}"
-                           f", which the configuration has")
+        want = {(n, i) for i, s in layers.items() for n in s}
+        if held != want:
+            missing = sorted(want - held, key=str)
+            raise KeyError(f"the program holds no {missing}, which the "
+                           f"configuration has")
         return tree
 
     return jax.jit(build)(seed_key(seed))

@@ -17,29 +17,28 @@ KERNEL = "paged_decode"  # the decode program is the one running it
 
 
 def _decode_flops(ctx) -> int:
-    d = ctx.dims
+    b, d = ctx.block, ctx.dims
     total = 0
     for (lens,) in ctx.calls.decode:
         att = np.asarray(lens)
         att = att[att > 0] + 1
-        total += len(att) * (flops.dense_flops_per_token(d)
-                             + flops.logits_flops(d))
-        total += flops.attention_flops(d, int(att.sum()))
+        total += len(att) * (b.flops_per_token(d) + b.logits_flops(d))
+        total += b.attention_flops(d, int(att.sum()))
     return total
 
 
 def _extend_flops(ctx) -> int:
-    d = ctx.dims
+    b, d = ctx.block, ctx.dims
     total = 0
     for lens, wphys in ctx.calls.extend:
         start = int(np.asarray(lens)[0])
         n = int(np.count_nonzero(np.asarray(wphys)))
-        total += n * flops.dense_flops_per_token(d)
-        total += flops.attention_flops(d, flops.prefill_attended(start, n))
+        total += n * b.flops_per_token(d)
+        total += b.attention_flops(d, flops.prefill_attended(start, n))
     t0, t1 = ctx.traced
     firsts = sum(1 for r in ctx.records if r["engine_first"] is not None
                  and t0 <= r["engine_first"] < t1)
-    return total + firsts * flops.logits_flops(ctx.dims)
+    return total + firsts * b.logits_flops(d)
 
 
 def read(ctx, variant):

@@ -34,7 +34,7 @@ def main(argv=None) -> int:
     from jax.experimental import topologies
     from jax.sharding import SingleDeviceSharding
 
-    from chipbench import harness, flops, spec
+    from chipbench import harness, spec
     from repro import kernels
     from repro.launch import specs
     from repro.models import get_model, nn
@@ -42,8 +42,8 @@ def main(argv=None) -> int:
 
     kernels.pallas_interpret = lambda: False  # what a TPU backend chooses
     cell = spec.load_cell(args.workload)
-    cfg = spec.model_config(cell.config)
-    dims = spec.dims(cell.config)
+    cfg = cell.block.model_config(cell.config)
+    dims = cell.block.dims(cell.config)
     api = get_model(cfg)
     topo = topologies.get_topology_desc(platform="tpu",
                                         topology_name="v5e:2x2")
@@ -60,7 +60,7 @@ def main(argv=None) -> int:
     eng = cell.engine
     bs = harness.BLOCK_SIZE
     mb = -(-int(eng["max_len"]) // bs)
-    block_bytes = dims["layers"] * flops.kv_bytes_per_position(dims) * bs
+    block_bytes = harness.kv_block_bytes(cell.block, dims)
     util = harness.HBM_UTILIZATION
     budget = util * HBM_LIMIT - weight_bytes
     n_blocks, info = harness.pool_blocks(

@@ -22,6 +22,7 @@ Run once when a cell is defined; the chosen rate goes into the cell file.
 """
 import argparse
 import copy
+import dataclasses
 import json
 import statistics
 import sys
@@ -87,7 +88,8 @@ def main(argv=None) -> int:
     base = spec.load_cell(args.workload)
     knee, below = None, True
     for rate in sorted(args.rates):
-        cell = copy.deepcopy(base)
+        cell = dataclasses.replace(
+            base, traffic=copy.deepcopy(base.traffic), run=dict(base.run))
         cell.traffic["arrivals"]["rate_per_s"] = rate
         if args.drain_cap is not None:
             cell.run["drain_cap_s"] = args.drain_cap

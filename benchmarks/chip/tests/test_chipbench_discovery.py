@@ -1,5 +1,5 @@
-"""A new configuration, mix, cell or per-layer metric is a new file, found
-by name with no change to the harness."""
+"""A new configuration, mix, cell, block kind or per-layer metric is a new
+file, found by name with no change to the harness."""
 import json
 import types
 
@@ -34,7 +34,7 @@ def test_new_files_are_found_by_name(tmp_path):
     assert cell.config["num_hidden_layers"] == 4
     assert cell.traffic["arrivals"] == {"kind": "gamma", "cv": 3.0,
                                         "rate_per_s": 7.0}
-    assert spec.dims(cell.config)["layers"] == 4
+    assert cell.block.dims(cell.config)["layers"] == 4
     ctx = types.SimpleNamespace()
     out = metrics.read_all(ctx, {"answer": "u", "answer.batch": "u"},
                            tmp_path)
@@ -58,8 +58,29 @@ def test_a_config_with_tied_embeddings_is_refused(tmp_path):
                       "qwen1.5-0.5b.json").read_text())
     cfg.update(name="tied", tie_word_embeddings=True)
     (tmp_path / "configs" / "tied.json").write_text(json.dumps(cfg))
+    cfg = spec.load_config("tied", tmp_path)
     with pytest.raises(ValueError, match="tied embeddings"):
-        spec.model_config(spec.load_config("tied", tmp_path))
+        spec.load_block(cfg, tmp_path).model_config(cfg)
+
+
+@pytest.mark.parametrize("block,missing", [
+    (None, "names no block kind"),
+    ("sparse", "the block kind 'sparse', and no file .*blocks/sparse.py"),
+])
+def test_a_config_without_a_known_block_kind_is_refused(tmp_path, block,
+                                                         missing):
+    for d in ("configs", "cells"):
+        (tmp_path / d).mkdir()
+    cfg = json.loads((spec.BENCH_DIR / "configs" /
+                      "qwen1.5-0.5b.json").read_text())
+    cfg.pop("block")
+    cfg.update(name="nokind", **({"block": block} if block else {}))
+    (tmp_path / "configs" / "nokind.json").write_text(json.dumps(cfg))
+    (tmp_path / "cells" / "nokind.chat.json").write_text(json.dumps({
+        "config": "nokind", "traffic": "chat", "engine": {}, "run": {},
+        "check": {}}))
+    with pytest.raises(KeyError, match=missing):
+        spec.load_cell("nokind.chat", tmp_path)
 
 
 def test_a_reader_that_finds_nothing_leaves_the_metric_out(tmp_path):
