@@ -10,7 +10,7 @@ import importlib
 from repro.models.config import ModelConfig
 
 ARCHS = {
-    "moonshot-v1-16b-a3b": "moonshot_v1_16b_a3b",
+    "moonlight-16b-a3b": "moonlight_16b_a3b",
     "deepseek-moe-16b": "deepseek_moe_16b",
     "whisper-small": "whisper_small",
     "qwen1.5-0.5b": "qwen1_5_0_5b",

@@ -12,7 +12,7 @@ def config() -> ModelConfig:
         n_layers=28, d_model=2048, n_heads=16, n_kv_heads=16, head_dim=128,
         d_ff=1408, vocab=102400,
         n_experts=64, top_k=6, n_shared_experts=2, first_dense_layers=1,
-        dense_ff=10944, capacity_factor=1.25,
+        dense_ff=10944,
         activation="silu", gated_mlp=True,
         rope_theta=1e4, max_seq=32768,
         param_dtype="bfloat16", compute_dtype="bfloat16",

@@ -29,6 +29,20 @@ Two variants share the online-softmax body:
   (their table entries point at the null block 0).
 
 Both take the lengths in scalar prefetch as well.
+
+``paged_decode_latent`` is their sibling for latent attention (MLA):
+every query head of a sequence against ONE shared latent per position.
+The stacked store is ``[L, num_blocks, block_size/2, 2*(r+rope)]``,
+two positions to a lane-dense row, ``[c(2i) | c(2i+1) | k_pe(2i) |
+k_pe(2i+1)]``.  Each head comes twice, once laid out against a row's
+even position and once against its odd one, so that one MXU product
+per grid step scores all the positions of the step's blocks (q_lat . c
++ q_pe . k_pe, over r + rope) and a second accumulates the
+softmax-weighted rows, whose latent halves are the values: the latent is
+never duplicated as per-head keys or values.  A grid step reads several
+blocks of the table (each its own BlockSpec of the same store), which
+both amortises the step and widens the products.  The layer, the tables and the lengths ride in
+scalar prefetch as for the GQA kernel.
 """
 from __future__ import annotations
 
@@ -173,3 +187,111 @@ def paged_decode_attention_grouped(q, k_store, v_store, layer, block_tables,
                  lambda b, ki, ly, bt, ln: (ly[0], bt[b, ki], 0, 0, 0),
                  block=k_store.shape[2], n_blocks=block_tables.shape[1],
                  interpret=interpret, name="paged_decode_attention")
+
+
+# KV blocks one grid step of the latent kernel reads (256 positions), so
+# that a step's two MXU products are more than slivers: at Moonlight's
+# decode shapes (batch 128, 512-block tables, one layer) a TPU v5e took
+# 21.8, 7.9, 5.5 and 4.4 ms a call at 1, 4, 8 and 16 blocks a step
+LATENT_BLOCKS_PER_STEP = 16
+
+
+def _latent_kernel(layer_ref, bt_ref, len_ref, q_ref, *refs, rank: int,
+                   n_steps: int, sm_scale: float):
+    """``blocks`` blocks of one sequence.  q: [1, 2H, W], the H
+    even-position queries ``[q_lat | 0 | q_pe | 0]`` over the H
+    odd-position ones ``[0 | q_lat | 0 | q_pe]``; refs: the blocks, each
+    [rows, W] (rows = block_size/2), then o [1, H, r], then scratch acc
+    [2H, W] and m/l [2H, 1], one max per head kept alike in its even and
+    odd row."""
+    del layer_ref, bt_ref  # consumed by the index maps
+    *kv_refs, o_ref, acc_ref, m_ref, l_ref = refs
+    kv_len = len_ref[pl.program_id(0)]
+    ki = pl.program_id(1)
+    H = o_ref.shape[1]
+
+    @pl.when(ki == 0)
+    def _init():
+        acc_ref[...] = jnp.zeros_like(acc_ref)
+        m_ref[...] = jnp.full_like(m_ref, NEG_INF)
+        l_ref[...] = jnp.zeros_like(l_ref)
+
+    rows = kv_refs[0].shape[0] * len(kv_refs)
+    start = ki * 2 * rows
+
+    @pl.when(start < kv_len)
+    def _compute():
+        # the blocks one under another (through float32, whose 8-row
+        # tiles stack where bfloat16's 16-row ones would not)
+        kv = jnp.concatenate([r[...].astype(jnp.float32) for r in kv_refs],
+                             axis=0).astype(kv_refs[0].dtype)
+        s = jax.lax.dot_general(q_ref[0], kv, (((1,), (1,)), ((), ())),
+                                preferred_element_type=jnp.float32)
+        pos = (start + 2 * jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
+               + (jax.lax.broadcasted_iota(jnp.int32, s.shape, 0) >= H))
+        s = jnp.where(pos < kv_len, s * sm_scale, NEG_INF)  # [2H, rows]
+        m_rows = jnp.max(s, axis=1, keepdims=True)
+        m_head = jnp.maximum(m_rows[:H], m_rows[H:])
+        m_prev = m_ref[...]
+        m_new = jnp.maximum(m_prev, jnp.concatenate([m_head, m_head], 0))
+        p = jnp.exp(s - m_new)
+        alpha = jnp.exp(m_prev - m_new)
+        l_ref[...] = alpha * l_ref[...] + jnp.sum(p, axis=1, keepdims=True)
+        acc_ref[...] = acc_ref[...] * alpha + jnp.dot(
+            p.astype(kv.dtype), kv, preferred_element_type=jnp.float32)
+        m_ref[...] = m_new
+
+    @pl.when(ki == n_steps - 1)
+    def _finish():
+        acc = acc_ref[...]
+        denom = jnp.maximum(l_ref[:H] + l_ref[H:], 1e-30)
+        out = acc[:H, :rank] + acc[H:, rank:2 * rank]
+        o_ref[0] = (out / denom).astype(o_ref.dtype)
+
+
+def paged_decode_latent(q, store, layer, block_tables, kv_length, *,
+                        rank: int, sm_scale: float, interpret: bool = False,
+                        blocks_per_step: int = LATENT_BLOCKS_PER_STEP):
+    """Paged latent flash-decode of one layer of a stacked store.
+
+    q: [B, 2H, W], per sequence the H even-position queries
+    ``[q_lat | 0 | q_pe | 0]`` over the H odd-position ones ``[0 | q_lat
+    | 0 | q_pe]`` (W = 2*(r+rope), a store row's width); store: [L,
+    num_blocks, block_size/2, W]; layer: int32 scalar; block_tables: [B,
+    max_blocks] int32 (entries past a sequence's length point at a valid
+    block, conventionally the null one, and are predicated off);
+    kv_length: [B].  Each grid step reads ``blocks_per_step`` blocks of
+    the table (fewer where that does not divide max_blocks).  Returns the
+    softmax-weighted latent [B, H, r] in q's dtype."""
+    B, H2, W = q.shape
+    rows = store.shape[2]
+    n_blocks = block_tables.shape[1]
+    k = max(d for d in range(1, blocks_per_step + 1) if n_blocks % d == 0)
+
+    def kv_map(j):
+        return lambda b, ki, ly, bt, ln: (ly[0], bt[b, ki * k + j], 0, 0)
+
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=3,
+        grid=(B, n_blocks // k),
+        in_specs=[pl.BlockSpec((1, H2, W), lambda b, ki, *_: (b, 0, 0))]
+        + [pl.BlockSpec((None, None, rows, W), kv_map(j)) for j in range(k)],
+        out_specs=pl.BlockSpec((1, H2 // 2, rank),
+                               lambda b, ki, *_: (b, 0, 0)),
+        scratch_shapes=[
+            pltpu.VMEM((H2, W), jnp.float32),
+            pltpu.VMEM((H2, 1), jnp.float32),
+            pltpu.VMEM((H2, 1), jnp.float32),
+        ],
+    )
+    kernel = functools.partial(_latent_kernel, rank=rank,
+                               n_steps=n_blocks // k, sm_scale=sm_scale)
+    return pl.pallas_call(
+        kernel,
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((B, H2 // 2, rank), q.dtype),
+        interpret=interpret,
+        name="paged_decode_latent",
+    )(jnp.reshape(layer, (1,)).astype(jnp.int32),
+      block_tables.astype(jnp.int32), kv_length.astype(jnp.int32), q,
+      *([store] * k))

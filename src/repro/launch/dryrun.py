@@ -50,7 +50,7 @@ MICROBATCHES = {
     "qwen3-8b": 4,
     "llama3.2-3b": 4,
     "zamba2-2.7b": 4,
-    "moonshot-v1-16b-a3b": 4,
+    "moonlight-16b-a3b": 4,
     "deepseek-moe-16b": 4,
     "rwkv6-1.6b": 4,
 }

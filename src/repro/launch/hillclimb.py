@@ -21,8 +21,8 @@ CELLS = [
     ("B0-baseline", "llama3.2-3b", "prefill_32k", None, None),
     ("B*-optimized", "llama3.2-3b", "prefill_32k",
      {"pad_heads_to": 32, "explicit_tp": True}, None),
-    ("C0-baseline", "moonshot-v1-16b-a3b", "decode_32k", None, None),
-    ("C*-optimized", "moonshot-v1-16b-a3b", "decode_32k",
+    ("C0-baseline", "moonlight-16b-a3b", "decode_32k", None, None),
+    ("C*-optimized", "moonlight-16b-a3b", "decode_32k",
      {"explicit_tp": True}, None),
 ]
 

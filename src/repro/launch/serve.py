@@ -292,6 +292,19 @@ def main(argv=None) -> int:
               f"mean decode batch {batch}, attended KV per decoded token "
               f"{kv}; mean prefill chunk {chunk} tokens, padded share "
               f"{pad}")
+        if cfg.is_moe:
+            tot = {k: sum(getattr(st, k) for st in engines)
+                   for k in ("expert_assignments", "expert_assignments_max",
+                             "decode_calls", "prefill_chunks")}
+            runs = ((tot["decode_calls"] + tot["prefill_chunks"])
+                    * (cfg.n_layers - cfg.first_dense_layers))
+            held = tot["expert_assignments"]
+            if held:
+                busiest = tot["expert_assignments_max"] * cfg.n_held / held
+                print(f"[serve] held experts ({cfg.n_held} of "
+                      f"{cfg.n_experts}): {held / runs:.1f} routed tokens "
+                      f"per step call (decode or prefill chunk) and MoE "
+                      f"layer; busiest / mean expert {busiest:.2f}")
         print("[serve] per-replica requests:",
               [p["requests"] for p in stats["per_replica"]])
         btel = {g: s.get("block_telemetry")

@@ -145,6 +145,13 @@ def cache_template(cfg: ModelConfig, batch: int, max_len: int):
     n_scan = n_dec - n_pre
 
     def layer_cache(lead=()):
+        if cfg.is_mla:  # the latent rows, two positions to a row
+            if max_len % 2:
+                raise ValueError(f"a latent cache packs positions in "
+                                 f"pairs; max_len {max_len} is odd")
+            return {"ckv": SDS(lead + (batch, max_len // 2,
+                                       2 * cfg.latent_width), cd),
+                    "len": SDS(lead + (batch,), jnp.int32)}
         c = {
             "k": SDS(lead + (batch, max_len, cfg.n_kv_heads, cfg.head_dim), cd),
             "v": SDS(lead + (batch, max_len, cfg.n_kv_heads, cfg.head_dim), cd),
@@ -192,6 +199,9 @@ def cache_specs(cfg: ModelConfig, mesh, batch: int, max_len: int):
             bdim = rank - 4
             ent[bdim] = batch_entry
             ent[bdim + 1] = model_if(leaf.shape[bdim + 1])  # kv sequence
+        elif name == "ckv":
+            ent[rank - 3] = batch_entry
+            ent[rank - 2] = model_if(leaf.shape[rank - 2])  # kv sequence
         elif name == "len":
             ent[rank - 1] = batch_entry
         elif name == "wkv":

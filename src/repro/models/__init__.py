@@ -23,11 +23,14 @@ class ModelApi:
     decode: Callable  # (params, cache, tokens, cfg, *, mesh=None) -> (cache, logits)
     # chunked cache extension (paged serving); None for state-carrying
     # families whose recurrent state has no per-position KV to extend
-    extend: Optional[Callable] = None  # (params, cache, tokens [B,T], cfg, *, mesh=None) -> (cache, logits [B,T,V])
+    # (a MoE model given ``valid``, the real tokens, also returns the held
+    # experts' assignment counts per MoE layer third; so do decode and
+    # decode_paged)
+    extend: Optional[Callable] = None  # (params, cache, tokens [B,T], cfg, *, mesh=None, valid=None) -> (cache, logits [B,T,V])
     # single-token decode directly on a block-paged physical store; None
     # for families without per-position KV (and unused by encdec/vlm,
     # whose cross/prefix handling the paged engine does not support)
-    decode_paged: Optional[Callable] = None  # (params, store, block_tables, lens, tokens [B], write_phys, write_off, cfg, *, mesh=None) -> (store, logits [B,V])
+    decode_paged: Optional[Callable] = None  # (params, store, block_tables, lens, tokens [B], write_phys, write_off, cfg, *, mesh=None, valid=None) -> (store, logits [B,V])
 
 
 def get_model(cfg: ModelConfig) -> ModelApi:

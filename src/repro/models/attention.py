@@ -422,3 +422,259 @@ def attention_decode(p, x, cache_k, cache_v, kv_length, cfg: ModelConfig):
     out = decode_attention(q, cache_k, cache_v, new_len)
     out = out.reshape(B, 1, cfg.padded_heads * cfg.head_dim)
     return nn.linear_apply(p["o"], out, cfg.cdtype), cache_k, cache_v, new_len
+
+
+# ---------------------------------------------------------------------------
+# Latent attention (MLA, DeepSeek-V2/V3)
+# ---------------------------------------------------------------------------
+#
+# Queries come from ``q`` ([d, H*(nope+rope)], no q_lora_rank); keys and
+# values from one latent per position: ``kv_a`` gives the latent c_kv
+# [r] and one rotary key k_pe [rope] shared by every head, ``kv_norm``
+# normalises c_kv, and ``kv_b`` expands it to each head's k_nope [nope]
+# and value [v].  Scores are over nope + rope (scale 1/sqrt(nope + rope)),
+# rotary embeddings pair the rope dimensions half and half (``apply_rope``).
+#
+# The cache holds, per position, the normed latent c [r] and the roped
+# k_pe [rope], two positions to a row so that the row is lane-dense:
+# row i = [c(2i) | c(2i+1) | k_pe(2i) | k_pe(2i+1)], 2 * (r + rope) wide
+# (``pack_latent``/``unpack_latent``).  Decode is absorbed: the query is
+# moved into the latent space (q_nope . W_UK), scored against c and
+# k_pe, and the weighted latent is expanded by W_UV only then.  Prefill
+# and extend expand the latent into per-head keys and values.
+
+
+def mla_init(key, cfg: ModelConfig):
+    d, nh = cfg.d_model, cfg.n_heads
+    r, rope = cfg.kv_lora_rank, cfg.qk_rope_head_dim
+    nope, vd = cfg.qk_nope_head_dim, cfg.v_head_dim
+    ks = jax.random.split(key, 4)
+    dt = cfg.pdtype
+    return {
+        "q": nn.linear_init(ks[0], d, nh * (nope + rope),
+                            axes=("embed", "q_proj"), dtype=dt),
+        "kv_a": nn.linear_init(ks[1], d, r + rope,
+                               axes=("embed", "kv_lora"), dtype=dt),
+        "kv_norm": nn.rmsnorm_init(r, axis="kv_lora", dtype=dt),
+        "kv_b": nn.linear_init(ks[2], r, nh * (nope + vd),
+                               axes=("kv_lora", "q_proj"), dtype=dt),
+        "o": nn.linear_init(ks[3], nh * vd, d, axes=("q_proj", "embed"),
+                            dtype=dt, stddev=1.0 / math.sqrt(nh * vd)),
+    }
+
+
+def pack_latent(c, k_pe):
+    """c [..., S, r] and k_pe [..., S, rope] (S even) -> the cache's rows
+    [..., S/2, 2 * (r + rope)]."""
+    lead, S = c.shape[:-2], c.shape[-2]
+    return jnp.concatenate(
+        [c.reshape(lead + (S // 2, 2 * c.shape[-1])),
+         k_pe.reshape(lead + (S // 2, 2 * k_pe.shape[-1]))], axis=-1)
+
+
+def unpack_latent(ckv, r: int):
+    """The cache's rows [..., S/2, 2 * (r + rope)] -> (c [..., S, r],
+    k_pe [..., S, rope])."""
+    lead, rows = ckv.shape[:-2], ckv.shape[-2]
+    return (ckv[..., :2 * r].reshape(lead + (2 * rows, r)),
+            ckv[..., 2 * r:].reshape(lead + (2 * rows, -1)))
+
+
+def _mla_project(p, x, positions, cfg: ModelConfig):
+    """x [B,S,d] at ``positions`` [B,S] -> q_nope [B,S,H,nope], q_pe
+    [B,S,H,rope] (roped), c [B,S,r] (normed), k_pe [B,S,rope] (roped)."""
+    B, S, _ = x.shape
+    cd = cfg.cdtype
+    r, rope = cfg.kv_lora_rank, cfg.qk_rope_head_dim
+    q = nn.linear_apply(p["q"], x, cd).reshape(
+        B, S, cfg.n_heads, cfg.qk_nope_head_dim + rope)
+    q_nope, q_pe = q[..., :cfg.qk_nope_head_dim], q[..., cfg.qk_nope_head_dim:]
+    kv = nn.linear_apply(p["kv_a"], x, cd)
+    c = nn.rmsnorm_apply(p["kv_norm"], kv[..., :r], cfg.norm_eps)
+    k_pe = nn.apply_rope(kv[..., None, r:], positions,
+                         cfg.rope_theta)[..., 0, :]
+    q_pe = nn.apply_rope(q_pe, positions, cfg.rope_theta)
+    return q_nope, q_pe, c, k_pe
+
+
+def _kv_b(p, cfg: ModelConfig):
+    """W_UK [r, H, nope] and W_UV [r, H, v] in the compute dtype."""
+    w = p["kv_b"]["w"].astype(cfg.cdtype).reshape(
+        cfg.kv_lora_rank, cfg.n_heads, cfg.qk_nope_head_dim + cfg.v_head_dim)
+    return w[..., :cfg.qk_nope_head_dim], w[..., cfg.qk_nope_head_dim:]
+
+
+def _mla_expand(p, q_nope, q_pe, c, k_pe, cfg: ModelConfig):
+    """Per-head q, k [B,*,H,nope+rope] and v [B,S,H,v] from the latent."""
+    w_uk, w_uv = _kv_b(p, cfg)
+    k_nope = jnp.einsum("bsr,rhn->bshn", c, w_uk)
+    v = jnp.einsum("bsr,rhv->bshv", c, w_uv)
+    k_pe = jnp.broadcast_to(k_pe[:, :, None, :],
+                            k_pe.shape[:2] + (cfg.n_heads, k_pe.shape[-1]))
+    q = jnp.concatenate([q_nope, q_pe], axis=-1)
+    k = jnp.concatenate([k_nope, k_pe.astype(k_nope.dtype)], axis=-1)
+    return q, k, v
+
+
+def _mla_out(p, out, cfg: ModelConfig):
+    B, S = out.shape[:2]
+    return nn.linear_apply(p["o"], out.reshape(B, S, -1), cfg.cdtype)
+
+
+def mla_apply(p, x, cfg: ModelConfig, *, positions=None):
+    """Causal latent attention over a full sequence (the published,
+    expanded form).  Returns (out [B,S,d], (c [B,S,r], k_pe [B,S,rope]))
+    for the cache."""
+    with jax.named_scope("mla"):
+        B, S, _ = x.shape
+        if positions is None:
+            positions = jnp.arange(S)[None, :]
+        q_nope, q_pe, c, k_pe = _mla_project(p, x, positions, cfg)
+        q, k, v = _mla_expand(p, q_nope, q_pe, c, k_pe, cfg)
+        if _pick_impl(cfg, S) != "full" and S % cfg.attn_chunk_q == 0:
+            out = chunked_causal_attention(q, k, v, block_q=cfg.attn_chunk_q,
+                                           block_k=cfg.attn_chunk_k)
+        else:
+            out = full_attention(q, k, v, causal=True)
+        return _mla_out(p, out, cfg), (c, k_pe)
+
+
+def mla_extend(p, x, ckv, kv_length, cfg: ModelConfig):
+    """Multi-token extension of a contiguous latent cache (chunked
+    prefill): x [B,T,d] at positions kv_length..kv_length+T-1; ckv
+    [B,Smax/2,2*(r+rope)].  The chunk's latent rows are written at their
+    positions, then its queries attend (expanded form) to every cached
+    position up to their own, with ``attention_extend``'s score math but
+    the operands left in the compute dtype.  Returns (out [B,T,d], ckv,
+    new_len)."""
+    with jax.named_scope("mla"):
+        B, T, _ = x.shape
+        pos = kv_length[:, None] + jnp.arange(T)[None, :]
+        q_nope, q_pe, c, k_pe = _mla_project(p, x, pos, cfg)
+        c_all, kpe_all = unpack_latent(ckv, cfg.kv_lora_rank)
+        bidx = jnp.arange(B)[:, None]
+        c_all = c_all.at[bidx, pos].set(c.astype(ckv.dtype))
+        kpe_all = kpe_all.at[bidx, pos].set(k_pe.astype(ckv.dtype))
+        w_uk, w_uv = _kv_b(p, cfg)
+        k_nope = jnp.einsum("bsr,rhn->bshn", c_all, w_uk)
+        v = jnp.einsum("bsr,rhv->bshv", c_all, w_uv)
+        # the rope key is shared by every head, so it is scored against
+        # all heads' queries in a product of its own.  Broadcast over the
+        # heads and concatenated onto their keys, it led the v5e compiler
+        # to take the softmax's row max as a sliding-window reduce over
+        # the whole view, work quadratic in the view's length
+        # (tests/test_tpu_compile.py).  Accumulated in float32, as decode
+        # does.
+        logits = (jnp.einsum("bqhn,bkhn->bhqk", q_nope, k_nope,
+                             preferred_element_type=jnp.float32)
+                  + jnp.einsum("bqhp,bkp->bhqk", q_pe, kpe_all,
+                               preferred_element_type=jnp.float32)
+                  ) * _mla_scale(cfg)
+        mask = jnp.arange(c_all.shape[1])[None, None, :] <= pos[:, :, None]
+        logits = jnp.where(mask[:, None], logits, NEG_INF)
+        probs = jax.nn.softmax(logits, axis=-1)
+        out = jnp.einsum("bhqk,bkhd->bqhd", probs.astype(v.dtype), v)
+        return (_mla_out(p, out, cfg), pack_latent(c_all, kpe_all),
+                kv_length + T)
+
+
+def _absorb(p, q_nope, cfg: ModelConfig):
+    """q_nope [B,H,nope] -> the query in the latent space, [B,H,r]."""
+    w_uk, _ = _kv_b(p, cfg)
+    return jnp.einsum("bhn,rhn->bhr", q_nope, w_uk)
+
+
+def _latent_out(p, o_lat, cfg: ModelConfig):
+    """The weighted latent [B,H,r] -> the sublayer's output [B,1,d]."""
+    _, w_uv = _kv_b(p, cfg)
+    o = jnp.einsum("bhr,rhv->bhv", o_lat.astype(cfg.cdtype), w_uv)
+    return _mla_out(p, o[:, None], cfg)
+
+
+def latent_attend(q_lat, q_pe, c, k_pe, kv_length, scale: float):
+    """Absorbed one-token attention: q_lat [B,H,r] and q_pe [B,H,rope]
+    against c [B,S,r] and k_pe [B,S,rope], the first ``kv_length`` [B]
+    positions valid.  Returns the weighted latent [B,H,r] in q's dtype."""
+    s = (jnp.einsum("bhr,bsr->bhs", q_lat, c,
+                    preferred_element_type=jnp.float32)
+         + jnp.einsum("bhp,bsp->bhs", q_pe, k_pe,
+                      preferred_element_type=jnp.float32)) * scale
+    valid = jnp.arange(c.shape[1])[None, :] < kv_length[:, None]
+    s = jnp.where(valid[:, None, :], s, NEG_INF)
+    probs = jax.nn.softmax(s, axis=-1)
+    out = jnp.einsum("bhs,bsr->bhr", probs.astype(c.dtype), c,
+                     preferred_element_type=jnp.float32)
+    return out.astype(q_lat.dtype)
+
+
+def _mla_scale(cfg: ModelConfig) -> float:
+    return 1.0 / math.sqrt(cfg.qk_nope_head_dim + cfg.qk_rope_head_dim)
+
+
+def mla_decode(p, x, ckv, kv_length, cfg: ModelConfig):
+    """Single-token absorbed decode against a contiguous latent cache
+    [B,Smax/2,2*(r+rope)].  Returns (out [B,1,d], ckv, new_len)."""
+    with jax.named_scope("mla"):
+        B = x.shape[0]
+        pos = kv_length[:, None]
+        q_nope, q_pe, c, k_pe = _mla_project(p, x, pos, cfg)
+        c_all, kpe_all = unpack_latent(ckv, cfg.kv_lora_rank)
+        bidx = jnp.arange(B)
+        c_all = c_all.at[bidx, kv_length].set(c[:, 0].astype(ckv.dtype))
+        kpe_all = kpe_all.at[bidx, kv_length].set(k_pe[:, 0].astype(ckv.dtype))
+        new_len = kv_length + 1
+        o_lat = latent_attend(_absorb(p, q_nope[:, 0], cfg), q_pe[:, 0],
+                              c_all, kpe_all, new_len, _mla_scale(cfg))
+        return (_latent_out(p, o_lat, cfg), pack_latent(c_all, kpe_all),
+                new_len)
+
+
+def _write_latent_rows(store, layer, write_phys, write_off, c, k_pe):
+    """Write each row's position into a paged latent store [L, N, bs/2,
+    2*(r+rope)]: read the row that holds it, replace its half, write the
+    row back (a writable block belongs to one sequence, so no two real
+    rows share a cell's row; padded rows all write the null block)."""
+    B, r = c.shape
+    rope = k_pe.shape[-1]
+    row = store[layer, write_phys, write_off // 2]  # [B, W]
+    half = (jnp.arange(2)[None, :] == (write_off % 2)[:, None])[..., None]
+    cc = jnp.where(half, c[:, None].astype(store.dtype),
+                   row[:, :2 * r].reshape(B, 2, r))
+    kk = jnp.where(half, k_pe[:, None].astype(store.dtype),
+                   row[:, 2 * r:].reshape(B, 2, rope))
+    row = jnp.concatenate([cc.reshape(B, 2 * r), kk.reshape(B, 2 * rope)], -1)
+    return store.at[layer, write_phys, write_off // 2].set(row)
+
+
+def mla_decode_paged(p, x, store, layer, block_tables, kv_length,
+                     write_phys, write_off, cfg: ModelConfig):
+    """Single-token absorbed decode against the stacked paged latent
+    store [L, num_blocks, block_size/2, 2*(r+rope)], as
+    ``attention_decode_paged`` does for K/V: the token's latent is
+    written into its cell of the stack, and attention reads the layer
+    through the block tables, on a TPU with the Pallas latent kernel
+    (``paged_decode_latent``), on the CPU by a jnp table-gather (the
+    kernel in interpret mode where ``attention_impl="pallas"``).
+    Returns (out [B,1,d], store)."""
+    with jax.named_scope("mla"):
+        pos = kv_length[:, None]
+        q_nope, q_pe, c, k_pe = _mla_project(p, x, pos, cfg)
+        store = _write_latent_rows(store, layer, write_phys, write_off,
+                                   c[:, 0], k_pe[:, 0])
+        new_len = kv_length + 1
+        q_lat = _absorb(p, q_nope[:, 0], cfg)
+        interpret = kernels.pallas_interpret()
+        if cfg.attention_impl == "pallas" or not interpret:
+            from repro.kernels.decode_attention import ops as da_ops
+
+            o_lat = da_ops.paged_decode_latent(
+                q_lat, q_pe[:, 0], store, layer, block_tables, new_len,
+                scale=_mla_scale(cfg), interpret=interpret)
+        else:
+            from repro.kernels.decode_attention.ref import gather_blocks
+
+            c_all, kpe_all = unpack_latent(
+                gather_blocks(store[layer], block_tables), cfg.kv_lora_rank)
+            o_lat = latent_attend(q_lat, q_pe[:, 0], c_all, kpe_all,
+                                  new_len, _mla_scale(cfg))
+        return _latent_out(p, o_lat, cfg), store

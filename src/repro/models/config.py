@@ -35,6 +35,16 @@ class ModelConfig:
     attn_chunk_q: int = 1024
     attn_chunk_k: int = 1024
     positions: str = "rope"  # rope | learned | sinusoidal | none
+    # Latent attention (MLA, DeepSeek-V2/V3): kv_lora_rank > 0 turns it on.
+    # Keys and values come from one latent of that rank per position (the
+    # decode cache), queries from a plain projection (no q_lora_rank);
+    # each head's query and key are qk_nope_head_dim wide without rotary
+    # embeddings plus qk_rope_head_dim with them (one rotary key for all
+    # heads), and its value v_head_dim wide.
+    kv_lora_rank: int = 0
+    qk_nope_head_dim: int = 0
+    qk_rope_head_dim: int = 0
+    v_head_dim: int = 0
 
     # MLP
     activation: str = "silu"
@@ -46,9 +56,23 @@ class ModelConfig:
     top_k: int = 0
     first_dense_layers: int = 0  # deepseek-style: first N layers use dense FFN
     dense_ff: int = 0  # d_ff of the dense layers (0 -> n_experts * d_ff heuristics)
+    # routing: "softmax" (deepseek-moe) or "sigmoid" scores over all
+    # experts; with router_bias the top-k is chosen by score plus a
+    # per-expert correction bias (DeepSeek-V3's noaux_tc), the weights
+    # still come from the scores; the chosen weights are renormalised to
+    # sum to 1 and scaled by routed_scaling_factor
+    router_score: str = "softmax"
+    router_bias: bool = False
+    routed_scaling_factor: float = 1.0
+    # the experts this chip holds, [experts_first, experts_first +
+    # experts_held) of the n_experts routed over (0: all of them), as one
+    # expert-parallel shard does; the layer computes their part alone
+    experts_held: int = 0
+    experts_first: int = 0
+    # the expert layer is dropless; these two remain so that configurations
+    # written for the earlier capacity dispatch still load, and are unused
     capacity_factor: float = 1.25
-    decode_capacity_factor: float = 4.0  # decode batches are small; drops hurt
-    moe_impl: str = "auto"  # auto | dense | ep (shard_map + ragged_dot)
+    decode_capacity_factor: float = 4.0
     router_aux_weight: float = 0.01
 
     # SSM (mamba2) / hybrid (zamba2)
@@ -121,6 +145,21 @@ class ModelConfig:
         return self.n_experts > 0
 
     @property
+    def n_held(self) -> int:
+        """Routed experts whose weights this config holds."""
+        return self.experts_held or self.n_experts
+
+    @property
+    def is_mla(self) -> bool:
+        return self.kv_lora_rank > 0
+
+    @property
+    def latent_width(self) -> int:
+        """Cached width of one position of one MLA layer: the normed
+        latent and the rotary key."""
+        return self.kv_lora_rank + self.qk_rope_head_dim
+
+    @property
     def is_attention_free(self) -> bool:
         return self.family == "ssm"
 
@@ -136,12 +175,23 @@ class ModelConfig:
         return dataclasses.replace(self, **overrides)
 
     # -- analytical param count (for roofline MODEL_FLOPS) -------------------
+    def attention_params(self) -> int:
+        """Weights of one layer's attention projections."""
+        d, hd, nh, nkv = self.d_model, self.head_dim, self.n_heads, \
+            self.n_kv_heads
+        if self.is_mla:
+            r, rope = self.kv_lora_rank, self.qk_rope_head_dim
+            nope, v = self.qk_nope_head_dim, self.v_head_dim
+            return (d * nh * (nope + rope) + d * (r + rope) + r
+                    + r * nh * (nope + v) + nh * v * d)
+        return d * nh * hd + 2 * d * nkv * hd + nh * hd * d
+
     def param_count_analytical(self) -> int:
-        """Rough analytical parameter count (embedding + blocks)."""
+        """Rough analytical parameter count (embedding + blocks); of a MoE
+        layer, the experts held."""
         d, ff, v = self.d_model, self.d_ff, self.vocab
-        hd, nh, nkv = self.head_dim, self.n_heads, self.n_kv_heads
         emb = v * d * (1 if self.tie_embeddings else 2)
-        attn = d * nh * hd + 2 * d * nkv * hd + nh * hd * d
+        attn = self.attention_params()
         mlp_dense = d * ff * (3 if self.gated_mlp else 2)
         if self.family == "ssm":  # rwkv6
             att = 4 * d * d + d * d  # r,k,v,g,o approx
@@ -150,16 +200,16 @@ class ModelConfig:
         if self.family == "hybrid":
             d_in = self.ssm_expand * d
             ssm = d * (2 * d_in + 2 * self.ssm_state) + d_in * d
-            n_attn = self.n_layers // max(1, self.attn_every)
             shared = attn + mlp_dense  # one shared block, reused
             return emb + self.n_layers * ssm + shared
         if self.is_moe:
             expert = d * ff * (3 if self.gated_mlp else 2)
             moe_layers = self.n_layers - self.first_dense_layers
-            router = d * self.n_experts
+            router = d * self.n_experts + (self.n_experts
+                                           if self.router_bias else 0)
             total = emb + self.n_layers * attn
             total += moe_layers * (
-                (self.n_experts + self.n_shared_experts) * expert + router
+                (self.n_held + self.n_shared_experts) * expert + router
             )
             dense_ff = self.dense_ff or ff
             total += self.first_dense_layers * d * dense_ff * (3 if self.gated_mlp else 2)
@@ -173,12 +223,13 @@ class ModelConfig:
         return emb + n_blocks * (attn + mlp_dense + cross)
 
     def active_param_count(self) -> int:
-        """Params touched per token (MoE: routed top-k + shared only)."""
+        """Params touched per token (MoE: shared experts, and of the routed
+        ones the top-k, in the share of them held here)."""
         if not self.is_moe:
             return self.param_count_analytical()
         d, ff = self.d_model, self.d_ff
         expert = d * ff * (3 if self.gated_mlp else 2)
         total = self.param_count_analytical()
         moe_layers = self.n_layers - self.first_dense_layers
-        inactive = moe_layers * (self.n_experts - self.top_k) * expert
-        return total - inactive
+        active = self.top_k * self.n_held / self.n_experts
+        return total - round(moe_layers * (self.n_held - active) * expert)

@@ -397,7 +397,8 @@ def hybrid_decode_step(p, cache, tokens, cfg: ModelConfig, *, mesh=None):
             return y, st2
 
         x, new_states = jax.lax.scan(inner, x, (group_params, states))
-        y, new_attn = tfm.block_decode(shared, x, attn_cache, cfg, mesh=mesh)
+        y, new_attn, _ = tfm.block_decode(shared, x, attn_cache, cfg,
+                                          mesh=mesh)
         return y, (new_states, new_attn)
 
     x, (new_ssm, new_attn) = jax.lax.scan(

@@ -1,27 +1,36 @@
-"""Fine-grained mixture-of-experts FFN (deepseek-moe / moonlight style).
+"""Fine-grained mixture-of-experts FFN (DeepSeek-MoE / DeepSeek-V3 style).
 
-Routing: softmax over all experts -> top-k -> renormalize.  Dispatch is
-capacity-based (dropped-token MoE): tokens are scattered into a per-expert
-``[n_local_experts, capacity, d]`` buffer and the expert FFN runs as one
-batched matmul — MXU-shaped, no ragged ops on the hot path.
+Routing runs over all ``n_experts``: softmax or sigmoid scores in float32,
+the top-k chosen by score (plus a per-expert correction bias where the
+config has one, DeepSeek-V3's ``noaux_tc``), their weights the unbiased
+scores, renormalised and scaled (``routed_scaling_factor``).
 
-Expert parallelism: under tensor parallelism the block input is *replicated*
-over the ``model`` mesh axis, so EP needs **no all_to_all** — each model-axis
-device runs the experts it owns over all locally-visible tokens and a single
-``psum`` over ``model`` combines expert outputs (same collective cost as a
-dense TP MLP).  Implemented with ``jax.shard_map``; gating/aux-loss run
-outside in plain GSPMD.
+The layer is told which experts it holds, ``[experts_first,
+experts_first + n_held)``, and is dropless: every (token, expert) pair
+that falls on a held expert is computed, whatever else is in the batch,
+and the others add nothing.  The assignments are sorted by expert and
+the held experts run as one grouped matrix product over them: on a TPU
+the Pallas grouped matmul (``megablox.gmm``, a ``gmm`` kernel in the
+device trace), elsewhere ``jax.lax.ragged_dot``.  No capacity buffer,
+nothing padded to a capacity.
 
-Shared experts (deepseek: 2) are a dense TP MLP with ``ff = n_shared * d_ff``.
+Expert parallelism: under tensor parallelism the block input is
+*replicated* over the ``model`` mesh axis, so each model-axis device
+runs the held experts it owns over all locally-visible tokens and one
+``psum`` over ``model`` adds their parts (the same collective cost as a
+dense TP MLP).  One chip holding an expert-parallel shard runs the same
+layer without the exchange.
+
+Shared experts (DeepSeek: 2) are a dense MLP with
+``ff = n_shared * d_ff``, added once.
 """
 from __future__ import annotations
-
-import math
-from functools import partial
 
 import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
+
+from repro import kernels
 
 from . import nn
 from .config import ModelConfig
@@ -31,23 +40,25 @@ BATCH_AXES = ("pod", "data")
 
 def moe_init(key, cfg: ModelConfig):
     ks = jax.random.split(key, 5)
-    d, ff, E = cfg.d_model, cfg.d_ff, cfg.n_experts
+    d, ff, E, El = cfg.d_model, cfg.d_ff, cfg.n_experts, cfg.n_held
     dt = cfg.pdtype
     # expert-internal dims get their own (replicated) logical axes: the
     # expert dim itself carries the "model" sharding (EP), so d/ff must not
     # also map to "model"
+    router = {"w": nn.Px(nn.lecun_init(ks[0], (d, E), jnp.float32, d),
+                         ("embed", "router_experts"))}
+    if cfg.router_bias:
+        router["bias"] = nn.Px(jnp.zeros((E,), jnp.float32),
+                               ("router_experts",))
     p = {
-        "router": {
-            "w": nn.Px(nn.lecun_init(ks[0], (d, E), jnp.float32, d),
-                       ("embed", "router_experts")),
-        },
-        "up": nn.Px(nn.lecun_init(ks[1], (E, d, ff), dt, d),
+        "router": router,
+        "up": nn.Px(nn.lecun_init(ks[1], (El, d, ff), dt, d),
                     ("experts", "expert_in", "expert_ff")),
-        "down": nn.Px(nn.lecun_init(ks[2], (E, ff, d), dt, ff),
+        "down": nn.Px(nn.lecun_init(ks[2], (El, ff, d), dt, ff),
                       ("experts", "expert_ff", "expert_in")),
     }
     if cfg.gated_mlp:
-        p["gate"] = nn.Px(nn.lecun_init(ks[3], (E, d, ff), dt, d),
+        p["gate"] = nn.Px(nn.lecun_init(ks[3], (El, d, ff), dt, d),
                           ("experts", "expert_in", "expert_ff"))
     if cfg.n_shared_experts > 0:
         p["shared"] = nn.mlp_init(ks[4], d, cfg.n_shared_experts * ff,
@@ -60,83 +71,96 @@ def moe_init(key, cfg: ModelConfig):
 # ---------------------------------------------------------------------------
 
 
-def route(router_w, x_flat, cfg: ModelConfig):
-    """Returns (weights [T,k], idx [T,k], aux_loss scalar)."""
-    logits = x_flat.astype(jnp.float32) @ router_w.astype(jnp.float32)
-    probs = jax.nn.softmax(logits, axis=-1)  # [T, E]
-    top_p, top_i = jax.lax.top_k(probs, cfg.top_k)
-    top_p = top_p / jnp.maximum(top_p.sum(-1, keepdims=True), 1e-9)
+def route(router, x_flat, cfg: ModelConfig):
+    """Returns (weights [T,k], idx [T,k], aux_loss scalar); ``router`` is
+    the router's params (``w`` [d, E], and ``bias`` [E] where the config
+    has one)."""
+    logits = x_flat.astype(jnp.float32) @ router["w"].astype(jnp.float32)
+    if cfg.router_score == "sigmoid":
+        scores = jax.nn.sigmoid(logits)
+    else:
+        scores = jax.nn.softmax(logits, axis=-1)  # [T, E]
+    choice = scores + router["bias"] if "bias" in router else scores
+    _, top_i = jax.lax.top_k(choice, cfg.top_k)
+    top_p = jnp.take_along_axis(scores, top_i, axis=-1)
+    top_p = top_p / (top_p.sum(-1, keepdims=True) + 1e-20)
+    top_p = top_p * cfg.routed_scaling_factor
     # load-balancing aux (Switch-style): E * sum_e f_e * p_e
     E = cfg.n_experts
     T = x_flat.shape[0]
     f = jnp.zeros((E,), jnp.float32).at[top_i.reshape(-1)].add(1.0)
     f = f / (T * cfg.top_k)
-    pbar = probs.mean(axis=0)
+    pbar = (scores / scores.sum(-1, keepdims=True)).mean(axis=0)
     aux = E * jnp.sum(f * pbar)
     return top_p, top_i, aux
 
 
-def _capacity(T: int, cfg: ModelConfig, decode: bool) -> int:
-    cf = max(cfg.decode_capacity_factor, cfg.capacity_factor) if decode \
-        else cfg.capacity_factor
-    c = math.ceil(T * cfg.top_k / cfg.n_experts * cf)
-    return max(1, c)
-
-
 # ---------------------------------------------------------------------------
-# Expert compute over a local expert range
+# Held experts, dropless
 # ---------------------------------------------------------------------------
 
+GMM_ROWS = 128  # the grouped kernel's row tile
 
-def _expert_compute(x_flat, top_w, top_i, up, gate, down, *, expert_offset,
-                    n_local: int, capacity: int, cfg: ModelConfig):
-    """Dropped-token expert FFN over experts [offset, offset+n_local).
 
-    x_flat [T,d]; top_w/top_i [T,k]; up/gate [El,d,ff], down [El,ff,d].
-    Returns y_flat [T,d] (only local experts' contributions).
-    """
+def _gmm_tiles(k: int, n: int) -> tuple:
+    """Row, contraction and output tiles of the grouped kernel: the whole
+    of k and n where one bf16 weight tile stays under 3 MiB, else halves."""
+    tk, tn = k, n
+    while tk * tn * 2 > 3 * 2**20 and tk % 256 == 0:
+        tk //= 2
+    while tk * tn * 2 > 3 * 2**20 and tn % 256 == 0:
+        tn //= 2
+    return GMM_ROWS, tk, tn
+
+
+def grouped_matmul(x, w, sizes):
+    """Rows of ``x`` [m, k], sorted by group, times their group's matrix
+    of ``w`` [G, k, n]: group g holds the ``sizes[g]`` rows after those of
+    the groups before it; rows past all groups come out as zeros or
+    garbage, for the caller to mask.  Returns [m, n] in x's dtype."""
+    if kernels.pallas_interpret():
+        return jax.lax.ragged_dot(x, w, sizes)
+    from jax.experimental.pallas.ops.tpu.megablox import gmm
+
+    return gmm(x, w, sizes, preferred_element_type=x.dtype,
+               tiling=_gmm_tiles(w.shape[1], w.shape[2]))
+
+
+def held_experts(x_flat, top_w, top_i, up, gate, down, *, first,
+                 cfg: ModelConfig, valid=None):
+    """The held experts' part of the layer, dropless.
+
+    x_flat [T,d]; top_w/top_i [T,k] over all experts; up/gate [El,d,ff],
+    down [El,ff,d], the experts ``first .. first + El - 1``; ``valid``
+    [T] bool, where False a token routes to none of them (a padded row).
+    Returns (y [T,d] in the compute dtype, counts [El] int32: the
+    (token, expert) pairs each held expert computed)."""
     T, d = x_flat.shape
     k = top_i.shape[1]
-    E = cfg.n_experts
-    C = capacity
+    El = up.shape[0]
     cd = cfg.cdtype
-
-    flat_e = top_i.reshape(-1)  # [T*k] global expert ids
-    flat_t = jnp.repeat(jnp.arange(T), k)
-    flat_w = top_w.reshape(-1)
-
-    # rank of each assignment within its (global) expert group
-    order = jnp.argsort(flat_e)
-    sorted_e = flat_e[order]
-    counts = jnp.bincount(flat_e, length=E)
-    starts = jnp.concatenate([jnp.zeros((1,), counts.dtype),
-                              jnp.cumsum(counts)[:-1]])
-    rank_sorted = jnp.arange(T * k) - starts[sorted_e]
-    rank = jnp.zeros((T * k,), jnp.int32).at[order].set(
-        rank_sorted.astype(jnp.int32))
-
-    local_e = flat_e - expert_offset
-    is_local = (local_e >= 0) & (local_e < n_local)
-    keep = is_local & (rank < C)
-    dest = jnp.where(keep, local_e * C + rank, n_local * C)  # drop row at end
-
-    buf = jnp.zeros((n_local * C + 1, d), cd)
-    buf = buf.at[dest].set(x_flat.astype(cd)[flat_t])
-    h_in = buf[: n_local * C].reshape(n_local, C, d)
-
-    up_h = jnp.einsum("ecd,edf->ecf", h_in, up.astype(cd))
+    local = top_i.reshape(-1) - first  # [T*k]
+    held = (local >= 0) & (local < El)
+    if valid is not None:
+        held = held & jnp.repeat(valid, k)
+    group = jnp.where(held, local, El)  # not held: after every group
+    order = jnp.argsort(group)
+    counts = jnp.bincount(group, length=El + 1)[:El].astype(jnp.int32)
+    rows = -(-T * k // GMM_ROWS) * GMM_ROWS
+    tok = jnp.pad(order // k, (0, rows - T * k))
+    mask = jnp.pad(held[order], (0, rows - T * k))[:, None]
+    xs = x_flat.astype(cd)[tok]  # [rows, d], sorted by held expert
+    act = nn.ACTIVATIONS[cfg.activation]
+    h = grouped_matmul(xs, up.astype(cd), counts)
     if gate is not None:
-        act = nn.ACTIVATIONS[cfg.activation]
-        h = act(jnp.einsum("ecd,edf->ecf", h_in, gate.astype(cd))) * up_h
+        h = act(grouped_matmul(xs, gate.astype(cd), counts)) * h
     else:
-        h = nn.ACTIVATIONS[cfg.activation](up_h)
-    out = jnp.einsum("ecf,efd->ecd", h, down.astype(cd))  # [El,C,d]
-    out = out.reshape(n_local * C, d)
-    out = jnp.concatenate([out, jnp.zeros((1, d), cd)], axis=0)
-
-    contrib = out[dest] * flat_w.astype(cd)[:, None] * keep.astype(cd)[:, None]
-    y = jnp.zeros((T, d), cd).at[flat_t].add(contrib)
-    return y
+        h = act(h)
+    out = grouped_matmul(h, down.astype(cd), counts)  # [rows, d]
+    w = jnp.pad(top_w.reshape(-1)[order], (0, rows - T * k))
+    out = jnp.where(mask, out * w.astype(cd)[:, None], 0)
+    y = jnp.zeros((T, d), cd).at[tok].add(out)
+    return y, counts
 
 
 # ---------------------------------------------------------------------------
@@ -144,77 +168,50 @@ def _expert_compute(x_flat, top_w, top_i, up, gate, down, *, expert_offset,
 # ---------------------------------------------------------------------------
 
 
-def moe_apply(p, x, cfg: ModelConfig, *, mesh=None, decode: bool = False):
-    """x [B,S,d] -> (y [B,S,d], aux scalar)."""
-    B, S, d = x.shape
-    T = B * S
-    x_flat = x.reshape(T, d)
-    top_w, top_i, aux = route(p["router"]["w"], x_flat, cfg)
-    C = _capacity(T, cfg, decode)
-    gate = p.get("gate")
+def moe_apply(p, x, cfg: ModelConfig, *, mesh=None, valid=None):
+    """x [B,S,d] -> (y [B,S,d], aux scalar, counts [n_held] int32).
 
-    use_ep = (
-        mesh is not None
-        and "model" in mesh.axis_names
-        and cfg.moe_impl in ("auto", "ep")
-        and cfg.n_experts % mesh.shape["model"] == 0
-    )
-    if use_ep:
-        n_model = mesh.shape["model"]
-        n_local = cfg.n_experts // n_model
-        batch_axes = tuple(a for a in BATCH_AXES if a in mesh.axis_names)
+    ``valid`` [B,S] bool marks the real tokens (padded ones route to no
+    held expert); ``counts`` are the (token, expert) pairs each held
+    expert computed."""
+    with jax.named_scope("moe"):
+        B, S, d = x.shape
+        T = B * S
+        x_flat = x.reshape(T, d)
+        top_w, top_i, aux = route(p["router"], x_flat, cfg)
+        vflat = None if valid is None else valid.reshape(T)
+        gate = p.get("gate")
+        n_model = (mesh.shape["model"] if mesh is not None
+                   and "model" in mesh.axis_names else 1)
+        use_ep = n_model > 1 and cfg.n_held % n_model == 0
+        if use_ep:
+            n_local = cfg.n_held // n_model
+            batch_axes = tuple(a for a in BATCH_AXES if a in mesh.axis_names)
 
-        def local_fn(xf, tw, ti, up, gt, dn):
-            j = jax.lax.axis_index("model")
-            c_loc = _capacity(xf.shape[0], cfg, decode)
-            y = _expert_compute(xf, tw, ti, up,
-                                gt if gate is not None else None, dn,
-                                expert_offset=j * n_local, n_local=n_local,
-                                capacity=c_loc, cfg=cfg)
-            return jax.lax.psum(y, "model")
+            def local_fn(xf, tw, ti, up, gt, dn):
+                j = jax.lax.axis_index("model")
+                y, c = held_experts(
+                    xf, tw, ti, up, gt if gate is not None else None, dn,
+                    first=cfg.experts_first + j * n_local, cfg=cfg)
+                if batch_axes:
+                    c = jax.lax.psum(c, batch_axes)
+                return jax.lax.psum(y, "model"), c
 
-        tok = P(batch_axes if batch_axes else None, None)
-        espec = P("model", None, None)
-        gate_arg = gate if gate is not None else p["up"]  # placeholder, unused
-        y_flat = jax.shard_map(
-            local_fn, mesh=mesh,
-            in_specs=(tok, tok, tok, espec, espec, espec),
-            out_specs=tok,
-        )(x_flat, top_w, top_i, p["up"], gate_arg, p["down"])
-    else:
-        # local capacity should reflect the *local* token count
-        y_flat = _expert_compute(x_flat, top_w, top_i, p["up"], gate,
-                                 p["down"], expert_offset=0,
-                                 n_local=cfg.n_experts, capacity=C, cfg=cfg)
-
-    y = y_flat.reshape(B, S, d)
-    if "shared" in p:
-        y = y + nn.mlp_apply(p["shared"], x, activation=cfg.activation,
-                             compute_dtype=cfg.cdtype)
-    return y.astype(x.dtype), aux
-
-
-# ---------------------------------------------------------------------------
-# Dropless reference (tests only; loops over experts)
-# ---------------------------------------------------------------------------
-
-
-def moe_reference(p, x, cfg: ModelConfig):
-    B, S, d = x.shape
-    x_flat = x.reshape(-1, d)
-    top_w, top_i, aux = route(p["router"]["w"], x_flat, cfg)
-    act = nn.ACTIVATIONS[cfg.activation]
-    y = jnp.zeros_like(x_flat, jnp.float32)
-    for e in range(cfg.n_experts):
-        w_e = jnp.where(top_i == e, top_w, 0.0).sum(-1)  # [T]
-        up = x_flat @ p["up"][e]
-        if "gate" in p:
-            h = act(x_flat @ p["gate"][e]) * up
+            tok = P(batch_axes if batch_axes else None, None)
+            espec = P("model", None, None)
+            gate_arg = gate if gate is not None else p["up"]  # unused
+            y_flat, counts = jax.shard_map(
+                local_fn, mesh=mesh,
+                in_specs=(tok, tok, tok, espec, espec, espec),
+                out_specs=(tok, P("model")),
+            )(x_flat, top_w, top_i, p["up"], gate_arg, p["down"])
         else:
-            h = act(up)
-        out = h @ p["down"][e]
-        y = y + out.astype(jnp.float32) * w_e[:, None]
-    y = y.reshape(B, S, d)
-    if "shared" in p:
-        y = y + nn.mlp_apply(p["shared"], x, activation=cfg.activation)
-    return y.astype(x.dtype), aux
+            y_flat, counts = held_experts(
+                x_flat, top_w, top_i, p["up"], gate, p["down"],
+                first=cfg.experts_first, cfg=cfg, valid=vflat)
+
+        y = y_flat.reshape(B, S, d)
+        if "shared" in p:
+            y = y + nn.mlp_apply(p["shared"], x, activation=cfg.activation,
+                                 compute_dtype=cfg.cdtype)
+        return y.astype(x.dtype), aux, counts

@@ -1,8 +1,9 @@
 """Decoder-only and encoder-decoder transformer LMs.
 
 Covers the dense archs (qwen1.5-0.5b, qwen3-8b, llama3.2-3b, nemotron-4-340b),
-the MoE archs (via ``repro.models.moe`` FFN plug-in), whisper-small (enc-dec)
-and internvl2-1b (vision-prefix LM).
+the MoE archs (via ``repro.models.moe`` FFN plug-in; moonlight-16b-a3b also
+with latent attention, ``attention.mla_*``), whisper-small (enc-dec) and
+internvl2-1b (vision-prefix LM).
 
 Stack layout: an optional short list of "pre" blocks (e.g. deepseek's first
 dense layer) followed by a homogeneous stack of blocks applied with
@@ -48,7 +49,8 @@ def block_init(key, cfg: ModelConfig, *, layer_idx: int = 0,
     dt = cfg.pdtype
     p = {
         "ln_attn": nn.rmsnorm_init(cfg.d_model, dtype=dt),
-        "attn": attn.attention_init(ks[0], cfg),
+        "attn": (attn.mla_init(ks[0], cfg) if cfg.is_mla
+                 else attn.attention_init(ks[0], cfg)),
         "ln_mlp": nn.rmsnorm_init(cfg.d_model, dtype=dt),
     }
     use_moe = cfg.is_moe and layer_idx >= cfg.first_dense_layers
@@ -77,11 +79,16 @@ def _gather_seq(x, cfg, mesh):
     return nn.constrain(x, mesh, nn.batch_pspec(mesh, x.shape[0]))
 
 
-def _ffn(p, x, cfg: ModelConfig, mesh, decode):
+def _ffn(p, x, cfg: ModelConfig, mesh, valid=None):
+    """The FFN sublayer: (x + out, aux loss, held experts' assignment
+    counts [n_held] or, for a dense MLP, None).  ``valid`` [B,S] marks
+    the real tokens, which alone a MoE layer routes."""
     sp = _sp_on(cfg, mesh, x)
     h = nn.rmsnorm_apply(p["ln_mlp"], _gather_seq(x, cfg, mesh), cfg.norm_eps)
+    counts = None
     if "moe" in p:
-        h, aux = moe_lib.moe_apply(p["moe"], h, cfg, mesh=mesh, decode=decode)
+        h, aux, counts = moe_lib.moe_apply(p["moe"], h, cfg, mesh=mesh,
+                                           valid=valid)
         if sp:
             from jax.sharding import PartitionSpec as P
 
@@ -93,7 +100,7 @@ def _ffn(p, x, cfg: ModelConfig, mesh, decode):
                          explicit_tp=cfg.explicit_tp, fsdp=cfg.fsdp_params,
                          seq_shard=sp)
         aux = jnp.zeros((), jnp.float32)
-    return x + h, aux
+    return x + h, aux, counts
 
 
 def block_apply(p, x, cfg: ModelConfig, *, causal=True, positions=None,
@@ -102,17 +109,21 @@ def block_apply(p, x, cfg: ModelConfig, *, causal=True, positions=None,
     sp = _sp_on(cfg, mesh, x)
     h = nn.rmsnorm_apply(p["ln_attn"], _gather_seq(x, cfg, mesh),
                          cfg.norm_eps)
-    h = attn.attention_apply(p["attn"], h, cfg, causal=causal,
-                             positions=positions,
-                             rope=cfg.positions == "rope", mesh=mesh,
-                             seq_shard=sp)
+    if cfg.is_mla:
+        h, _ = attn.mla_apply(p["attn"], h, cfg, positions=positions)
+    else:
+        h = attn.attention_apply(p["attn"], h, cfg, causal=causal,
+                                 positions=positions,
+                                 rope=cfg.positions == "rope", mesh=mesh,
+                                 seq_shard=sp)
     x = x + h
     if "cross" in p and enc_out is not None:
         h = nn.rmsnorm_apply(p["ln_cross"], x, cfg.norm_eps)
         h = attn.attention_apply(p["cross"], h, cfg, causal=False,
                                  x_kv=enc_out, rope=False, mesh=mesh)
         x = x + h
-    return _ffn(p, x, cfg, mesh, decode=False)
+    y, aux, _ = _ffn(p, x, cfg, mesh)
+    return y, aux
 
 
 def block_prefill(p, x, cfg: ModelConfig, *, max_len: int, positions=None,
@@ -120,14 +131,16 @@ def block_prefill(p, x, cfg: ModelConfig, *, max_len: int, positions=None,
     """Prefill forward; returns (y, cache dict with padded KV)."""
     B, S, _ = x.shape
     h = nn.rmsnorm_apply(p["ln_attn"], x, cfg.norm_eps)
-    h, (k, v) = attn.attention_prefill(p["attn"], h, cfg, positions=positions,
-                                       mesh=mesh)
-    pad = max_len - S
-    cache = {
-        "k": jnp.pad(k, ((0, 0), (0, pad), (0, 0), (0, 0))),
-        "v": jnp.pad(v, ((0, 0), (0, pad), (0, 0), (0, 0))),
-        "len": jnp.full((B,), S, jnp.int32),
-    }
+    pad = ((0, 0), (0, max_len - S), (0, 0))
+    if cfg.is_mla:
+        h, (c, k_pe) = attn.mla_apply(p["attn"], h, cfg, positions=positions)
+        cache = {"ckv": attn.pack_latent(jnp.pad(c, pad), jnp.pad(k_pe, pad))}
+    else:
+        h, (k, v) = attn.attention_prefill(p["attn"], h, cfg,
+                                           positions=positions, mesh=mesh)
+        cache = {"k": jnp.pad(k, pad + ((0, 0),)),
+                 "v": jnp.pad(v, pad + ((0, 0),))}
+    cache["len"] = jnp.full((B,), S, jnp.int32)
     x = x + h
     if "cross" in p and enc_out is not None:
         h = nn.rmsnorm_apply(p["ln_cross"], x, cfg.norm_eps)
@@ -135,16 +148,22 @@ def block_prefill(p, x, cfg: ModelConfig, *, max_len: int, positions=None,
         cache["cross_k"] = ck
         cache["cross_v"] = cv
         x = x + hq
-    y, _ = _ffn(p, x, cfg, mesh, decode=True)
+    y, _, _ = _ffn(p, x, cfg, mesh)
     return y, cache
 
 
-def block_decode(p, x, cache, cfg: ModelConfig, *, mesh=None):
-    """Single-token decode; cross-attn reads precomputed cross K/V."""
+def block_decode(p, x, cache, cfg: ModelConfig, *, mesh=None, valid=None):
+    """Single-token decode; cross-attn reads precomputed cross K/V.
+    Returns (y, cache, held experts' counts or None)."""
     h = nn.rmsnorm_apply(p["ln_attn"], x, cfg.norm_eps)
-    h, ck, cv, clen = attn.attention_decode(
-        p["attn"], h, cache["k"], cache["v"], cache["len"], cfg)
-    cache = dict(cache, k=ck, v=cv, len=clen)
+    if cfg.is_mla:
+        h, ckv, clen = attn.mla_decode(p["attn"], h, cache["ckv"],
+                                       cache["len"], cfg)
+        cache = dict(cache, ckv=ckv, len=clen)
+    else:
+        h, ck, cv, clen = attn.attention_decode(
+            p["attn"], h, cache["k"], cache["v"], cache["len"], cfg)
+        cache = dict(cache, k=ck, v=cv, len=clen)
     x = x + h
     if "cross" in p and "cross_k" in cache:
         h = nn.rmsnorm_apply(p["ln_cross"], x, cfg.norm_eps)
@@ -156,40 +175,56 @@ def block_decode(p, x, cache, cfg: ModelConfig, *, mesh=None):
         o = attn.decode_attention(q, cache["cross_k"], cache["cross_v"], kv_len)
         o = o.reshape(B, 1, cfg.padded_heads * cfg.head_dim)
         x = x + nn.linear_apply(p["cross"]["o"], o, cfg.cdtype)
-    y, _ = _ffn(p, x, cfg, mesh, decode=True)
-    return y, cache
+    y, _, counts = _ffn(p, x, cfg, mesh, valid)
+    return y, cache, counts
 
 
 def block_decode_paged(p, x, store, layer, block_tables, lens, write_phys,
-                       write_off, cfg: ModelConfig, *, mesh=None):
+                       write_off, cfg: ModelConfig, *, mesh=None,
+                       valid=None):
     """Single-token decode of layer ``layer`` against the stacked paged
-    K/V store.
+    store.
 
     ``store`` holds the block-paged k/v of every layer, shape [L,
-    num_blocks, block_size, Hkv, D]; this layer's token K/V is written
-    into it and attention reads the layer from it, so the store is
-    returned whole (any other leaf, such as the template's "len",
-    passes through untouched: lengths live host-side in the engine and
-    arrive as ``lens``).  Only dense/moe stacks run paged, so there is
-    no cross-attention branch."""
+    num_blocks, block_size, Hkv, D] (or, for latent attention, the
+    packed latent rows ``ckv`` [L, num_blocks, block_size/2,
+    2*(r+rope)]); this layer's token entry is written into it and
+    attention reads the layer from it, so the store is returned whole
+    (any other leaf, such as the template's "len", passes through
+    untouched: lengths live host-side in the engine and arrive as
+    ``lens``).  Only dense/moe stacks run paged, so there is no
+    cross-attention branch.  Returns (y, store, held experts' counts or
+    None)."""
     h = nn.rmsnorm_apply(p["ln_attn"], x, cfg.norm_eps)
-    h, ck, cv = attn.attention_decode_paged(
-        p["attn"], h, store["k"], store["v"], layer, block_tables, lens,
-        write_phys, write_off, cfg)
-    store = dict(store, k=ck, v=cv)
+    if cfg.is_mla:
+        h, ckv = attn.mla_decode_paged(
+            p["attn"], h, store["ckv"], layer, block_tables, lens,
+            write_phys, write_off, cfg)
+        store = dict(store, ckv=ckv)
+    else:
+        h, ck, cv = attn.attention_decode_paged(
+            p["attn"], h, store["k"], store["v"], layer, block_tables, lens,
+            write_phys, write_off, cfg)
+        store = dict(store, k=ck, v=cv)
     x = x + h
-    y, _ = _ffn(p, x, cfg, mesh, decode=True)
-    return y, store
+    y, _, counts = _ffn(p, x, cfg, mesh, valid)
+    return y, store, counts
 
 
-def block_extend(p, x, cache, cfg: ModelConfig, *, mesh=None):
+def block_extend(p, x, cache, cfg: ModelConfig, *, mesh=None, valid=None):
     """Multi-token cache extension (chunked prefill): x [B,T,d] appended
     at cache positions len..len+T-1.  Cross-attn reads precomputed cross
-    K/V, mirroring ``block_decode``."""
+    K/V, mirroring ``block_decode``.  Returns (y, cache, held experts'
+    counts or None)."""
     h = nn.rmsnorm_apply(p["ln_attn"], x, cfg.norm_eps)
-    h, ck, cv, clen = attn.attention_extend(
-        p["attn"], h, cache["k"], cache["v"], cache["len"], cfg)
-    cache = dict(cache, k=ck, v=cv, len=clen)
+    if cfg.is_mla:
+        h, ckv, clen = attn.mla_extend(p["attn"], h, cache["ckv"],
+                                       cache["len"], cfg)
+        cache = dict(cache, ckv=ckv, len=clen)
+    else:
+        h, ck, cv, clen = attn.attention_extend(
+            p["attn"], h, cache["k"], cache["v"], cache["len"], cfg)
+        cache = dict(cache, k=ck, v=cv, len=clen)
     x = x + h
     if "cross" in p and "cross_k" in cache:
         h = nn.rmsnorm_apply(p["ln_cross"], x, cfg.norm_eps)
@@ -201,8 +236,8 @@ def block_extend(p, x, cache, cfg: ModelConfig, *, mesh=None):
                                 causal=False)
         o = o.reshape(B, T, cfg.padded_heads * cfg.head_dim)
         x = x + nn.linear_apply(p["cross"]["o"], o, cfg.cdtype)
-    y, _ = _ffn(p, x, cfg, mesh, decode=True)
-    return y, cache
+    y, _, counts = _ffn(p, x, cfg, mesh, valid)
+    return y, cache, counts
 
 
 def _cross_prefill(p, x, enc_out, cfg):
@@ -476,8 +511,13 @@ def prefill(p, batch, cfg: ModelConfig, *, max_len: int, mesh=None,
     return cache, logits
 
 
-def extend_step(p, cache, tokens, cfg: ModelConfig, *, mesh=None):
+def extend_step(p, cache, tokens, cfg: ModelConfig, *, mesh=None,
+                valid=None):
     """Chunked cache extension; tokens [B, T] -> (cache, logits [B, T, vocab]).
+
+    With ``valid`` [B, T] (the real tokens; a MoE model's paged steps
+    give it) padded tokens route to no expert and a third output is the
+    held experts' assignment counts per MoE layer, [n_moe, n_held].
 
     The T-token generalization of ``decode_step``: the chunk is written
     into the cache at positions len..len+T-1 and logits come back for
@@ -496,25 +536,31 @@ def extend_step(p, cache, tokens, cfg: ModelConfig, *, mesh=None):
 
     new_pre = {}
     for name in _pre_names(p):
-        x, c = block_extend(p["pre"][name], x, cache["pre"][name], cfg,
-                            mesh=mesh)
+        x, c, _ = block_extend(p["pre"][name], x, cache["pre"][name], cfg,
+                               mesh=mesh, valid=valid)
         new_pre[name] = c
 
     def scan_body(x, layer):
         layer_params, layer_cache = layer
-        y, c = block_extend(layer_params, x, layer_cache, cfg, mesh=mesh)
-        return y, c
+        y, c, counts = block_extend(layer_params, x, layer_cache, cfg,
+                                    mesh=mesh, valid=valid)
+        return y, (c, counts)
 
-    x, new_scan = jax.lax.scan(scan_body, x, (p["blocks"], cache["scan"]))
+    x, (new_scan, counts) = jax.lax.scan(scan_body, x,
+                                         (p["blocks"], cache["scan"]))
     new_cache = {"scan": new_scan}
     if new_pre:
         new_cache["pre"] = new_pre
     logits = _logits(p, x, cfg)
+    if valid is not None:
+        return new_cache, logits, counts
     return new_cache, logits
 
 
-def decode_step(p, cache, tokens, cfg: ModelConfig, *, mesh=None):
-    """One decode step; tokens [B] int32 -> (cache, logits [B, vocab])."""
+def decode_step(p, cache, tokens, cfg: ModelConfig, *, mesh=None,
+                valid=None):
+    """One decode step; tokens [B] int32 -> (cache, logits [B, vocab]);
+    ``valid`` [B] as for ``extend_step``, with the same third output."""
     x = nn.embedding_apply(p["embed"], tokens[:, None], cfg.cdtype, mesh=mesh)
     if cfg.positions == "learned":
         # current position = cache length of first scanned layer
@@ -523,27 +569,32 @@ def decode_step(p, cache, tokens, cfg: ModelConfig, *, mesh=None):
         tab = p["pos_embed"]["table"].astype(x.dtype)
         x = x + jnp.take(tab, pos, axis=0)[:, None, :]
 
+    vrow = None if valid is None else valid[:, None]
     new_pre = {}
     for name in _pre_names(p):
-        x, c = block_decode(p["pre"][name], x, cache["pre"][name], cfg,
-                            mesh=mesh)
+        x, c, _ = block_decode(p["pre"][name], x, cache["pre"][name], cfg,
+                               mesh=mesh, valid=vrow)
         new_pre[name] = c
 
     def scan_body(x, layer):
         layer_params, layer_cache = layer
-        y, c = block_decode(layer_params, x, layer_cache, cfg, mesh=mesh)
-        return y, c
+        y, c, counts = block_decode(layer_params, x, layer_cache, cfg,
+                                    mesh=mesh, valid=vrow)
+        return y, (c, counts)
 
-    x, new_scan = jax.lax.scan(scan_body, x, (p["blocks"], cache["scan"]))
+    x, (new_scan, counts) = jax.lax.scan(scan_body, x,
+                                         (p["blocks"], cache["scan"]))
     new_cache = {"scan": new_scan}
     if new_pre:
         new_cache["pre"] = new_pre
     logits = _logits(p, x, cfg)[:, 0]
+    if valid is not None:
+        return new_cache, logits, counts
     return new_cache, logits
 
 
 def paged_decode_step(p, store, block_tables, lens, tokens, write_phys,
-                      write_off, cfg: ModelConfig, *, mesh=None):
+                      write_off, cfg: ModelConfig, *, mesh=None, valid=None):
     """One decode step directly on the block-paged physical store.
 
     The paged analogue of ``decode_step``: ``store`` is the engine's
@@ -560,32 +611,37 @@ def paged_decode_step(p, store, block_tables, lens, tokens, write_phys,
     carried stack and the kernel reads its layer from it, so the donated
     store is written in place, with no per-layer slice or update-slice
     and no second stacked store.  An unscanned ``pre`` layer is a stack
-    of one.  Returns (store, logits [B, vocab])."""
+    of one.  Returns (store, logits [B, vocab]); ``valid`` [B] as for
+    ``extend_step``, with the same third output."""
     x = nn.embedding_apply(p["embed"], tokens[:, None], cfg.cdtype, mesh=mesh)
     if cfg.positions == "learned":
         tab = p["pos_embed"]["table"].astype(x.dtype)
         x = x + jnp.take(tab, lens, axis=0)[:, None, :]
-    step = functools.partial(block_decode_paged, block_tables=block_tables,
-                             lens=lens, write_phys=write_phys,
-                             write_off=write_off, cfg=cfg, mesh=mesh)
+    step = functools.partial(
+        block_decode_paged, block_tables=block_tables, lens=lens,
+        write_phys=write_phys, write_off=write_off, cfg=cfg, mesh=mesh,
+        valid=None if valid is None else valid[:, None])
 
     new_pre = {}
     for name in _pre_names(p):
         one = jax.tree.map(lambda a: a[None], store["pre"][name])
-        x, one = step(p["pre"][name], x, one, 0)
+        x, one, _ = step(p["pre"][name], x, one, 0)
         new_pre[name] = jax.tree.map(lambda a: a[0], one)
 
     def scan_body(carry, layer):
         x, scan_store = carry
         layer_params, idx = layer
-        return step(layer_params, x, scan_store, idx), None
+        x, scan_store, counts = step(layer_params, x, scan_store, idx)
+        return (x, scan_store), counts
 
-    n_scan = store["scan"]["k"].shape[0]
-    (x, new_scan), _ = jax.lax.scan(
+    n_scan = store["scan"]["len"].shape[0]
+    (x, new_scan), counts = jax.lax.scan(
         scan_body, (x, store["scan"]),
         (p["blocks"], jnp.arange(n_scan, dtype=jnp.int32)))
     new_store = {"scan": new_scan}
     if new_pre:
         new_store["pre"] = new_pre
     logits = _logits(p, x, cfg)[:, 0]
+    if valid is not None:
+        return new_store, logits, counts
     return new_store, logits

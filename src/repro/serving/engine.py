@@ -198,6 +198,21 @@ class EngineStats:
     # and the autoscaler were missing when only peaks were reported
     free_blocks: int = 0
     reserved_blocks: int = 0
+    # MoE (paged steps): (token, expert) pairs of the real tokens of
+    # decode calls and prefill chunks that fell on the experts this engine
+    # holds, summed over MoE layers; and, per call and MoE layer, the
+    # busiest held expert's count, summed: their ratio to the mean
+    # (``expert_assignments`` / held experts) is the imbalance
+    expert_assignments: int = 0
+    expert_assignments_max: int = 0
+
+
+def _moe_valid(cfg: ModelConfig, wphys):
+    """A MoE model's real tokens in a paged step, where padded rows and
+    chunk positions write the null block 0; given to the model, they
+    alone are routed and counted.  None for a dense model, whose steps
+    return no counts."""
+    return (wphys != 0) if cfg.is_moe else None
 
 
 def paged_extend_step(api: ModelApi, cfg: ModelConfig, params, store, bt,
@@ -205,12 +220,15 @@ def paged_extend_step(api: ModelApi, cfg: ModelConfig, params, store, bt,
     """One prefill chunk on the paged store: gather the sequences' block
     views, extend them by ``tokens`` [B, T], and scatter the T new K/V
     rows into their (``wphys``, ``woff``) cells.  Returns (store, logits
-    [B, T, vocab])."""
+    [B, T, vocab]), and for a MoE model the held experts' assignment
+    counts per MoE layer [n_moe, n_held] third."""
     view = gather_block_view(store, bt, lens)
-    view, logits = api.extend(params, view, tokens, cfg, mesh=mesh)
+    view, logits, *counts = api.extend(params, view, tokens, cfg, mesh=mesh,
+                                       valid=_moe_valid(cfg, wphys))
     T = tokens.shape[1]
     wpos = lens[:, None] + jnp.arange(T)[None, :]
-    return scatter_block_writes(store, view, wphys, woff, wpos), logits
+    return (scatter_block_writes(store, view, wphys, woff, wpos), logits,
+            *counts)
 
 
 def paged_decode_step(api: ModelApi, cfg: ModelConfig, params, store, bt,
@@ -219,9 +237,10 @@ def paged_decode_step(api: ModelApi, cfg: ModelConfig, params, store, bt,
     gathered view — the model writes the token's K/V into its tail block
     and reads K/V through the block table (the Pallas paged kernel on a
     TPU, a jnp table-gather on the CPU).  Returns (store, logits [B,
-    vocab])."""
+    vocab]), and for a MoE model the held experts' assignment counts per
+    MoE layer [n_moe, n_held] third."""
     return api.decode_paged(params, store, bt, lens, tokens, wphys, woff,
-                            cfg, mesh=mesh)
+                            cfg, mesh=mesh, valid=_moe_valid(cfg, wphys))
 
 
 class InferenceEngine:
@@ -329,14 +348,18 @@ class InferenceEngine:
                 def paged_decode(params, store, bt, lens, tokens, wphys,
                                  woff):
                     view = gather_block_view(store, bt, lens)
-                    view, logits = api.decode(params, view, tokens, cfg,
-                                              mesh=mesh)
+                    view, logits, *counts = api.decode(
+                        params, view, tokens, cfg, mesh=mesh,
+                        valid=_moe_valid(cfg, wphys))
                     store = scatter_block_writes(store, view, wphys[:, None],
                                                  woff[:, None], lens[:, None])
-                    return store, logits
+                    return store, logits, *counts
 
-            self._paged_extend = jax.jit(paged_extend, donate_argnums=(1,))
-            self._paged_decode = jax.jit(paged_decode, donate_argnums=(1,))
+            self._expert_counts: list = []  # device arrays not yet read
+            self._paged_extend = self._counted(
+                jax.jit(paged_extend, donate_argnums=(1,)))
+            self._paged_decode = self._counted(
+                jax.jit(paged_decode, donate_argnums=(1,)))
             self.stats.free_blocks = self.pool.n_free
             return
 
@@ -354,6 +377,35 @@ class InferenceEngine:
             return api.prefill(params, batch, cfg, mesh=mesh, **kw)
 
         self._prefill = jax.jit(prefill_fn)
+
+    def _counted(self, step):
+        """A MoE model's paged steps return the held experts' counts
+        third: keep them on the device, for ``_readback`` to fold into
+        the stats with the next sampled tokens, and return (store,
+        logits) as a dense model's steps do."""
+        if not self.cfg.is_moe:
+            return step
+
+        def call(*args):
+            store, logits, counts = step(*args)
+            self._expert_counts.append(counts)
+            if len(self._expert_counts) > 256:  # a caller that reads its
+                self._readback(0)  # own tokens (a speculative session)
+            return store, logits
+
+        return call
+
+    def _readback(self, x) -> np.ndarray:
+        """``x`` on the host, and in the same transfer the expert counts
+        of the steps run since the last readback, folded into the stats."""
+        if not self._expert_counts:
+            return np.asarray(x)
+        x, counts = jax.device_get((x, self._expert_counts))
+        self._expert_counts = []
+        for c in counts:  # [n_moe, n_held]
+            self.stats.expert_assignments += int(c.sum())
+            self.stats.expert_assignments_max += int(c.max(axis=-1).sum())
+        return np.asarray(x)
 
     # ------------------------------------------------------------------
     # Public API
@@ -1109,10 +1161,11 @@ class InferenceEngine:
                         logits_last = logits[0, T - 1]
                         if req.temperature > 0:
                             self._key, sub = jax.random.split(self._key)
-                            tok = int(sample(logits_last[None, :], sub,
-                                             temperature=req.temperature)[0])
+                            tok = int(self._readback(sample(
+                                logits_last[None, :], sub,
+                                temperature=req.temperature)[0]))
                         else:
-                            tok = int(jnp.argmax(logits_last))
+                            tok = int(self._readback(jnp.argmax(logits_last)))
                     req.output.append(tok)
                     req.last_token = tok
                     if req.first_token_at is None:
@@ -1171,10 +1224,10 @@ class InferenceEngine:
                 if np.any(temps > 0):
                     self._key, sub = jax.random.split(self._key)
                     sampled = sample(logits, sub, temperature=1.0)
-                    toks = np.asarray(jnp.where(jnp.asarray(temps) > 0,
-                                                sampled, greedy))
+                    toks = self._readback(jnp.where(jnp.asarray(temps) > 0,
+                                                    sampled, greedy))
                 else:
-                    toks = np.asarray(greedy)
+                    toks = self._readback(greedy)
             events = []
             for i, r in enumerate(active):
                 tok = int(toks[i])

@@ -56,6 +56,8 @@ def batch_dim_for(keys, rank: int) -> int:
     name = keys[-1]
     if name in ("k", "v", "cross_k", "cross_v"):
         return rank - 4
+    if name == "ckv":  # latent rows [..., batch, positions / 2, width]
+        return rank - 3
     if name == "len":
         return rank - 1
     if name == "wkv":
@@ -325,14 +327,26 @@ def scatter_block_writes(store, view, write_phys, write_off, write_pos):
     Only the positions actually produced this step move back — the rest
     of the gathered view is a read-only copy.  Padded (b, t) entries are
     redirected to the null block by the caller (phys 0), so collisions
-    there are harmless.  ``len`` leaves of the store are untouched (the
-    engine tracks logical lengths host-side)."""
+    there are harmless.  A latent leaf (``ckv``) holds two positions to a
+    row: the row that holds a written position moves back whole, its
+    other half as the view has it (the view was gathered from the store,
+    so that half is the store's own, or written in this step too).
+    ``len`` leaves of the store are untouched (the engine tracks logical
+    lengths host-side)."""
 
     def s(path, sleaf, vleaf):
         keys = _path_keys(path)
         if keys[-1] == "len":
             return sleaf
         bdim = batch_dim_for(keys, sleaf.ndim)
+        if keys[-1] == "ckv":  # written where it lies, no relayout
+            written = _kv_write_rows(vleaf, bdim, write_pos // 2)
+            lead = jnp.ix_(*(jnp.arange(n) for n in sleaf.shape[:bdim]))
+            cells = tuple(i[..., None, None] for i in lead) + (
+                write_phys, write_off // 2)
+            return sleaf.at[cells].set(
+                jnp.moveaxis(written, (0, 1), (bdim, bdim + 1))
+                .astype(sleaf.dtype))
         written = _kv_write_rows(vleaf, bdim, write_pos)  # [B, T, rest]
         s2 = jnp.moveaxis(sleaf, (bdim, bdim + 1), (0, 1))  # [N, bs, rest]
         s2 = s2.at[write_phys, write_off].set(written.astype(s2.dtype))

@@ -24,8 +24,10 @@ def _build(name):
         cfg = get_config("rhapsody-demo").scaled(
             n_layers=2, d_model=64, n_heads=4, n_kv_heads=2, head_dim=16,
             d_ff=128, vocab=512)
-    else:
+    elif name == "moe":
         cfg = get_smoke_config("deepseek-moe-16b")
+    else:  # latent attention, sigmoid-routed experts
+        cfg = get_smoke_config("moonlight-16b-a3b")
     api = get_model(cfg)
     params, _ = nn.split(api.init(jax.random.PRNGKey(0), cfg))
     return cfg, api, params
@@ -39,6 +41,11 @@ def dense_lm():
 @pytest.fixture(scope="module")
 def moe_lm():
     return _build("moe")
+
+
+@pytest.fixture(scope="module")
+def mla_lm():
+    return _build("mla")
 
 
 ENGINE_KW = dict(max_num_seqs=4, max_num_batched_tokens=256, max_len=64,
@@ -63,12 +70,14 @@ def _prefill_export_all(pre, n, max_steps=200):
 # ---------------------------------------------------------------------------
 
 
-@pytest.mark.parametrize("family", ["dense", "moe"])
-def test_export_import_round_trip_token_identity(family, dense_lm, moe_lm):
+@pytest.mark.parametrize("family", ["dense", "moe", "mla"])
+def test_export_import_round_trip_token_identity(family, dense_lm, moe_lm,
+                                                 mla_lm):
     """Greedy outputs survive the prefill->decode migration bit-for-bit:
     prefill on engine A, export, import into engine B, finish there —
     token-identical to the same prompts decoded on one unified engine."""
-    cfg, api, params = dense_lm if family == "dense" else moe_lm
+    cfg, api, params = {"dense": dense_lm, "moe": moe_lm,
+                         "mla": mla_lm}[family]
     rng = np.random.RandomState(0)
     prompts = [list(map(int, rng.randint(1, cfg.vocab, size=n)))
                for n in (5, 12, 23)]

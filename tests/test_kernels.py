@@ -251,3 +251,31 @@ def test_chunked_matches_recurrent_models():
     y2, s2 = wkv_recurrent(r, k, v, lw, u)
     np.testing.assert_allclose(y1, y2, rtol=1e-4, atol=1e-4)
     np.testing.assert_allclose(s1, s2, rtol=1e-4, atol=1e-4)
+
+
+@pytest.mark.parametrize("B,num_blocks,bs,mb,H,r,rope", [
+    (3, 17, 16, 4, 4, 32, 8),    # ragged lengths across block edges
+    (2, 13, 8, 6, 2, 16, 16),    # small blocks, rope as wide as r / 2
+])
+def test_paged_decode_latent(B, num_blocks, bs, mb, H, r, rope):
+    """The latent (MLA) kernel, in interpret mode, against its
+    gather-then-dense oracle, reading one layer of a stacked store;
+    lengths land mid-block, on a block's last and first cell, and at
+    the table's end."""
+    from repro.kernels.decode_attention.ops import paged_decode_latent
+    from repro.kernels.decode_attention.ref import paged_decode_latent_ref
+
+    ks = jax.random.split(jax.random.PRNGKey(11), 4)
+    store = jax.random.normal(ks[0], (2, num_blocks, bs // 2,
+                                      2 * (r + rope)), jnp.float32)
+    q_lat = jax.random.normal(ks[1], (B, H, r), jnp.float32)
+    q_pe = jax.random.normal(ks[2], (B, H, rope), jnp.float32)
+    bt = jax.random.permutation(ks[3], num_blocks - 1)[:B * mb]
+    bt = (bt + 1).reshape(B, mb).astype(jnp.int32)
+    lens = jnp.asarray([bs * mb, bs + 1, bs - 1][:B], jnp.int32)
+    scale = 1.0 / np.sqrt(r + rope)
+    out = paged_decode_latent(q_lat, q_pe, store, jnp.int32(1), bt, lens,
+                              scale=scale, interpret=True)
+    ref = paged_decode_latent_ref(q_lat, q_pe, store[1], bt, lens, scale)
+    np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
+                               rtol=2e-5, atol=2e-5)

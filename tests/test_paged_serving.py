@@ -18,8 +18,10 @@ def _build(name):
         cfg = get_config("rhapsody-demo").scaled(
             n_layers=2, d_model=64, n_heads=4, n_kv_heads=2, head_dim=16,
             d_ff=128, vocab=512)
-    else:
+    elif name == "moe":
         cfg = get_smoke_config("deepseek-moe-16b")
+    else:  # latent attention, sigmoid-routed experts
+        cfg = get_smoke_config("moonlight-16b-a3b")
     api = get_model(cfg)
     params, _ = nn.split(api.init(jax.random.PRNGKey(0), cfg))
     return cfg, api, params
@@ -33,6 +35,11 @@ def dense_lm():
 @pytest.fixture(scope="module")
 def moe_lm():
     return _build("moe")
+
+
+@pytest.fixture(scope="module")
+def mla_lm():
+    return _build("mla")
 
 
 def _ref_generate(api, params, cfg, prompt, steps):
@@ -64,12 +71,13 @@ ENGINE_KW = dict(max_num_seqs=4, max_num_batched_tokens=256, max_len=64,
                  prefill_buckets=(16, 32), seed=0)
 
 
-@pytest.mark.parametrize("family", ["dense", "moe"])
-def test_paged_matches_monolithic_and_ref(family, dense_lm, moe_lm):
+@pytest.mark.parametrize("family", ["dense", "moe", "mla"])
+def test_paged_matches_monolithic_and_ref(family, dense_lm, moe_lm, mla_lm):
     """Greedy outputs are token-for-token identical across the paged
     engine, the slot-pool engine, and the from-scratch incremental oracle
     — mixed prompt lengths spanning chunk and block boundaries."""
-    cfg, api, params = dense_lm if family == "dense" else moe_lm
+    cfg, api, params = {"dense": dense_lm, "moe": moe_lm,
+                         "mla": mla_lm}[family]
     rng = np.random.RandomState(1)
     prompts = [list(rng.randint(1, cfg.vocab, size=n))
                for n in (3, 8, 9, 17, 30)]
@@ -282,13 +290,14 @@ def test_paged_sampling_smoke(dense_lm):
 # ---------------------------------------------------------------------------
 
 
-@pytest.mark.parametrize("family", ["dense", "moe"])
-def test_paged_decode_modes_equivalent(family, dense_lm, moe_lm):
+@pytest.mark.parametrize("family", ["dense", "moe", "mla"])
+def test_paged_decode_modes_equivalent(family, dense_lm, moe_lm, mla_lm):
     """Direct paged decode (K/V written straight into the tail block,
     attention through the block table) is token-identical to the legacy
     gather round-trip AND the slot pool — ragged lengths straddling block
     boundaries (block_size 8: 7/8/9 and 15/16/17)."""
-    cfg, api, params = dense_lm if family == "dense" else moe_lm
+    cfg, api, params = {"dense": dense_lm, "moe": moe_lm,
+                         "mla": mla_lm}[family]
     rng = np.random.RandomState(7)
     prompts = [list(rng.randint(1, cfg.vocab, size=n))
                for n in (7, 8, 9, 15, 16, 17)]
@@ -301,12 +310,13 @@ def test_paged_decode_modes_equivalent(family, dense_lm, moe_lm):
     assert outs["direct"] == outs["gather"] == outs["slot"]
 
 
-@pytest.mark.parametrize("family", ["dense", "moe"])
-def test_paged_kernel_decode_matches_slot(family, dense_lm, moe_lm):
+@pytest.mark.parametrize("family", ["dense", "moe", "mla"])
+def test_paged_kernel_decode_matches_slot(family, dense_lm, moe_lm, mla_lm):
     """Direct decode through the Pallas paged kernel (interpret mode here;
     the path a TPU takes with the shipped configs) is token-identical to
     the slot pool, across block-boundary lengths."""
-    cfg, api, params = dense_lm if family == "dense" else moe_lm
+    cfg, api, params = {"dense": dense_lm, "moe": moe_lm,
+                         "mla": mla_lm}[family]
     rng = np.random.RandomState(9)
     prompts = [list(rng.randint(1, cfg.vocab, size=n))
                for n in (7, 8, 9, 16, 17, 31)]
@@ -376,14 +386,15 @@ def test_direct_decode_never_gathers(dense_lm, monkeypatch):
     assert decode_gathers["gather"] > 0
 
 
-@pytest.mark.parametrize("family", ["dense", "moe"])
-def test_paged_decode_writes_store_in_place(family, dense_lm, moe_lm):
+@pytest.mark.parametrize("family", ["dense", "moe", "mla"])
+def test_paged_decode_writes_store_in_place(family, dense_lm, moe_lm, mla_lm):
     """One paged decode step changes only the batch's (layer, write_phys,
     write_off) cells of the store, every other K/V cell bit-identical to
     before, and each written cell holds the token's K/V: the row the
     slot-pool decode writes at the sequence's length.  The moe model's
     unscanned ``pre`` layer is checked alongside the scanned stack."""
-    cfg, api, params = dense_lm if family == "dense" else moe_lm
+    cfg, api, params = {"dense": dense_lm, "moe": moe_lm,
+                         "mla": mla_lm}[family]
     bs = 8
     pool = PagedCachePool(cfg, num_blocks=16, block_size=bs, max_len=4 * bs)
     # random contents, so that a cell left alone is told from one zeroed
@@ -399,9 +410,9 @@ def test_paged_decode_writes_store_in_place(family, dense_lm, moe_lm):
     wphys = bt[np.arange(3), lens // bs]
     woff = lens % bs
     tokens = jnp.asarray([11, 22, 33], jnp.int32)
-    new, _ = paged_decode_step(api, cfg, params, store, jnp.asarray(bt),
-                               jnp.asarray(lens), tokens, jnp.asarray(wphys),
-                               jnp.asarray(woff))
+    new = paged_decode_step(api, cfg, params, store, jnp.asarray(bt),
+                            jnp.asarray(lens), tokens, jnp.asarray(wphys),
+                            jnp.asarray(woff))[0]
     view, _ = api.decode(params, gather_block_view(
         store, jnp.asarray(bt), jnp.asarray(lens)), tokens, cfg)
 
@@ -412,20 +423,25 @@ def test_paged_decode_writes_store_in_place(family, dense_lm, moe_lm):
         if path[-1].key == "len":
             np.testing.assert_array_equal(after, old)
             return
-        if old.ndim == 4:  # an unscanned layer: a stack of one
+        if path[0].key == "pre":  # an unscanned layer: a stack of one
             old, after, ref = old[None], after[None], ref[None]
+        # a latent row holds two positions: the written one's row moves
+        # whole, its other half as it was
+        per_row = 2 if path[-1].key == "ckv" else 1
         written = np.zeros(old.shape[:3], bool)
-        written[:, wphys, woff] = True
+        written[:, wphys, woff // per_row] = True
         np.testing.assert_array_equal(after[~written], old[~written])
         for b in range(len(lens)):
-            np.testing.assert_array_equal(after[:, wphys[b], woff[b]],
-                                          ref[:, b, lens[b]])
+            np.testing.assert_array_equal(
+                after[:, wphys[b], woff[b] // per_row],
+                ref[:, b, lens[b] // per_row])
         checked.append(jax.tree_util.keystr(path))
 
     jax.tree_util.tree_map_with_path(check, store, new, view)
-    expect = {"['scan']['k']", "['scan']['v']"}
-    if family == "moe":
-        expect |= {"['pre']['layer_0']['k']", "['pre']['layer_0']['v']"}
+    leaves = ("ckv",) if cfg.is_mla else ("k", "v")
+    expect = {f"['scan']['{n}']" for n in leaves}
+    if cfg.is_moe:
+        expect |= {f"['pre']['layer_0']['{n}']" for n in leaves}
     assert set(checked) == expect
 
 

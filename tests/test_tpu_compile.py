@@ -231,3 +231,59 @@ def test_paged_decode_writes_store_in_place(one_chip, monkeypatch, arch,
         k = store["scan"]["k"]
         layer_bytes = k.size // k.shape[0] * k.dtype.itemsize
         assert compiled.memory_analysis().temp_size_in_bytes < layer_bytes
+
+
+def test_moonlight_decode_step_holds_the_latent_store_in_place(one_chip,
+                                                               monkeypatch):
+    """moonlight-16b-a3b at the benchmark cell's widths and cut (9 layers,
+    8 of 64 experts held), decode batch 8: the step compiles with the
+    latent kernel and the grouped expert kernel, copies and relays no
+    store-shaped array, and the chip holds the latent store in exactly
+    blocks x 16 x 9 x 1152 bytes (two positions to an unpadded row)."""
+    monkeypatch.setattr(kernels, "pallas_interpret", lambda: False)
+    cfg = get_config("moonlight-16b-a3b").scaled(n_layers=9, experts_held=8)
+    api = get_model(cfg)
+    params = _on(one_chip, jax.eval_shape(
+        lambda k: nn.split(api.init(k, cfg))[0], jax.random.PRNGKey(0)))
+    store = _on(one_chip, specs.cache_template(cfg, LARGE_POOL, BLOCK_SIZE))
+    B, mb = 8, MAX_LEN // BLOCK_SIZE
+    vec = _sds(one_chip, (B,), jnp.int32)
+    compiled = _compile_step(paged_decode_step, api, cfg, params, store,
+                             _sds(one_chip, (B, mb), jnp.int32),
+                             vec, vec, vec, vec)
+    hlo = compiled.as_text()
+    assert "paged_decode_latent" in hlo and "%gmm" in hlo
+    assert _store_copies(hlo, LARGE_POOL) == ([], [])
+    latent = [x for x in jax.tree.leaves(store) if x.dtype == jnp.bfloat16]
+    want = LARGE_POOL * BLOCK_SIZE * 9 * 1152
+    assert sum(x.size * x.dtype.itemsize for x in latent) == want
+    lens = sum(x.size * x.dtype.itemsize for x in jax.tree.leaves(store)
+               if x.dtype == jnp.int32)
+    # the donated store is aliased whole; the chip's layout pads nothing
+    # (the int32 length leaves round up to their tiles, under 0.1%)
+    alias = compiled.memory_analysis().alias_size_in_bytes
+    assert want + lens <= alias < (want + lens) * 1.001
+
+
+def test_moonlight_extend_attention_takes_the_softmax_max_by_row(one_chip):
+    """moonlight-16b-a3b's chunked-prefill attention at the benchmark
+    cell's sizes (a 512-token chunk over an 8192-position view): the
+    softmax's max over each score row is a plain reduce.  With the shared
+    rope key concatenated onto every head's key, the compiler took it as
+    a reduce-window of 16383 positions over the padded row, which cost
+    ~23.5 ms a layer on a v5e."""
+    from repro.models import attention
+
+    cfg = get_config("moonlight-16b-a3b")
+    params = _on(one_chip, jax.eval_shape(
+        lambda k: nn.split(attention.mla_init(k, cfg))[0],
+        jax.random.PRNGKey(0)))
+    width = 2 * (cfg.kv_lora_rank + cfg.qk_rope_head_dim)
+    compiled = _compile(
+        lambda p, x, ckv, n: attention.mla_extend(p, x, ckv, n, cfg),
+        params, _sds(one_chip, (1, 512, cfg.d_model)),
+        _sds(one_chip, (1, 8192 // 2, width)),
+        _sds(one_chip, (1,), jnp.int32))
+    hlo = compiled.as_text()
+    assert re.search(r"f32\[16,512\]\S* reduce\(", hlo)
+    assert "reduce-window" not in hlo
