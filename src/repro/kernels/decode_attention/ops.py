@@ -29,11 +29,11 @@ def paged_decode_attention(q, k_store, v_store, layer, block_tables,
     """Paged flash-decode of layer ``layer`` of a stacked store, on
     model-layout tensors.
 
-    q [B,1,Hq,D]; stores [L, num_blocks, block_size, Hkv, D]; layer an
+    q [B,1,Hq,D]; stores [L, num_blocks, block_size, Hkv*D]; layer an
     int32 scalar; block_tables [B, max_blocks] int32; kv_length [B]
     -> [B,1,Hq,D]."""
     B, _, Hq, D = q.shape
-    Hkv = k_store.shape[3]
+    Hkv = k_store.shape[3] // D
     qg = q[:, 0].reshape(B, Hkv, Hq // Hkv, D)
     out = paged_decode_attention_grouped(qg, k_store, v_store, layer,
                                          block_tables.astype(jnp.int32),

@@ -22,14 +22,13 @@ def decode_ref(q, k_cache, v_cache, kv_length):
     return out.astype(q.dtype)
 
 
-def gather_kv(store, block_tables):
+def gather_kv(store, block_tables, head_dim):
     """Materialize contiguous caches from a paged store (oracle gather).
 
-    store [num_blocks, block_size, Hkv, D]; block_tables [B, max_blocks]
-    -> [B, max_blocks * block_size, Hkv, D]."""
-    B, mb = block_tables.shape
-    _, bs, Hkv, D = store.shape
-    return store[block_tables].reshape(B, mb * bs, Hkv, D)
+    store [num_blocks, block_size, Hkv*D]; block_tables [B, max_blocks]
+    -> [B, max_blocks * block_size, Hkv, D] with D = ``head_dim``."""
+    rows = gather_blocks(store, block_tables)
+    return rows.reshape(rows.shape[:2] + (-1, head_dim))
 
 
 def gather_blocks(store, block_tables):
@@ -63,7 +62,8 @@ def paged_decode_latent_ref(q_lat, q_pe, store, block_tables, kv_length,
 def paged_decode_ref(q, k_store, v_store, block_tables, kv_length):
     """Paged oracle: gather through the block tables, then ``decode_ref``.
 
-    q [B,Hkv,G,D]; stores [num_blocks, block_size, Hkv, D]; block_tables
+    q [B,Hkv,G,D]; stores [num_blocks, block_size, Hkv*D]; block_tables
     [B, max_blocks]; kv_length [B] -> [B,Hkv,G,D]."""
-    return decode_ref(q, gather_kv(k_store, block_tables),
-                      gather_kv(v_store, block_tables), kv_length)
+    D = q.shape[-1]
+    return decode_ref(q, gather_kv(k_store, block_tables, D),
+                      gather_kv(v_store, block_tables, D), kv_length)

@@ -351,8 +351,9 @@ def attention_decode_paged(p, x, k_store, v_store, layer, block_tables,
                            cfg: ModelConfig):
     """Single-token decode directly against a block-paged KV store.
 
-    x: [B,1,d]; k_store/v_store: [L, num_blocks, block_size, Hkv, D]
-    physical stores of every layer, shared by every sequence; layer:
+    x: [B,1,d]; k_store/v_store: [L, num_blocks, block_size, Hkv*D]
+    physical stores of every layer, shared by every sequence, each row
+    the token's heads side by side (lane-dense whatever D is); layer:
     int32 scalar, the layer this call reads and writes; block_tables:
     [B, max_blocks] int32 physical block ids per sequence; kv_length: [B]
     valid positions *before* this token; write_phys/write_off: [B] the
@@ -382,9 +383,9 @@ def attention_decode_paged(p, x, k_store, v_store, layer, block_tables,
     q, k_new, v_new = _project_qkv(p, x, x, cfg, pos, pos,
                                    rope=cfg.positions == "rope")
     k_store = k_store.at[layer, write_phys, write_off].set(
-        k_new[:, 0].astype(k_store.dtype))
+        k_new[:, 0].reshape(B, -1).astype(k_store.dtype))
     v_store = v_store.at[layer, write_phys, write_off].set(
-        v_new[:, 0].astype(v_store.dtype))
+        v_new[:, 0].reshape(B, -1).astype(v_store.dtype))
     new_len = kv_length + 1
     interpret = kernels.pallas_interpret()
     if cfg.attention_impl == "pallas" or not interpret:
@@ -396,8 +397,9 @@ def attention_decode_paged(p, x, k_store, v_store, layer, block_tables,
     else:
         from repro.kernels.decode_attention.ref import gather_kv
 
-        out = decode_attention(q, gather_kv(k_store[layer], block_tables),
-                               gather_kv(v_store[layer], block_tables),
+        D = cfg.head_dim
+        out = decode_attention(q, gather_kv(k_store[layer], block_tables, D),
+                               gather_kv(v_store[layer], block_tables, D),
                                new_len)
     out = out.reshape(B, 1, cfg.padded_heads * cfg.head_dim)
     return nn.linear_apply(p["o"], out, cfg.cdtype), k_store, v_store

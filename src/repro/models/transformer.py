@@ -186,7 +186,7 @@ def block_decode_paged(p, x, store, layer, block_tables, lens, write_phys,
     store.
 
     ``store`` holds the block-paged k/v of every layer, shape [L,
-    num_blocks, block_size, Hkv, D] (or, for latent attention, the
+    num_blocks, block_size, Hkv*D] (or, for latent attention, the
     packed latent rows ``ckv`` [L, num_blocks, block_size/2,
     2*(r+rope)]); this layer's token entry is written into it and
     attention reads the layer from it, so the store is returned whole
@@ -598,7 +598,7 @@ def paged_decode_step(p, store, block_tables, lens, tokens, write_phys,
     """One decode step directly on the block-paged physical store.
 
     The paged analogue of ``decode_step``: ``store`` is the engine's
-    physical cache tree (k/v leaves [L, num_blocks, block_size, Hkv, D]),
+    physical cache tree (k/v leaves [L, num_blocks, block_size, Hkv*D]),
     ``block_tables`` [B, max_blocks] maps each sequence's logical blocks
     to physical ones, ``lens`` [B] is each sequence's valid length before
     this token, and ``write_phys``/``write_off`` [B] name the single

@@ -103,8 +103,8 @@ from repro.core.prefix import RadixIndex
 from repro.core.tracing import span
 from repro.models import ModelApi, get_model
 from repro.models.config import ModelConfig
-from .kvcache import (CachePool, PagedCachePool, extract_blocks,
-                      gather_block_view, insert_blocks,
+from .kvcache import (CachePool, PagedCachePool, as_paged_store,
+                      extract_blocks, gather_block_view, insert_blocks,
                       scatter_block_writes)
 from .sampling import sample
 
@@ -215,6 +215,20 @@ def _moe_valid(cfg: ModelConfig, wphys):
     return (wphys != 0) if cfg.is_moe else None
 
 
+def _in_store_layout(step, store):
+    """``step`` run on ``store`` in the paged layout (``as_paged_store``),
+    its first output, the updated store, handed back in the shapes
+    ``store`` came in.  The pool's store passes as it is; a store built
+    from ``cache_template`` (as a caller that sizes the step programs
+    from the slot template builds it) comes back in that shape, so a
+    program compiled on it donates and aliases its store as the engine's
+    program does, and its transient counts the relayouts into and out of
+    the paged layout besides the step's own."""
+    out = step(as_paged_store(store))
+    return (jax.tree.map(lambda new, old: new.reshape(old.shape), out[0],
+                         store), *out[1:])
+
+
 def paged_extend_step(api: ModelApi, cfg: ModelConfig, params, store, bt,
                       lens, tokens, wphys, woff, *, mesh=None):
     """One prefill chunk on the paged store: gather the sequences' block
@@ -222,13 +236,17 @@ def paged_extend_step(api: ModelApi, cfg: ModelConfig, params, store, bt,
     rows into their (``wphys``, ``woff``) cells.  Returns (store, logits
     [B, T, vocab]), and for a MoE model the held experts' assignment
     counts per MoE layer [n_moe, n_held] third."""
-    view = gather_block_view(store, bt, lens)
-    view, logits, *counts = api.extend(params, view, tokens, cfg, mesh=mesh,
-                                       valid=_moe_valid(cfg, wphys))
-    T = tokens.shape[1]
-    wpos = lens[:, None] + jnp.arange(T)[None, :]
-    return (scatter_block_writes(store, view, wphys, woff, wpos), logits,
-            *counts)
+    def step(store):
+        view = gather_block_view(store, bt, lens, cfg.head_dim)
+        view, logits, *counts = api.extend(params, view, tokens, cfg,
+                                           mesh=mesh,
+                                           valid=_moe_valid(cfg, wphys))
+        T = tokens.shape[1]
+        wpos = lens[:, None] + jnp.arange(T)[None, :]
+        return (scatter_block_writes(store, view, wphys, woff, wpos),
+                logits, *counts)
+
+    return _in_store_layout(step, store)
 
 
 def paged_decode_step(api: ModelApi, cfg: ModelConfig, params, store, bt,
@@ -239,8 +257,11 @@ def paged_decode_step(api: ModelApi, cfg: ModelConfig, params, store, bt,
     TPU, a jnp table-gather on the CPU).  Returns (store, logits [B,
     vocab]), and for a MoE model the held experts' assignment counts per
     MoE layer [n_moe, n_held] third."""
-    return api.decode_paged(params, store, bt, lens, tokens, wphys, woff,
-                            cfg, mesh=mesh, valid=_moe_valid(cfg, wphys))
+    return _in_store_layout(
+        lambda store: api.decode_paged(params, store, bt, lens, tokens,
+                                       wphys, woff, cfg, mesh=mesh,
+                                       valid=_moe_valid(cfg, wphys)),
+        store)
 
 
 class InferenceEngine:
@@ -347,7 +368,8 @@ class InferenceEngine:
                 # run the slot-pool decode, scatter the one new row back
                 def paged_decode(params, store, bt, lens, tokens, wphys,
                                  woff):
-                    view = gather_block_view(store, bt, lens)
+                    view = gather_block_view(store, bt, lens,
+                                             cfg.head_dim)
                     view, logits, *counts = api.decode(
                         params, view, tokens, cfg, mesh=mesh,
                         valid=_moe_valid(cfg, wphys))

@@ -11,7 +11,9 @@ per-position KV to page.
 
 ``PagedCachePool`` — the TPU adaptation of vLLM's PagedAttention
 proper: each cache leaf is a ``[num_blocks, block_size, ...]`` physical
-store, a sequence is a *block table* (list of physical block ids), and
+store (k/v stacked over layers and lane-dense, ``[L, num_blocks,
+block_size, Hkv*D]``; see ``paged_template``), a sequence is a *block
+table* (list of physical block ids), and
 ``BlockAllocator`` hands out blocks with per-block refcounts.  Multiple
 sequences sharing a prompt prefix point their tables at the same
 physical blocks (refcount > 1); the first divergent write triggers
@@ -286,6 +288,40 @@ class BlockAllocator:
         return sum(r - 1 for r in self._ref if r > 1)
 
 
+def _block_dim(keys, rank: int) -> int:
+    """The block axis of a paged store's leaf: k, v and latent rows are
+    [..., num_blocks, rows, width], lengths [..., batch]."""
+    return rank - 1 if keys[-1] == "len" else rank - 3
+
+
+def as_paged_store(store):
+    """``store`` in the paged layout: each k/v leaf [..., N, block_size,
+    Hkv, D] of a tree in ``cache_template``'s shape becomes [..., N,
+    block_size, Hkv*D], the heads side by side on the last axis.  A leaf
+    already lane-dense (one axis fewer than its sibling ``len`` leaf
+    implies), as the pool's own are, passes as it is."""
+    if "len" not in store:
+        return {name: as_paged_store(sub) for name, sub in store.items()}
+    slot_rank = store["len"].ndim + 3
+    return {name: (x.reshape(x.shape[:-2] + (-1,))
+                   if name in ("k", "v") and x.ndim == slot_rank else x)
+            for name, x in store.items()}
+
+
+def paged_template(cfg: ModelConfig, num_blocks: int, block_size: int):
+    """Shapes of the paged store of ``num_blocks`` blocks: the slot
+    template at batch ``num_blocks`` and ``block_size`` positions, with
+    every k/v leaf lane-dense, ``[L, num_blocks, block_size, Hkv*D]``.
+    At qwen1.5-0.5b's 16 heads of 64 a row is 1024 lanes, eight whole
+    tiles, so the chip keeps the store row-major and unpadded and the
+    decode kernel reads its blocks where they lie; ``[..., Hkv, D]`` at
+    D=64 pads each row to 128 lanes in that layout, so the chip keeps it
+    with the block axis minor instead and relays the whole stack into
+    the kernel's layout on every step."""
+    return jax.eval_shape(as_paged_store,
+                          sp.cache_template(cfg, num_blocks, block_size))
+
+
 def _kv_write_rows(view_leaf, bdim, write_pos):
     """Rows of a contiguous view at per-sequence positions: [B, T, rest]."""
     v2 = jnp.moveaxis(view_leaf, (bdim, bdim + 1), (0, 1))  # [B, S, rest]
@@ -293,21 +329,23 @@ def _kv_write_rows(view_leaf, bdim, write_pos):
     return v2[jnp.arange(B)[:, None], write_pos]
 
 
-def gather_block_view(store, block_tables, lens):
+def gather_block_view(store, block_tables, lens, head_dim: int):
     """Reassemble a contiguous cache view from a blocked store.
 
-    ``store``: cache tree with leaves ``[..., num_blocks, block_size, ...]``
-    (the batch/seq dims of ``cache_template``); ``block_tables``:
-    ``[B, max_blocks]`` int32 physical block ids; ``lens``: ``[B]`` int32
-    valid lengths.  Returns a tree shaped like a ``[B, max_blocks *
-    block_size, ...]`` slot cache, with ``len`` leaves broadcast from
-    ``lens`` — exactly what ``ModelApi.decode`` / ``extend`` expect.
+    ``store``: the paged store (``paged_template``), leaves ``[...,
+    num_blocks, rows, width]``; ``block_tables``: ``[B, max_blocks]``
+    int32 physical block ids; ``lens``: ``[B]`` int32 valid lengths;
+    ``head_dim``: the model's D.  Returns a tree shaped like a ``[B,
+    max_blocks * block_size, ...]`` slot cache (``cache_template``'s
+    shapes: k/v ``[..., B, S, Hkv, D]``, the view's rows split back into
+    heads), with ``len`` leaves broadcast from ``lens`` — exactly what
+    ``ModelApi.decode`` / ``extend`` expect.
     """
     B, mb = block_tables.shape
 
     def g(path, leaf):
         keys = _path_keys(path)
-        bdim = batch_dim_for(keys, leaf.ndim)
+        bdim = _block_dim(keys, leaf.ndim)
         if keys[-1] == "len":
             lead = leaf.shape[:bdim]
             return jnp.broadcast_to(lens.astype(jnp.int32), lead + (B,))
@@ -315,6 +353,8 @@ def gather_block_view(store, block_tables, lens):
         bs = s2.shape[1]
         v = s2[block_tables]  # [B, mb, bs, rest]
         v = v.reshape((B, mb * bs) + s2.shape[2:])
+        if keys[-1] in ("k", "v"):  # [B, S, lead, Hkv*D] -> [..., Hkv, D]
+            v = v.reshape(v.shape[:-1] + (-1, head_dim))
         return jnp.moveaxis(v, (0, 1), (bdim, bdim + 1))
 
     return jax.tree_util.tree_map_with_path(g, store)
@@ -330,7 +370,8 @@ def scatter_block_writes(store, view, write_phys, write_off, write_pos):
     there are harmless.  A latent leaf (``ckv``) holds two positions to a
     row: the row that holds a written position moves back whole, its
     other half as the view has it (the view was gathered from the store,
-    so that half is the store's own, or written in this step too).
+    so that half is the store's own, or written in this step too).  A
+    k/v row moves back as ``[Hkv*D]``, the store's lane-dense row.
     ``len`` leaves of the store are untouched (the engine tracks logical
     lengths host-side)."""
 
@@ -338,7 +379,7 @@ def scatter_block_writes(store, view, write_phys, write_off, write_pos):
         keys = _path_keys(path)
         if keys[-1] == "len":
             return sleaf
-        bdim = batch_dim_for(keys, sleaf.ndim)
+        bdim = _block_dim(keys, sleaf.ndim)
         if keys[-1] == "ckv":  # written where it lies, no relayout
             written = _kv_write_rows(vleaf, bdim, write_pos // 2)
             lead = jnp.ix_(*(jnp.arange(n) for n in sleaf.shape[:bdim]))
@@ -347,8 +388,10 @@ def scatter_block_writes(store, view, write_phys, write_off, write_pos):
             return sleaf.at[cells].set(
                 jnp.moveaxis(written, (0, 1), (bdim, bdim + 1))
                 .astype(sleaf.dtype))
-        written = _kv_write_rows(vleaf, bdim, write_pos)  # [B, T, rest]
+        written = _kv_write_rows(vleaf, batch_dim_for(keys, vleaf.ndim),
+                                 write_pos)  # [B, T, lead, Hkv, D]
         s2 = jnp.moveaxis(sleaf, (bdim, bdim + 1), (0, 1))  # [N, bs, rest]
+        written = written.reshape(written.shape[:2] + s2.shape[2:])
         s2 = s2.at[write_phys, write_off].set(written.astype(s2.dtype))
         return jnp.moveaxis(s2, (0, 1), (bdim, bdim + 1))
 
@@ -371,7 +414,7 @@ def extract_blocks(store, blocks):
         keys = tuple(_path_keys(path))
         if keys[-1] == "len":
             return leaf
-        bdim = batch_dim_for(keys, leaf.ndim)
+        bdim = _block_dim(keys, leaf.ndim)
         t = jnp.moveaxis(leaf, bdim, 0)
         out[keys] = jax.device_get(t[idx])
         return leaf
@@ -392,7 +435,7 @@ def insert_blocks(store, leaves, dst_blocks):
         src = leaves.get(keys)
         if src is None:
             return leaf
-        bdim = batch_dim_for(keys, leaf.ndim)
+        bdim = _block_dim(keys, leaf.ndim)
         t = jnp.moveaxis(leaf, bdim, 0)
         t = t.at[idx].set(jnp.asarray(src).astype(t.dtype))
         return jnp.moveaxis(t, 0, bdim)
@@ -404,7 +447,7 @@ class PagedCachePool:
     """Block-paged physical KV store + allocator.
 
     Each cache leaf is allocated once as ``[num_blocks, block_size, ...]``
-    (via ``cache_template`` with batch=num_blocks, max_len=block_size);
+    (``paged_template``: k/v ``[L, num_blocks, block_size, Hkv*D]``);
     sequences own *block tables* mapping logical block index ->
     physical block id.  The pool only moves data: the engine owns
     tables, refcount policy (via ``alloc``), and scheduling."""
@@ -424,7 +467,7 @@ class PagedCachePool:
             raise ValueError(
                 f"num_blocks={num_blocks} cannot hold one max_len={max_len} "
                 f"sequence ({self.max_blocks} blocks of {block_size})")
-        tmpl = sp.cache_template(cfg, num_blocks, block_size)
+        tmpl = paged_template(cfg, num_blocks, block_size)
         self.cache = jax.tree.map(lambda s: jnp.zeros(s.shape, s.dtype), tmpl)
         self.alloc = BlockAllocator(num_blocks)
 
@@ -433,7 +476,7 @@ class PagedCachePool:
                 keys = _path_keys(path)
                 if keys[-1] == "len":
                     return leaf
-                bdim = batch_dim_for(keys, leaf.ndim)
+                bdim = _block_dim(keys, leaf.ndim)
                 t = jnp.moveaxis(leaf, bdim, 0)
                 t = t.at[dst].set(t[src])
                 return jnp.moveaxis(t, 0, bdim)

@@ -45,7 +45,7 @@ def test_paged_decode_kernel_follows_backend(monkeypatch, backend, impl,
         return real(*a, interpret=True)  # this process can only interpret
 
     monkeypatch.setattr(da_ops, "paged_decode_attention", spy)
-    store = jnp.ones((2, 5, 4, 2, 8))  # [L, num_blocks, block, Hkv, D]
+    store = jnp.ones((2, 5, 4, 2 * 8))  # [L, num_blocks, block, Hkv * D]
     attention.attention_decode_paged(
         p, jnp.ones((2, 1, 32)), store, store, 1,
         jnp.asarray([[1, 2], [3, 0]]), jnp.asarray([5, 2]),

@@ -63,13 +63,13 @@ def test_decode_attention(B, S, Hq, Hkv, D, bk):
 
 
 def _paged_setup(key, B, num_blocks, bs, mb, Hq, Hkv, D, *, permute=True):
-    """Random paged stores + per-sequence block tables with DISTINCT,
-    permuted physical blocks and ragged lengths (including lengths not a
-    multiple of block_size)."""
+    """Random lane-dense paged stores ``[N, bs, Hkv * D]`` + per-sequence
+    block tables with DISTINCT, permuted physical blocks and ragged
+    lengths (including lengths not a multiple of block_size)."""
     ks = jax.random.split(key, 5)
     q = jax.random.normal(ks[0], (B, 1, Hq, D))
-    k_store = jax.random.normal(ks[1], (num_blocks, bs, Hkv, D))
-    v_store = jax.random.normal(ks[2], (num_blocks, bs, Hkv, D))
+    k_store = jax.random.normal(ks[1], (num_blocks, bs, Hkv * D))
+    v_store = jax.random.normal(ks[2], (num_blocks, bs, Hkv * D))
     # physical blocks 1..num_blocks-1 dealt without repeats (block 0 is
     # the null block), shuffled so tables are non-contiguous
     perm = np.arange(1, num_blocks)
@@ -89,8 +89,8 @@ def _paged_setup(key, B, num_blocks, bs, mb, Hq, Hkv, D, *, permute=True):
 
 
 def _paged(q, k_store, v_store, bt, lens):
-    """The paged kernel on a one-layer stack: ``[N, bs, Hkv, D]`` stores
-    read as layer 0 of ``[1, N, bs, Hkv, D]``."""
+    """The paged kernel on a one-layer stack: ``[N, bs, Hkv * D]`` stores
+    read as layer 0 of ``[1, N, bs, Hkv * D]``."""
     return paged_decode_attention(q, k_store[None], v_store[None], 0, bt,
                                   lens, interpret=True)
 
@@ -99,6 +99,10 @@ def _paged(q, k_store, v_store, bt, lens):
     (2, 17, 16, 4, 4, 2, 32),     # ragged lens, permuted tables
     (3, 32, 8, 6, 8, 4, 16),      # small blocks, more heads
     (1, 9, 32, 8, 2, 1, 64),      # single sequence, MHA-degenerate
+    (2, 17, 16, 4, 16, 16, 64),   # MHA, qwen1.5-0.5b's heads: 1024 lanes
+    (2, 17, 16, 4, 24, 8, 128),   # GQA (G=3), qwen3-8b's head width
+    (2, 17, 16, 4, 4, 2, 256),    # a head of two lane tiles
+    (2, 17, 16, 4, 4, 2, 48),     # a width that tiles no 128 lanes
 ])
 def test_paged_decode_attention(B, num_blocks, bs, mb, Hq, Hkv, D):
     """Paged kernel vs the gather-then-dense oracle."""
@@ -117,16 +121,16 @@ def test_paged_decode_attention(B, num_blocks, bs, mb, Hq, Hkv, D):
     (24, 8, 128),   # GQA (G=3) at D=128, qwen3-8b's head width
 ])
 def test_paged_decode_reads_layer_of_stack(L, layer, Hq, Hkv, D):
-    """The kernel reading layer ``layer`` of a stacked [L, N, bs, Hkv, D]
+    """The kernel reading layer ``layer`` of a stacked [L, N, bs, Hkv * D]
     store equals the oracle on that layer's store alone.  Every layer is
     drawn apart, so reading the wrong one fails."""
     B, num_blocks, bs, mb = 2, 9, 16, 4
     q, _, _, bt, lens = _paged_setup(
         jax.random.PRNGKey(10), B, num_blocks, bs, mb, Hq, Hkv, D)
     kk, kv = jax.random.split(jax.random.PRNGKey(11))
-    shape = (L, num_blocks, bs, Hkv, D)
+    shape = (L, num_blocks, bs, Hkv * D)
     # layer l is offset by 2l as well: no two layers share a value
-    off = 2.0 * jnp.arange(L, dtype=jnp.float32)[:, None, None, None, None]
+    off = 2.0 * jnp.arange(L, dtype=jnp.float32)[:, None, None, None]
     k_stack = jax.random.normal(kk, shape) + off
     v_stack = jax.random.normal(kv, shape) - off
     out = paged_decode_attention(q, k_stack, v_stack, layer, bt, lens,
@@ -152,16 +156,24 @@ def test_paged_matches_contiguous_kernel():
     q, ks_, vs_, bt, lens = _paged_setup(
         jax.random.PRNGKey(6), B, num_blocks, bs, mb, Hq, Hkv, D)
     paged = _paged(q, ks_, vs_, bt, lens)
-    kc, vc = gather_kv(ks_, bt), gather_kv(vs_, bt)
+    kc, vc = gather_kv(ks_, bt, D), gather_kv(vs_, bt, D)
     contig = decode_attention(q, kc, vc, lens, block_k=bs, interpret=True)
     np.testing.assert_allclose(np.asarray(paged), np.asarray(contig),
                                rtol=2e-6, atol=2e-6)
 
 
-def test_paged_decode_block_size_edges():
+HEADS = pytest.mark.parametrize("Hq,Hkv,D", [
+    (4, 2, 16),     # GQA (G=2), narrow heads
+    (16, 16, 64),   # MHA, qwen1.5-0.5b's heads
+    (24, 8, 128),   # GQA (G=3), qwen3-8b's head width
+])
+
+
+@HEADS
+def test_paged_decode_block_size_edges(Hq, Hkv, D):
     """Lengths straddling block boundaries: 1, block_size-1, block_size,
     block_size+1, and full capacity all mask correctly."""
-    num_blocks, bs, mb, Hq, Hkv, D = 23, 8, 4, 4, 2, 16
+    num_blocks, bs, mb = 23, 8, 4
     edge_lens = [1, bs - 1, bs, bs + 1, mb * bs]
     B = len(edge_lens)
     q, ks_, vs_, _, _ = _paged_setup(
@@ -182,11 +194,12 @@ def test_paged_decode_block_size_edges():
                                rtol=2e-5, atol=2e-5)
 
 
-def test_paged_table_permutation_invariance():
+@HEADS
+def test_paged_table_permutation_invariance(Hq, Hkv, D):
     """Physically relocating blocks (and rewriting the tables to match)
     must not change the output: attention depends only on the logical
     sequence the table reconstructs."""
-    B, num_blocks, bs, mb, Hq, Hkv, D = 2, 11, 8, 4, 4, 2, 16
+    B, num_blocks, bs, mb = 2, 11, 8, 4
     q, ks_, vs_, bt, lens = _paged_setup(
         jax.random.PRNGKey(8), B, num_blocks, bs, mb, Hq, Hkv, D,
         permute=False)

@@ -7,16 +7,27 @@ import numpy as np
 import pytest
 
 from repro.configs import get_config, get_smoke_config
+from repro.launch import specs
 from repro.models import get_model, nn
-from repro.serving.engine import InferenceEngine, paged_decode_step
+from repro.serving.engine import (InferenceEngine, paged_decode_step,
+                                  paged_extend_step)
 from repro.serving.kvcache import (BlockAllocator, PagedCachePool,
-                                   gather_block_view)
+                                   extract_blocks, gather_block_view,
+                                   insert_blocks)
 
 
 def _build(name):
     if name == "dense":
         cfg = get_config("rhapsody-demo").scaled(
             n_layers=2, d_model=64, n_heads=4, n_kv_heads=2, head_dim=16,
+            d_ff=128, vocab=512)
+    elif name == "d64":  # qwen1.5-0.5b's head width, MHA
+        cfg = get_config("rhapsody-demo").scaled(
+            n_layers=2, d_model=128, n_heads=2, n_kv_heads=2, head_dim=64,
+            d_ff=128, vocab=512)
+    elif name == "d128":  # qwen3-8b's head width, GQA
+        cfg = get_config("rhapsody-demo").scaled(
+            n_layers=2, d_model=128, n_heads=4, n_kv_heads=2, head_dim=128,
             d_ff=128, vocab=512)
     elif name == "moe":
         cfg = get_smoke_config("deepseek-moe-16b")
@@ -327,6 +338,62 @@ def test_paged_kernel_decode_matches_slot(family, dense_lm, moe_lm, mla_lm):
     assert kernel == slot
 
 
+@pytest.fixture(scope="module", params=["d64", "d128"])
+def wide_lm(request):
+    return _build(request.param)
+
+
+def test_paged_head_widths_match_slot(wide_lm):
+    """At head_dim 64 (MHA) and 128 (GQA), the widths whose lane-dense
+    rows are one and two whole 128-lane tiles, greedy outputs of the paged
+    path, through the jnp table-gather and through the Pallas kernel
+    (interpret mode), equal the slot pool's across block-boundary
+    lengths."""
+    cfg, _, params = wide_lm
+    rng = np.random.RandomState(11)
+    prompts = [list(rng.randint(1, cfg.vocab, size=n))
+               for n in (7, 8, 9, 17)]
+    slot = _drive(InferenceEngine(cfg, params, **ENGINE_KW), prompts, 5)
+    for impl in ("auto", "pallas"):
+        paged = InferenceEngine(cfg.scaled(attention_impl=impl), params,
+                                **ENGINE_KW, paged=True, block_size=8)
+        assert _drive(paged, prompts, 5) == slot
+
+
+def test_paged_block_moves_keep_lane_dense_rows(wide_lm):
+    """The block copy, ``extract_blocks`` and ``insert_blocks`` move
+    whole lane-dense rows ``[L, N, block_size, Hkv * D]``: a copied
+    block equals its source in every layer, and blocks moved to another
+    pool land there unchanged, every other block left as it was."""
+    cfg, _, _ = wide_lm
+    bs = 8
+    pool = PagedCachePool(cfg, num_blocks=9, block_size=bs, max_len=4 * bs)
+    k = pool.cache["scan"]["k"]
+    assert k.shape == (cfg.n_layers, 9, bs, cfg.n_kv_heads * cfg.head_dim)
+    leaves, tree = jax.tree.flatten(pool.cache)
+    keys = jax.random.split(jax.random.PRNGKey(4), len(leaves))
+    pool.cache = tree.unflatten([
+        leaf if leaf.dtype == jnp.int32
+        else jax.random.normal(key, leaf.shape, leaf.dtype)
+        for key, leaf in zip(keys, leaves)])
+    before = {n: np.asarray(pool.cache["scan"][n]) for n in ("k", "v")}
+    pool.copy_block(3, 7)
+    for n in ("k", "v"):
+        after = np.asarray(pool.cache["scan"][n])
+        np.testing.assert_array_equal(after[:, 7], before[n][:, 3])
+        np.testing.assert_array_equal(np.delete(after, 7, axis=1),
+                                      np.delete(before[n], 7, axis=1))
+    other = PagedCachePool(cfg, num_blocks=9, block_size=bs,
+                           max_len=4 * bs)
+    moved = extract_blocks(pool.cache, [3, 5])
+    other.cache = insert_blocks(other.cache, moved, [6, 2])
+    for n in ("k", "v"):
+        src = np.asarray(pool.cache["scan"][n])
+        dst = np.asarray(other.cache["scan"][n])
+        np.testing.assert_array_equal(dst[:, [6, 2]], src[:, [3, 5]])
+        assert not dst[:, [0, 1, 3, 4, 5, 7, 8]].any()
+
+
 def test_paged_decode_modes_agree_on_divergence(dense_lm):
     """Partial-hit resume plus copy-on-write divergence produce identical
     greedy tokens under the direct kernel and the gather round-trip, and
@@ -414,7 +481,7 @@ def test_paged_decode_writes_store_in_place(family, dense_lm, moe_lm, mla_lm):
                             jnp.asarray(lens), tokens, jnp.asarray(wphys),
                             jnp.asarray(woff))[0]
     view, _ = api.decode(params, gather_block_view(
-        store, jnp.asarray(bt), jnp.asarray(lens)), tokens, cfg)
+        store, jnp.asarray(bt), jnp.asarray(lens), cfg.head_dim), tokens, cfg)
 
     checked = []
 
@@ -425,6 +492,8 @@ def test_paged_decode_writes_store_in_place(family, dense_lm, moe_lm, mla_lm):
             return
         if path[0].key == "pre":  # an unscanned layer: a stack of one
             old, after, ref = old[None], after[None], ref[None]
+        # the view's [.., Hkv, D] rows are the store's lane-dense ones
+        ref = ref.reshape(ref.shape[:3] + old.shape[3:])
         # a latent row holds two positions: the written one's row moves
         # whole, its other half as it was
         per_row = 2 if path[-1].key == "ckv" else 1
@@ -443,6 +512,47 @@ def test_paged_decode_writes_store_in_place(family, dense_lm, moe_lm, mla_lm):
     if cfg.is_moe:
         expect |= {f"['pre']['layer_0']['{n}']" for n in leaves}
     assert set(checked) == expect
+
+
+@pytest.mark.parametrize("step", ["decode", "extend"])
+def test_paged_steps_take_a_slot_template_store(step, dense_lm):
+    """A store in ``cache_template``'s shape (k/v [L, N, bs, Hkv, D]),
+    as a caller that sizes the step programs from the slot template
+    builds it, runs through the paged steps as the pool's lane-dense
+    store does: the same logits and the same cells written, the store
+    handed back in the shapes it came in."""
+    cfg, api, params = dense_lm
+    bs = 8
+    pool = PagedCachePool(cfg, num_blocks=16, block_size=bs, max_len=4 * bs)
+    leaves, tree = jax.tree.flatten(pool.cache)
+    keys = jax.random.split(jax.random.PRNGKey(5), len(leaves))
+    dense = tree.unflatten([
+        leaf if leaf.dtype == jnp.int32
+        else jax.random.normal(k, leaf.shape, leaf.dtype)
+        for k, leaf in zip(keys, leaves)])
+    slot = jax.tree.map(lambda x, t: x.reshape(t.shape), dense,
+                        specs.cache_template(cfg, 16, bs))
+    assert slot["scan"]["k"].shape[-2:] == (cfg.n_kv_heads, cfg.head_dim)
+    bt = jnp.asarray([[3, 0, 0, 0], [5, 9, 0, 0]], jnp.int32)
+    if step == "decode":
+        lens = jnp.asarray([7, 8], jnp.int32)
+        args = (bt, lens, jnp.asarray([11, 22], jnp.int32),
+                jnp.asarray([3, 9]), jnp.asarray([7, 0]))
+        fn = paged_decode_step
+    else:
+        lens = jnp.asarray([0, 0], jnp.int32)
+        args = (bt[:1], lens[:1], jnp.asarray([[1, 2, 3, 4]], jnp.int32),
+                jnp.asarray([[3, 3, 3, 3]]), jnp.asarray([[0, 1, 2, 3]]))
+        fn = paged_extend_step
+    new_dense, logits_dense = fn(api, cfg, params, dense, *args)
+    new_slot, logits_slot = fn(api, cfg, params, slot, *args)
+    np.testing.assert_array_equal(np.asarray(logits_slot),
+                                  np.asarray(logits_dense))
+    for a, b, s0 in zip(jax.tree.leaves(new_slot), jax.tree.leaves(new_dense),
+                        jax.tree.leaves(slot)):
+        assert a.shape == s0.shape
+        np.testing.assert_array_equal(np.asarray(a).reshape(b.shape),
+                                      np.asarray(b))
 
 
 def test_paged_rejects_unknown_decode_mode(dense_lm):

@@ -25,9 +25,9 @@ from repro.configs import get_config
 from repro.kernels.decode_attention.ops import (decode_attention,
                                                 paged_decode_attention)
 from repro.kernels.flash_attention.ops import flash_attention
-from repro.launch import specs
 from repro.models import get_model, nn
 from repro.serving.engine import paged_decode_step, paged_extend_step
+from repro.serving.kvcache import paged_template
 
 BLOCK_SIZE = 16
 MAX_LEN = 128
@@ -68,10 +68,11 @@ def _compile(fn, *args):
 @pytest.mark.parametrize("B,Hq,Hkv,D", [
     (8, 16, 16, 64),   # qwen1.5-0.5b: MHA, 16 heads of 64
     (4, 24, 8, 128),   # grouped-query heads (G=3) at D=128
+    (4, 16, 8, 192),   # heads that tile no 128 lanes
 ])
 def test_paged_decode_kernel_compiles(one_chip, B, Hq, Hkv, D):
     mb = MAX_LEN // BLOCK_SIZE
-    stack = _sds(one_chip, (2, NUM_BLOCKS, BLOCK_SIZE, Hkv, D))
+    stack = _sds(one_chip, (2, NUM_BLOCKS, BLOCK_SIZE, Hkv * D))
     compiled = _compile(
         functools.partial(paged_decode_attention, interpret=False),
         _sds(one_chip, (B, 1, Hq, D)), stack, stack,
@@ -104,7 +105,7 @@ def _two_layers(sharding, arch, num_blocks):
     api = get_model(cfg)
     params = jax.eval_shape(
         lambda k: nn.split(api.init(k, cfg))[0], jax.random.PRNGKey(0))
-    store = specs.cache_template(cfg, num_blocks, BLOCK_SIZE)
+    store = paged_template(cfg, num_blocks, BLOCK_SIZE)
     return api, cfg, _on(sharding, params), _on(sharding, store)
 
 
@@ -132,10 +133,10 @@ def test_qwen_paged_decode_step_compiles(one_chip, qwen_two_layers,
     assert "tpu_custom_call" in compiled.as_text()
 
 
-def test_qwen_paged_extend_step_compiles(one_chip, qwen_two_layers,
-                                         monkeypatch):
+@pytest.mark.parametrize("arch", ["qwen1.5-0.5b", "qwen3-8b"])
+def test_qwen_paged_extend_step_compiles(one_chip, monkeypatch, arch):
     monkeypatch.setattr(kernels, "pallas_interpret", lambda: False)
-    api, cfg, params, store = qwen_two_layers
+    api, cfg, params, store = _two_layers(one_chip, arch, NUM_BLOCKS)
     T, mb = 64, MAX_LEN // BLOCK_SIZE
     chunk = _sds(one_chip, (1, T), jnp.int32)
     compiled = _compile_step(paged_extend_step, api, cfg, params, store,
@@ -201,20 +202,15 @@ def _store_copies(hlo, num_blocks):
     return found[True], found[False]
 
 
-@pytest.mark.parametrize("arch,relayouts", [
-    ("qwen3-8b", 0),
-    # head_dim 64: the chip lays the store out with num_blocks minor, so
-    # the stack is relaid into the kernel's row-major layout once on the
-    # way in and once on the way out, for K and for V.  A lane-dense
-    # store layout ([L, N, bs, Hkv * D]) would remove these four.
-    ("qwen1.5-0.5b", 4),
-])
-def test_paged_decode_writes_store_in_place(one_chip, monkeypatch, arch,
-                                            relayouts):
+@pytest.mark.parametrize("arch", ["qwen3-8b", "qwen1.5-0.5b"])
+def test_paged_decode_writes_store_in_place(one_chip, monkeypatch, arch):
     """The decode step carries the stacked store through its layer loop
     and the kernel reads its layer from it: no layer of the store is
-    sliced out, copied or updated back inside the loop, and at
-    head_dim 128 nothing copies the store at all."""
+    sliced out, copied or updated back inside the loop, nothing copies
+    or relays the store outside it, at head_dim 64 as at 128, and the
+    step needs less scratch than one layer of the store.  The chip holds
+    the lane-dense store unpadded: the donated store is aliased whole at
+    its own size (the int32 length leaves round up to their tiles)."""
     monkeypatch.setattr(kernels, "pallas_interpret", lambda: False)
     api, cfg, params, store = _two_layers(one_chip, arch, LARGE_POOL)
     B, mb = 8, MAX_LEN // BLOCK_SIZE
@@ -224,13 +220,13 @@ def test_paged_decode_writes_store_in_place(one_chip, monkeypatch, arch,
                              vec, vec, vec, vec)
     hlo = compiled.as_text()
     assert "tpu_custom_call" in hlo and " while(" in hlo
-    in_loop, outside = _store_copies(hlo, LARGE_POOL)
-    assert in_loop == []
-    assert len(outside) <= relayouts, outside
-    if relayouts == 0:
-        k = store["scan"]["k"]
-        layer_bytes = k.size // k.shape[0] * k.dtype.itemsize
-        assert compiled.memory_analysis().temp_size_in_bytes < layer_bytes
+    assert _store_copies(hlo, LARGE_POOL) == ([], [])
+    k = store["scan"]["k"]
+    layer_bytes = k.size // k.shape[0] * k.dtype.itemsize
+    ma = compiled.memory_analysis()
+    assert ma.temp_size_in_bytes < layer_bytes
+    want = sum(x.size * x.dtype.itemsize for x in jax.tree.leaves(store))
+    assert want <= ma.alias_size_in_bytes < want * 1.001
 
 
 def test_moonlight_decode_step_holds_the_latent_store_in_place(one_chip,
@@ -245,7 +241,7 @@ def test_moonlight_decode_step_holds_the_latent_store_in_place(one_chip,
     api = get_model(cfg)
     params = _on(one_chip, jax.eval_shape(
         lambda k: nn.split(api.init(k, cfg))[0], jax.random.PRNGKey(0)))
-    store = _on(one_chip, specs.cache_template(cfg, LARGE_POOL, BLOCK_SIZE))
+    store = _on(one_chip, paged_template(cfg, LARGE_POOL, BLOCK_SIZE))
     B, mb = 8, MAX_LEN // BLOCK_SIZE
     vec = _sds(one_chip, (B,), jnp.int32)
     compiled = _compile_step(paged_decode_step, api, cfg, params, store,
